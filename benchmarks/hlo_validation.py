@@ -18,7 +18,7 @@ once inside a layer — a known ~4% residual for llama2-7b at 4k.)
 
 import jax
 
-from repro.launch.dryrun import build_cell, normalized_cost_analysis
+from repro.launch.dryrun import build_cell
 from repro.launch.mesh import make_production_mesh
 
 
@@ -34,7 +34,7 @@ def measured_flops(arch: str, shape: str, mesh, n_layers: int) -> float:
                                             "scan_layers": False})
     with mesh:
         compiled = lower_fn().compile()
-    return float(normalized_cost_analysis(compiled).get("flops", 0.0))
+    return float(compiled.cost_analysis().get("flops", 0.0))
 
 
 def main() -> None:

@@ -31,9 +31,10 @@ Soundness notes
 * Integer-narrowing ``convert_element_type`` whose input interval does
   not fit the target dtype emits ``narrowing-convert`` (lint rule R2);
   in-range narrowing (e.g. unpacked nibbles int32->int8) is clean.
-* ``swap`` replaces the cell interval (all kernel stores in this repo
-  cover the full block); reads of never-written cells fall back to the
-  dtype range and emit ``uninit-read``.
+* A ``swap`` that covers the whole ref replaces the cell interval; a
+  partial one (a slice store, e.g. the chunked activation quantization)
+  joins it with the old interval. Reads of never-written cells fall back
+  to the dtype range and emit ``uninit-read``.
 """
 from __future__ import annotations
 
@@ -44,6 +45,7 @@ from typing import Any, Callable
 import jax
 import numpy as np
 from jax._src import source_info_util
+from jax.extend.core import Literal
 
 from .intervals import Interval
 
@@ -157,7 +159,7 @@ class _Interp:
             Event(kind, eqn.primitive.name, detail, interval, _where(eqn)))
 
     def read(self, env, atom):
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             v = atom.val
             if hasattr(v, "shape"):
                 return Interval.of_array(v)
@@ -224,7 +226,7 @@ class _Interp:
             return self.run(sub.jaxpr, sub.consts, ins, store)
         return self.run(sub, (), ins, store)
 
-    def _p_pjit(self, eqn, ins, store):
+    def _p_jit(self, eqn, ins, store):
         # jnp's floor_divide wrapper lowers to div + a sign/rem-coupled
         # correction that a non-relational domain can't prune (it would
         # widen r // G to [r//G - 1, r//G], flagging every grouped-head
@@ -236,9 +238,7 @@ class _Interp:
         return self._call(eqn, ins, store)
 
     _p_closed_call = _call
-    _p_core_call = _call
     _p_remat2 = _call
-    _p_checkpoint = _call
 
     def _p_custom_jvp_call(self, eqn, ins, store):
         sub = eqn.params.get("call_jaxpr")
@@ -248,7 +248,6 @@ class _Interp:
         return self.run(sub.jaxpr, sub.consts, ins, store)
 
     _p_custom_vjp_call = _p_custom_jvp_call
-    _p_custom_vjp_call_jaxpr = _p_custom_jvp_call
 
     def _p_cond(self, eqn, ins, store):
         branches = eqn.params["branches"]
@@ -311,7 +310,9 @@ class _Interp:
         ref, val = ins[0], ins[1]
         assert isinstance(ref, _Ref), "swap on non-ref"
         old = store.get(ref)
-        store[ref] = val
+        full = (math.prod(eqn.invars[1].aval.shape)
+                == math.prod(eqn.invars[0].aval.shape))
+        store[ref] = val if full or old is None else old.union(val)
         return [old if old is not None else Interval.from_dtype(ref.dtype)]
 
     def _p_pallas_call(self, eqn, ins, store):

@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+from jax.experimental import pallas as pl
 
 from .interp import Analysis, analyze_index_map
 from .intervals import Interval
@@ -121,23 +122,16 @@ def rule_float_accum_on_is_path(entry, an: Analysis) -> list:
 
 
 def _block_dims(bm) -> list:
-    dims = []
-    for b in getattr(bm, "block_shape", ()) or ():
-        try:
-            dims.append(int(b))
-        except (TypeError, ValueError):
-            dims.append(1)  # mapped/squeezed dim
-    return dims
+    """Block extent per operand dim (a squeezed dim counts as 1)."""
+    return [1 if isinstance(b, pl.Squeezed) else int(b.block_size)
+            for b in bm.block_shape]
 
 
 def rule_blockspec_divisibility(entry, an: Analysis) -> list:
     out = []
     for p in an.pallas:
-        for i, bm in enumerate(getattr(p.grid_mapping, "block_mappings", ())):
-            shape = getattr(getattr(bm, "array_shape_dtype", None),
-                            "shape", None)
-            if shape is None:
-                continue
+        for i, bm in enumerate(p.grid_mapping.block_mappings):
+            shape = bm.array_aval.shape
             for d, (s, b) in enumerate(zip(shape, _block_dims(bm))):
                 if b and s % b:
                     out.append(Finding(
@@ -151,12 +145,9 @@ def rule_index_map_bounds(entry, an: Analysis) -> list:
     out = []
     prefetch = list(getattr(entry, "prefetch_ranges", ()) or ())
     for p in an.pallas:
-        for i, bm in enumerate(getattr(p.grid_mapping, "block_mappings", ())):
-            imj = getattr(bm, "index_map_jaxpr", None)
-            shape = getattr(getattr(bm, "array_shape_dtype", None),
-                            "shape", None)
-            if imj is None or shape is None:
-                continue
+        for i, bm in enumerate(p.grid_mapping.block_mappings):
+            imj = bm.index_map_jaxpr
+            shape = bm.array_aval.shape
             blocks = _block_dims(bm)
             try:
                 idx = analyze_index_map(imj, p.grid, prefetch, len(p.grid))

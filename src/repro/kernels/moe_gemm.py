@@ -54,12 +54,27 @@ m-tile that starts at or past its expert's count. The contract:
     codes for every n-tile/k-group — ``ops.qgemm_grouped`` no longer runs
     the dense ``act_quant`` kernel over the full ``(E*C, K)`` buffer, so
     the padded slots are never even quantized. The in-kernel math is
-    ``act_quant._quantize_rows`` verbatim, which keeps fused and unfused
+    ``act_quant.quantize_rows`` verbatim, which keeps fused and unfused
     paths bit-identical.
   * bit-exactness invariant: for any zero-filled-past-count input, ragged
     output == dense grouped output, element for element, including
     per-expert alphas (the epilogue divides by alpha with the same op
     order the dense wrapper uses when folding 1/alpha into ``sa``).
+
+VMEM at full K. The fused-quant kernels hold an m-tile's whole raw
+``(bm, K)`` activation slab plus its ``(bm, K)`` int8 codes. At Mixtral's
+expert down projection (K=14336, bm=128) the compiler refused them for
+VMEM at every capacity that is a multiple of bm: a whole-row f32 upcast
+inside the quantization (7 MiB per temporary) on top of a double-buffered
+slab (7 MiB in bf16, 14 MiB in f32) against the 16 MiB default scoped
+limit. Two changes fix it without shrinking the tiles: the slab is
+quantized in lane chunks (``act_quant.quantize_rows``; temporaries are
+``(bm, 512)``), and it is single-buffered (``pl.Buffered(1)``). Its block
+index changes once per m-tile, every ``N/bn * K/bk`` grid steps, so a
+second buffer would only prefetch the next tile's slab at the cost of a
+whole slab of VMEM. Capping bm by K would halve the MXU rows of prefill;
+raising ``vmem_limit_bytes`` would tie the kernel to one chip's VMEM and
+grow with K. ``tests/test_tpu_compile.py`` compiles every capacity.
 
 With ragged skipping in place the ``act_quant`` ``maximum(amax, 1e-8)``
 floor is no longer what keeps padded slots sane on the grouped path (they
@@ -77,8 +92,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .act_quant import _quantize_rows
-from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, _snap_block)
+from .act_quant import quantize_rows
+from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, _snap_block,
+                        scale_rows, scale_spec)
 from .w4a16_gemm import _dequant_group_accumulate
 
 
@@ -93,9 +109,10 @@ def _grouped_kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] = _group_accumulate(
-        x_ref[0], wp_ref[0], s_ref[0], acc_ref[...],
-        gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
-        integer=integer, coarse=coarse)
+        x_ref[0], wp_ref[0],
+        scale_rows(s_ref, k, groups_per_blk, coarse=coarse, expert=True),
+        acc_ref[...], gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
+        integer=integer)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -108,16 +125,15 @@ def _grouped_kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
             o_ref[0] = (acc_ref[...] * sa_ref[0]).astype(out_dtype)
 
 
-def _grouped_blocks(E, Cp, K, N, bm, bn, bk, *, pack, s_rows, coarse):
-    """Grid + BlockSpecs shared by the int- and float-scale variants."""
+def _grouped_blocks(E, Cp, K, N, bm, bn, bk, *, pack, s_rows):
+    """Grid + BlockSpecs shared by the int- and float-scale variants
+    (``s_rows``: rows of the scale operand, K/g or 1 for coarse)."""
     nk = K // bk
     grid = (E, Cp // bm, N // bn, nk)
     in_specs = [
         pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
         pl.BlockSpec((1, bk // pack, bn), lambda e, i, j, k: (e, k, j)),
-        pl.BlockSpec((1, s_rows, bn),
-                     (lambda e, i, j, k: (e, 0, j)) if coarse
-                     else (lambda e, i, j, k: (e, k, j))),
+        scale_spec(s_rows, bn, expert=True),
         pl.BlockSpec((1, bm, 1), lambda e, i, j, k: (e, i, 0)),
     ]
     out_spec = pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j))
@@ -173,8 +189,7 @@ def fg_grouped_gemm_integer_scale(
     xq, sa, Cp = _pad_tokens(xq, sa, C, bm)
     pack = 2 if w_bits == 4 else 1
     grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=groups_per_blk,
-        coarse=False)
+        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=K // gs)
     out = pl.pallas_call(
         functools.partial(
             _grouped_kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
@@ -226,8 +241,7 @@ def fg_grouped_gemm_float_scale(
     xq, sa, Cp = _pad_tokens(xq, sa, C, bm)
     pack = 2 if w_bits == 4 else 1
     grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=pack,
-        s_rows=1 if coarse else groups_per_blk, coarse=coarse)
+        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=scale.shape[1])
     out = pl.pallas_call(
         functools.partial(
             _grouped_kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
@@ -252,8 +266,9 @@ def _grouped_wo_kernel(x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
         facc_ref[...] = jnp.zeros_like(facc_ref)
 
     facc_ref[...] = _dequant_group_accumulate(
-        x_ref[0], wp_ref[0], s_ref[0], facc_ref[...],
-        gs=gs, groups_per_blk=groups_per_blk)
+        x_ref[0], wp_ref[0],
+        scale_rows(s_ref, k, groups_per_blk, expert=True),
+        facc_ref[...], gs=gs, groups_per_blk=groups_per_blk)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -292,8 +307,7 @@ def grouped_w4a16_gemm(
     if Cp != C:
         x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
     grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=2, s_rows=groups_per_blk,
-        coarse=False)
+        E, Cp, K, N, bm, bn, bk, pack=2, s_rows=K // gs)
     out = pl.pallas_call(
         functools.partial(_grouped_wo_kernel, nk=nk, gs=gs,
                           groups_per_blk=groups_per_blk,
@@ -328,7 +342,7 @@ def ragged_tile_stats(row_counts, C: int, bm: int = 128) -> dict:
     return {"bm": bm, "dense_m_tiles": dense, "ragged_m_tiles": ragged}
 
 
-def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows, coarse,
+def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows,
                   fused_quant, n_extra=0):
     """Grid + BlockSpecs for the ragged kernels.
 
@@ -348,7 +362,8 @@ def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows, coarse,
         def x_map(e, i, j, k, rc):
             return (e, jnp.minimum(i, _last_tile(rc, e)), 0)
 
-        x_spec = pl.BlockSpec((1, bm, K), x_map)
+        x_spec = pl.BlockSpec((1, bm, K), x_map,
+                              pipeline_mode=pl.Buffered(1))
     else:
         def x_map(e, i, j, k, rc):
             act = i * bm < rc[e]
@@ -362,10 +377,8 @@ def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows, coarse,
         return (e, jnp.where(act, k, 0), jnp.where(act, j, 0))
 
     def s_map(e, i, j, k, rc):
-        act = i * bm < rc[e]
-        if coarse:
-            return (e, 0, jnp.where(act, j, 0))
-        return (e, jnp.where(act, k, 0), jnp.where(act, j, 0))
+        # the whole (s_rows, bn) scale column, as in w4a8_gemm.scale_spec
+        return (e, 0, jnp.where(i * bm < rc[e], j, 0))
 
     nk = K // bk
     grid = (E, Cp // bm, N // bn, nk)
@@ -374,8 +387,8 @@ def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows, coarse,
         pl.BlockSpec((1, bk // pack, bn), w_map),
         pl.BlockSpec((1, s_rows, bn), s_map),
     ]
-    if n_extra:  # per-expert alpha: (E, 1) f32, one scalar block
-        in_specs.append(pl.BlockSpec((1, 1), lambda e, i, j, k, rc: (e, 0)))
+    if n_extra:  # per-expert alpha: the whole (E, 1) f32 array, in SMEM
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     out_spec = pl.BlockSpec((1, bm, bn), lambda e, i, j, k, rc: (e, i, j))
     return grid, in_specs, out_spec, nk
 
@@ -400,9 +413,7 @@ def _ragged_kernel(rc_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
 
     @pl.when(active & (j == 0) & (k == 0))
     def _quant():
-        q, s = _quantize_rows(x_ref[0], qm=qm)
-        xq_s[...] = q
-        sa_s[...] = s
+        sa_s[...] = quantize_rows(x_ref, xq_s, qm=qm, lead=(0,))
 
     @pl.when(active)
     def _body():
@@ -412,9 +423,10 @@ def _ragged_kernel(rc_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
 
         xblk = xq_s[:, pl.ds(k * bk, bk)]
         acc_ref[...] = _group_accumulate(
-            xblk, wp_ref[0], s_ref[0], acc_ref[...],
-            gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
-            integer=integer, coarse=coarse)
+            xblk, wp_ref[0],
+            scale_rows(s_ref, k, groups_per_blk, coarse=coarse, expert=True),
+            acc_ref[...], gs=gs, groups_per_blk=groups_per_blk,
+            w_bits=w_bits, integer=integer)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -422,7 +434,7 @@ def _ragged_kernel(rc_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
         def _write():
             # same op order as the dense wrapper's 1/alpha folding
             # (sa / alpha, then ONE multiply) so ragged == dense bitwise.
-            sa = sa_s[...] / a_ref[0]
+            sa = sa_s[...] / a_ref[e, 0]
             if integer:
                 o_ref[0] = (acc_ref[...].astype(jnp.float32)
                             * sa).astype(out_dtype)
@@ -469,8 +481,7 @@ def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, *, integer: bool,
 
     pack = 2 if w_bits == 4 else 1
     grid, in_specs, out_spec, nk = _ragged_specs(
-        E, Cp, K, N, bm, bn, bk, pack=pack,
-        s_rows=1 if coarse else groups_per_blk, coarse=coarse,
+        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=scale.shape[1],
         fused_quant=True, n_extra=1)
     acc_dtype = jnp.int32 if integer else jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -567,8 +578,9 @@ def _ragged_wo_kernel(rc_ref, x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
             facc_ref[...] = jnp.zeros_like(facc_ref)
 
         facc_ref[...] = _dequant_group_accumulate(
-            x_ref[0], wp_ref[0], s_ref[0], facc_ref[...],
-            gs=gs, groups_per_blk=groups_per_blk)
+            x_ref[0], wp_ref[0],
+            scale_rows(s_ref, k, groups_per_blk, expert=True),
+            facc_ref[...], gs=gs, groups_per_blk=groups_per_blk)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -619,8 +631,8 @@ def grouped_w4a16_gemm_ragged(
     if Cp != C:
         x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
     grid, in_specs, out_spec, nk = _ragged_specs(
-        E, Cp, K, N, bm, bn, bk, pack=2, s_rows=groups_per_blk,
-        coarse=False, fused_quant=False)
+        E, Cp, K, N, bm, bn, bk, pack=2, s_rows=K // gs,
+        fused_quant=False)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,

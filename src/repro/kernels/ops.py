@@ -25,8 +25,10 @@ is derived from ``qspec.amplifier``; heuristic amplifiers have no static
 value and raise instead of silently rescaling by a wrong constant (the
 stored alpha is what the PR-3 overflow certificates cover).
 
-On this CPU container only ``BlockConfig(interpret=True)`` executes; the
-BlockSpecs/grids are identical either way.
+Without a TPU only ``BlockConfig(interpret=True)`` executes. Interpret
+mode runs the same BlockSpecs and grids but not the TPU compiler's tiling
+and VMEM checks: ``tests/test_tpu_compile.py`` compiles the kernels for a
+described v5e, and ``chip_smoke.py`` runs them on one.
 
 The v1 shims (``*_from_params``, positional ``qvalue, scale``,
 ``block=dict``, ``interpret=``) completed their one-release deprecation

@@ -17,19 +17,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .w4a8_gemm import _round_up, _snap_block, _unpack_wblock
+from .w4a8_gemm import (_round_up, _snap_block, _unpack_wblock, scale_rows,
+                        scale_spec)
 
 
-def _dequant_group_accumulate(x, wp, s, facc, *, gs: int,
+def _dequant_group_accumulate(x, wp, srows, facc, *, gs: int,
                               groups_per_blk: int):
     """Shared weight-only block body (also used by the grouped MoE kernel):
-    unpack int4, dequant each group to bf16 with its float scale, bf16 MXU
-    matmul with f32 accumulation."""
+    unpack int4, dequant each group to bf16 with its float scale row (from
+    ``w4a8_gemm.scale_rows``), bf16 MXU matmul with f32 accumulation."""
     wfull = _unpack_wblock(wp, gs * groups_per_blk)
     for gi in range(groups_per_blk):
         xg = x[:, gi * gs:(gi + 1) * gs]  # (bm, gs) bf16
         wg = wfull[gi * gs:(gi + 1) * gs, :]  # (gs, bn) int8
-        wd = (wg.astype(jnp.float32) * s[gi, :][None, :]).astype(
+        wd = (wg.astype(jnp.float32) * srows[gi]).astype(
             jnp.bfloat16
         )
         facc = facc + jax.lax.dot_general(
@@ -48,8 +49,8 @@ def _kernel(x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
         facc_ref[...] = jnp.zeros_like(facc_ref)
 
     facc_ref[...] = _dequant_group_accumulate(
-        x_ref[...], wp_ref[...], s_ref[...], facc_ref[...],
-        gs=gs, groups_per_blk=groups_per_blk)
+        x_ref[...], wp_ref[...], scale_rows(s_ref, k, groups_per_blk),
+        facc_ref[...], gs=gs, groups_per_blk=groups_per_blk)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -94,7 +95,7 @@ def w4a16_gemm(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((groups_per_blk, bn), lambda i, j, k: (k, j)),
+            scale_spec(K // gs, bn),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),

@@ -15,9 +15,12 @@ TPU adaptation (see DESIGN.md §2/§4):
     layout (``repro.core.packing``) so unpack = 2 shift pairs + one
     sublane-dim concat; no gathers/lane shuffles;
   * int32 accumulator lives in VMEM scratch across the K grid;
-  * BlockSpec tiles default to (bm=128, bn=256, bk=512): MXU-aligned
-    (multiples of 128 on the contraction/lane dims), VMEM footprint
-    ~0.4 MB << 16 MB so the pipeline can double-buffer.
+  * BlockSpec tiles default to (bm=128, bn=256, bk=512), multiples of 128
+    on the contraction/lane dims; the ``(K/g, bn)`` scale column stays
+    resident across k (:func:`scale_spec`), since a ``(bk/g, bn)`` block
+    per k-step is one Mosaic refuses whenever bk/g is not a multiple of 8.
+    Interpret mode does not apply the TPU compiler's tiling and VMEM rules;
+    ``tests/test_tpu_compile.py`` compiles the kernels for a v5e to do so.
 
 Weight-bit generality: the same kernel body serves W8A8 (``w_bits=8``,
 no unpack) — Integer Scale is bit-width agnostic (paper §7).
@@ -54,16 +57,47 @@ def _unpack_wblock(wp: jax.Array, bk: int) -> jax.Array:
     return out.astype(jnp.int8)
 
 
-def _group_accumulate(x, wp, s, acc, *, gs: int, groups_per_blk: int,
-                      w_bits: int, integer: bool, coarse: bool = False):
+def scale_spec(n_groups: int, bn: int, *, expert: bool = False):
+    """BlockSpec of a ``(K/g, N)`` (or ``(E, K/g, N)``) scale operand: the
+    whole ``(K/g, bn)`` column of the current n-tile, resident across the
+    k axis (its block index ignores ``k``, so it is fetched once per
+    output tile). A ``(bk/g, bn)`` block per k-step would be refused by
+    Mosaic whenever ``bk/g`` is neither a multiple of 8 (the sublane tile)
+    nor ``K/g`` — e.g. bk=512, g=128 gives 4. Kernels read their groups'
+    rows out of the column with :func:`scale_rows`.
+    """
+    if expert:
+        return pl.BlockSpec((1, n_groups, bn), lambda e, i, j, k: (e, 0, j))
+    return pl.BlockSpec((n_groups, bn), lambda i, j, k: (0, j))
+
+
+def scale_rows(s_ref, k, groups_per_blk: int, *, coarse: bool = False,
+               expert: bool = False) -> list:
+    """The k-th K-block's per-group scale rows, each ``(1, bn)``, read from
+    the resident column of :func:`scale_spec`. Rows are loaded one at a
+    time: Mosaic accepts a dynamic single-row load at any offset, but a
+    multi-row one only at offsets it can prove are multiples of 8.
+    ``coarse`` (one per-channel row) reuses row 0 for every group."""
+    lead = (0,) if expert else ()
+
+    def row(r):
+        return s_ref[(*lead, pl.ds(r, 1), slice(None))]
+
+    if coarse:
+        return [row(0)] * groups_per_blk
+    return [row(k * groups_per_blk + gi) for gi in range(groups_per_blk)]
+
+
+def _group_accumulate(x, wp, srows, acc, *, gs: int, groups_per_blk: int,
+                      w_bits: int, integer: bool):
     """Shared block body for every fine-grained W{4,8}A8 kernel: unpack the
-    packed weight block, run one MXU int8 matmul per group, scale-accumulate.
+    packed weight block, run one MXU int8 matmul per group, scale-accumulate
+    with that group's ``(1, bn)`` row from :func:`scale_rows`.
 
     ``integer=True`` keeps the accumulation in int32 (Eq. 2 — the
     integer-scale step, no convert in the loop); ``integer=False`` converts
     each group partial to f32 and FMAs with the float scale (Eq. 1 — the
-    bottleneck the paper removes). ``coarse`` reuses scale row 0 for every
-    group (per-channel baseline).
+    bottleneck the paper removes).
     """
     wfull = _unpack_wblock(wp, gs * groups_per_blk) if w_bits == 4 else wp
     for gi in range(groups_per_blk):  # static unroll over groups in block
@@ -73,13 +107,12 @@ def _group_accumulate(x, wp, s, acc, *, gs: int, groups_per_blk: int,
             xg, wg, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
-        srow = s[0, :] if coarse else s[gi, :]
         if integer:
             # THE integer-scale step: stays in int32 — no convert in loop.
-            acc = acc + part * srow[None, :]
+            acc = acc + part * srows[gi]
         else:
             # THE float-scale bottleneck: per-group convert + f32 FMA.
-            acc = acc + part.astype(jnp.float32) * srow[None, :]
+            acc = acc + part.astype(jnp.float32) * srows[gi]
     return acc
 
 
@@ -93,8 +126,9 @@ def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] = _group_accumulate(
-        x_ref[...], wp_ref[...], s_ref[...], acc_ref[...],
-        gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits, integer=True)
+        x_ref[...], wp_ref[...], scale_rows(s_ref, k, groups_per_blk),
+        acc_ref[...], gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
+        integer=True)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -152,7 +186,7 @@ def fg_gemm_integer_scale(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // pack, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((groups_per_blk, bn), lambda i, j, k: (k, j)),
+            scale_spec(K // gs, bn),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .w4a8_gemm import _group_accumulate, _round_up, _snap_block
+from .w4a8_gemm import (_group_accumulate, _round_up, _snap_block,
+                        scale_rows, scale_spec)
 
 
 def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
@@ -35,9 +36,9 @@ def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
         facc_ref[...] = jnp.zeros_like(facc_ref)
 
     facc_ref[...] = _group_accumulate(
-        x_ref[...], wp_ref[...], s_ref[...], facc_ref[...],
-        gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
-        integer=False, coarse=coarse)
+        x_ref[...], wp_ref[...],
+        scale_rows(s_ref, k, groups_per_blk, coarse=coarse), facc_ref[...],
+        gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits, integer=False)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -83,7 +84,6 @@ def fg_gemm_float_scale(
         sa = jnp.pad(sa, ((0, Mp - M), (0, 0)))
 
     pack = 2 if w_bits == 4 else 1
-    s_rows = 1 if coarse else groups_per_blk
     out = pl.pallas_call(
         functools.partial(
             _kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
@@ -93,9 +93,7 @@ def fg_gemm_float_scale(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // pack, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((s_rows, bn),
-                         (lambda i, j, k: (0, j)) if coarse
-                         else (lambda i, j, k: (k, j))),
+            scale_spec(scale.shape[0], bn),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),

@@ -40,14 +40,6 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
-def normalized_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on current jax but a
-    list of per-program dicts on older versions — normalize to one dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
                 "pred": 1, "s4": 0.5, "u4": 0.5}
@@ -249,7 +241,7 @@ def run_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
             compiled = lowered.compile()
             t2 = time.time()
         mem = compiled.memory_analysis()
-        cost = normalized_cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         rec.update(
             status="ok",
             lower_s=round(t1 - t0, 1),

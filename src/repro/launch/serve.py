@@ -1,12 +1,20 @@
-"""Serving driver: load a checkpoint, PTQ per recipe, run the continuous-
-batching engine over a stream of requests.
+"""Serving driver: build or load a model, PTQ per recipe, run the
+continuous-batching engine over a stream of requests.
 
-    PYTHONPATH=src:. python -m repro.launch.serve --algo gptq --requests 8 \
-        --scale-mode integer
+    PYTHONPATH=src:. python -m repro.launch.serve --arch mixtral-8x7b \
+        --num-layers 2 --prefill-len 512 --max-seq 640 --max-new 16
 
-``--arch mixtral-8x7b`` swaps the trained bench_lm for a smoke-shaped
-registry architecture (random init) — the quantized-MoE ragged decode
-path. ``--metrics-out PATH`` writes the run's telemetry as JSONL: one
+``--arch`` names a registry architecture, built at its published widths
+with random weights from a fixed seed; ``--num-layers N`` cuts its depth
+(printed as ``reduced: num_layers <published>-><N>``) and ``--smoke`` swaps in the
+architecture's CPU-sized smoke shape instead. ``--arch bench-lm`` (the
+default) serves the paper's small benchmark LM, trained if its checkpoint
+exists. Quantized layers run the Pallas kernels (``--kernel-mode
+pallas``, the default); CPU callers pass ``pallas_interpret`` or
+``reference``. ``chip_smoke.py`` drives this same path in-process
+through :func:`parse_args` and :func:`run`.
+
+``--metrics-out PATH`` writes the run's telemetry as JSONL: one
 event per line (submit / admit / tick / retire / counters / trace /
 ptq_run) with a trailing ``{"snapshot": ...}`` line carrying every
 counter/gauge/histogram — per-tick decode latency (host and device),
@@ -24,6 +32,12 @@ instrumentation can never silently add a retrace (each circuit-breaker
 fallback re-establishes the jitted decode: exactly one intentional extra
 trace).
 
+The run fails (non-zero exit) when any request ends ``error`` or ``nan``
+or any kernel-path exception was counted, unless a chaos drill is armed.
+There is no silent fallback: ``--fallback-kernel-mode`` defaults to
+``none``, so a kernel that fails is an error, never a quiet run on the
+reference route.
+
 Robustness knobs: ``--deadline-s`` / ``--max-queue`` /
 ``--truncate-prompts`` / ``--breaker-threshold`` /
 ``--fallback-kernel-mode`` map onto the ``ServeConfig`` lifecycle
@@ -35,7 +49,11 @@ the ``nan`` outcome lands in the metrics artifact + as distinct
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 import time
+from typing import Any
 
 import jax
 
@@ -46,15 +64,43 @@ from repro.data.pipeline import DataConfig, SyntheticPipeline
 from repro.nn import spec as S
 from repro.serving.engine import Engine, ServeConfig
 
+#: Persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed directory inside the checkout (the path is part of
+#: every cache key, so a moving directory never hits). Gitignored.
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def _load_model(arch: str):
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and no
+    other directory is configured here. Called once by each entry point
+    (``main`` below, ``chip_smoke.py``), never at import. Every program is
+    cached, however fast it compiled, so a second run compiles nothing.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir
+
+
+def load_model(arch: str, *, smoke: bool = False,
+               num_layers: int | None = None):
+    """(api, cfg, fp_params, trained). Registry architectures are built at
+    their published widths (or their smoke shape) with random weights
+    from a fixed seed; ``num_layers`` cuts depth only."""
     if arch == "bench-lm":
+        if num_layers is not None:
+            raise ValueError("--num-layers applies to registry archs only")
         from benchmarks.common import load_bench_model
 
         return load_bench_model()
     from repro.models.registry import get_arch, get_model
 
-    cfg = get_arch(arch, smoke=True)
+    cfg = get_arch(arch, smoke=smoke)
+    if num_layers is not None and num_layers != cfg.num_layers:
+        print(f"[serve] reduced: num_layers {cfg.num_layers}->{num_layers}")
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
     api = get_model(cfg)
     params = S.materialize(api.param_specs(cfg, None), jax.random.PRNGKey(0))
     return api, cfg, params, False
@@ -129,12 +175,18 @@ def _telemetry_cell(reg: obs.Registry) -> None:
           f"amax_floor_hits={c.get('amax_floor_hits_total', {})}")
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bench-lm",
                     help="bench-lm (trained ckpt if present) or a registry "
-                         "architecture run at smoke shape, e.g. "
+                         "architecture at its published widths, e.g. "
                          "mixtral-8x7b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="build the registry architecture at its CPU-sized "
+                         "smoke shape instead of its published widths")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="cut the architecture's depth to N layers "
+                         "(widths unchanged)")
     ap.add_argument("--algo", default="rtn",
                     choices=["rtn", "gptq", "awq", "smoothquant",
                              "omniquant"])
@@ -152,7 +204,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--fp", action="store_true",
                     help="serve unquantized (baseline)")
-    ap.add_argument("--kernel-mode", default="reference",
+    ap.add_argument("--kernel-mode", default="pallas",
                     choices=["reference", "pallas", "pallas_interpret"],
                     help="qlinear backend inside prefill/decode")
     ap.add_argument("--deadline-s", type=float, default=0.0,
@@ -167,11 +219,11 @@ def main() -> None:
     ap.add_argument("--breaker-threshold", type=int, default=3,
                     help="consecutive kernel failures / poisoned ticks "
                          "that trip the fallback circuit breaker")
-    ap.add_argument("--fallback-kernel-mode", default="reference",
+    ap.add_argument("--fallback-kernel-mode", default="none",
                     choices=["reference", "pallas", "pallas_interpret",
                              "none"],
-                    help="kernel mode the breaker degrades to "
-                         "('none' disables mode fallback)")
+                    help="kernel mode the breaker degrades to (default "
+                         "'none': a failing kernel aborts the run)")
     ap.add_argument("--chaos-nan-ticks", default="",
                     help="comma-separated decode ticks at which to inject "
                          "NaN logits into every active slot "
@@ -189,11 +241,34 @@ def main() -> None:
                     help="capture a jax.profiler trace of the serving "
                          "loop into this directory (TensorBoard profile "
                          "plugin format)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    reg = obs.default_registry()
-    api, cfg, params, trained = _load_model(args.arch)
-    print(f"[serve] model={cfg.name} trained={trained}")
+
+@dataclasses.dataclass
+class ServeResult:
+    """What one :func:`run` served, for in-process callers."""
+
+    api: Any
+    cfg: Any            # the ModelConfig the engine was built from
+    params: Any         # the served (quantized unless --fp) params
+    recipe: Any
+    engine: Engine
+    registry: obs.Registry
+    outputs: dict
+    seconds: float      # wall time of the serving loop
+    tokens: int
+
+
+class ServeFailed(RuntimeError):
+    """A request ended ``error``/``nan`` or a kernel path raised."""
+
+
+def run(args: argparse.Namespace) -> ServeResult:
+    reg = obs.current_registry()
+    api, cfg, params, trained = load_model(
+        args.arch, smoke=args.smoke, num_layers=args.num_layers)
+    print(f"[serve] model={cfg.name} layers={cfg.num_layers} "
+          f"d_model={cfg.d_model} trained={trained}")
     if args.fp:
         recipe, qparams = None, params
     else:
@@ -211,6 +286,7 @@ def main() -> None:
         t0 = time.time()
         qparams = ptq.post_training_quantize(api, cfg, params, recipe,
                                              calib)
+        del params  # the fp weights are not served
         print(f"[serve] quantized ({spec.name}) in {time.time()-t0:.1f}s")
 
     fb = args.fallback_kernel_mode
@@ -225,7 +301,8 @@ def main() -> None:
                      breaker_threshold=args.breaker_threshold,
                      fallback_kernel_mode=None if fb == "none" else fb)
     eng = Engine(api, cfg, qparams, sc, recipe=recipe)
-    if args.chaos_nan_ticks or args.chaos_kernel_ticks:
+    chaos_armed = bool(args.chaos_nan_ticks or args.chaos_kernel_ticks)
+    if chaos_armed:
         from repro.serving import chaos
 
         ccfg = chaos.ChaosConfig(
@@ -235,7 +312,7 @@ def main() -> None:
             kernel_failures=tuple(
                 chaos.KernelFault(tick=int(t))
                 for t in args.chaos_kernel_ticks.split(",") if t))
-        monkey = chaos.ChaosMonkey(ccfg).install(eng)
+        chaos.ChaosMonkey(ccfg).install(eng)
         print(f"[serve] chaos armed: nan_ticks="
               f"[{args.chaos_nan_ticks}] kernel_ticks="
               f"[{args.chaos_kernel_ticks}]")
@@ -277,6 +354,22 @@ def main() -> None:
                   f"(open at https://ui.perfetto.dev)")
         if args.profile_dir:
             print(f"[serve] jax profiler capture -> {args.profile_dir}")
+    if not chaos_armed:
+        failed = {rid: o for rid, o in eng.outcomes.items()
+                  if o in ("error", "nan")}
+        kfail = sum(reg.snapshot()["counters"].get(
+            "engine_kernel_failures_total", {}).values())
+        if failed or kfail:
+            raise ServeFailed(
+                f"{len(failed)} request(s) ended error/nan {failed}, "
+                f"{int(kfail)} kernel failure(s)")
+    return ServeResult(api, eng.cfg, eng.params, eng.recipe, eng, reg,
+                       outs, dt, total)
+
+
+def main(argv: list[str] | None = None) -> None:
+    use_compile_cache()
+    run(parse_args(argv))
 
 
 if __name__ == "__main__":

@@ -45,11 +45,12 @@ snapshots.
 * **Circuit breaker / graceful degradation**: ``breaker_threshold``
   consecutive kernel-path exceptions (prefill/decode), or that many
   consecutive poisoned decode ticks, trip a fallback — the engine swaps
-  ``kernel_mode`` to ``ServeConfig.fallback_kernel_mode`` (e.g.
-  ``pallas -> reference``) and, when ``fallback_params``/
-  ``fallback_recipe`` were provided at construction, the quantized
-  parameter set too (integer-scale -> float-scale, the DGQ-style
-  two-tier degradation), then RE-ESTABLISHES the jitted prefill/decode.
+  ``kernel_mode`` to ``ServeConfig.fallback_kernel_mode`` (an explicit
+  opt-in, e.g. ``pallas -> reference``; the default is none) and, when
+  ``fallback_params``/``fallback_recipe`` were provided at construction,
+  the quantized parameter set too (integer-scale -> float-scale, the
+  DGQ-style two-tier degradation), then RE-ESTABLISHES the jitted
+  prefill/decode.
   Each fallback is one intentional extra trace — steady state must still
   hold ``decode_traces == 1 + fallbacks``. ``engine_fallback_events_total
   {reason}`` counts trips; with no fallback remaining the engine aborts:
@@ -58,7 +59,11 @@ snapshots.
   ``finally`` can flush telemetry. External quant-health monitors (e.g.
   watching ``alpha_cap_events_total`` / ``qcert_verdicts_total{verdict=
   "fallback"}`` deltas) can force the same path via
-  :meth:`Engine.trip_breaker`.
+  :meth:`Engine.trip_breaker`. An exception raised by a call that traced
+  the route (its first trace, lowering or compile — a kernel the compiler
+  refuses) is a fault in the program, not a transient one: it is counted
+  in ``engine_kernel_failures_total`` and then propagates out of
+  :meth:`Engine.run` without touching the breaker.
 * **Tick watchdog**: a ``distributed.fault.Heartbeat`` on the registry
   clock times every decode tick; stragglers (> ``slow_tick_factor`` x
   rolling median) bump ``engine_slow_ticks_total`` + a ``slow_tick``
@@ -146,9 +151,10 @@ class ServeConfig:
     truncate_prompts: bool = False  # opt-in: clip over-length prompts
     nan_guard: bool = True         # host-side NaN/Inf logit quarantine
     breaker_threshold: int = 3     # consecutive failures tripping fallback
-    # kernel_mode the breaker degrades to (None disables mode fallback;
-    # a value equal to the active mode is ignored)
-    fallback_kernel_mode: str | None = "reference"
+    # kernel_mode the breaker degrades to — an explicit opt-in, so a
+    # failing kernel is never served by another route unasked (None: no
+    # mode fallback; a value equal to the active mode is ignored)
+    fallback_kernel_mode: str | None = None
     slow_tick_factor: float = 3.0  # watchdog straggler multiple of median
 
 
@@ -543,11 +549,13 @@ class Engine:
 
     # -- circuit breaker ----------------------------------------------------
     def _on_phase_failure(self, phase: str, exc: Exception,
-                          rid: int | None = None) -> None:
+                          rid: int | None = None, *,
+                          breaker: bool = True) -> None:
         """A kernel-path exception escaped the jitted ``phase``: count it,
         retire the directly-affected rid (prefill only — decode failures
-        leave slots intact for the retry), and trip the breaker when the
-        streak reaches the threshold."""
+        leave slots intact for the retry), and — unless ``breaker`` is
+        off (the failing call traced the route; the caller re-raises) —
+        trip the breaker when the streak reaches the threshold."""
         self._fail_streak += 1
         reg = obs.current_registry()
         reg.counter("engine_kernel_failures_total", "", ("phase",)).inc(
@@ -559,7 +567,7 @@ class Engine:
         reg.emit(ev)
         if rid is not None:
             self._finish(rid, "error", error=repr(exc)[:200])
-        if self._fail_streak >= max(1, self.sc.breaker_threshold):
+        if breaker and self._fail_streak >= max(1, self.sc.breaker_threshold):
             self._trip_breaker(f"{phase}_exception", exc)
 
     def _fallback_available(self) -> bool:
@@ -618,6 +626,7 @@ class Engine:
                 break
             rid, prompt = self.queue.pop(0)
             poisoned = False
+            traces = self._trace_counts["prefill"]
             try:
                 with obs.span(reg, "engine_phase_seconds", phase="prefill",
                               event="admit") as sp:
@@ -672,7 +681,11 @@ class Engine:
                             ).observe(ttft)
                             sp.fields["ttft_s"] = round(ttft, 6)
             except Exception as exc:
-                self._on_phase_failure("prefill", exc, rid=rid)
+                traced = self._trace_counts["prefill"] != traces
+                self._on_phase_failure("prefill", exc, rid=rid,
+                                       breaker=not traced)
+                if traced:
+                    raise
                 continue
             if poisoned:
                 self._finish(rid, "nan", slot=i, output=[],
@@ -698,6 +711,7 @@ class Engine:
                 pos[i] = s.length
                 slot_rids[i] = s.request_id
                 active += 1
+        traces = self._trace_counts["decode"]
         try:
             with obs.span(reg, "engine_phase_seconds", phase="decode",
                           event="tick") as sp:
@@ -722,7 +736,10 @@ class Engine:
         except Exception as exc:
             # tick NOT advanced, cache NOT committed: the run loop retries
             # (bounded — the breaker trips fallback/abort on a streak)
-            self._on_phase_failure("decode", exc)
+            traced = self._trace_counts["decode"] != traces
+            self._on_phase_failure("decode", exc, breaker=not traced)
+            if traced:
+                raise
             return
         self.cache = new_cache
         self._fail_streak = 0

@@ -162,7 +162,10 @@ def test_kernel_fault_below_threshold_retries_bit_exact(tiny):
     ref = _baseline(tiny, prompts)
     reg = obs.Registry()
     with obs.use_registry(reg):
-        eng = _engine(tiny, breaker_threshold=3)
+        # a fallback route exists, so "no fallback" below is the breaker
+        # holding under its threshold, not the route chain being empty
+        eng = _engine(tiny, breaker_threshold=3,
+                      fallback_kernel_mode="reference")
         monkey = ChaosMonkey(ChaosConfig(
             kernel_failures=(KernelFault(tick=1, count=2),))).install(eng)
         rids = [eng.submit(p) for p in prompts]
@@ -231,6 +234,44 @@ def test_breaker_exhausted_aborts_with_error_outcomes(tiny):
     assert any(n.startswith("kernel_failure:decode") for n in names)
     eng.close()
     eng.close()  # idempotent
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_first_trace_failure_propagates_without_fallback(tiny, phase):
+    """An exception raised while a route first traces/compiles (a kernel
+    the compiler refuses) is a program fault: it is counted, then it
+    propagates out of Engine.run — even with a fallback route and a
+    breaker threshold of 1, nothing is served by another route."""
+    from repro.models.registry import ModelApi
+
+    api, cfg, params = tiny
+    mode = "train" if phase == "prefill" else "decode"
+
+    def apply(p, c, tokens, **kw):
+        if kw.get("mode") == mode:
+            raise RuntimeError("refused at lowering")
+        return api.apply(p, c, tokens, **kw)
+
+    reg = obs.Registry()
+    with obs.use_registry(reg):
+        eng = Engine(ModelApi(api.param_specs, api.cache_specs, apply),
+                     cfg, params,
+                     ServeConfig(max_slots=2, max_seq=64, prefill_len=8,
+                                 max_new_tokens=4,
+                                 kernel_mode="pallas_interpret",
+                                 fallback_kernel_mode="reference",
+                                 breaker_threshold=1))
+        rids = [eng.submit(p) for p in _prompts(3, seed=11)]
+        with pytest.raises(RuntimeError, match="refused at lowering"):
+            eng.run()
+        eng.close()
+    assert eng.fallbacks == 0 and eng.cfg.kernel_mode == "pallas_interpret"
+    assert all(eng.outcome(r) == "error" for r in rids)
+    assert not any(s.active for s in eng.slots)
+    c = reg.snapshot()["counters"]
+    assert c["engine_kernel_failures_total"][f'phase="{phase}"'] == 1
+    assert not any(c.get("engine_fallback_events_total", {}).values())
+    _conserved(reg, eng)
 
 
 def test_nan_streak_trips_breaker(tiny):
@@ -426,7 +467,8 @@ def test_mixed_fault_drill_conservation(tiny):
     one decode trace."""
     reg = obs.Registry()
     with obs.use_registry(reg):
-        eng = _engine(tiny, max_slots=2, max_queue=4, breaker_threshold=5)
+        eng = _engine(tiny, max_slots=2, max_queue=4, breaker_threshold=5,
+                      fallback_kernel_mode="reference")
         ChaosMonkey(ChaosConfig(
             nan_logits=(NanFault(tick=1, rid=0),),
             kernel_failures=(KernelFault(tick=3, count=1),))).install(eng)

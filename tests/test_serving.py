@@ -85,3 +85,50 @@ def test_engine_quantized_serving(tiny):
     for v in outs.values():
         assert len(v) == 5
         assert all(0 <= t < 64 for t in v)
+
+
+_SERVE_SMOKE = ["--arch", "mixtral-8x7b", "--smoke", "--num-layers", "1",
+                "--kernel-mode", "reference", "--requests", "3",
+                "--slots", "2", "--prefill-len", "8", "--max-seq", "32",
+                "--max-new", "3"]
+
+
+def test_serve_entry_point_quantizes_and_serves(capsys):
+    """``repro.launch.serve`` as ``chip_smoke.py`` drives it: parse_args +
+    run, depth cut reported, every request ok on one decode trace."""
+    from repro import obs
+    from repro.launch import serve
+
+    with obs.use_registry(obs.Registry()):
+        res = serve.run(serve.parse_args(_SERVE_SMOKE))
+    out = capsys.readouterr().out
+    assert "reduced: num_layers 2->1" in out
+    assert res.cfg.num_layers == 1 and res.cfg.kernel_mode == "reference"
+    assert sorted(res.engine.outcomes.values()) == ["ok"] * 3
+    assert res.engine.decode_traces == 1 and res.engine.fallbacks == 0
+    assert res.tokens == 9 and "qvalue" in str(jax.tree.structure(res.params))
+
+
+def test_serve_fails_on_nan_outcome_without_chaos(monkeypatch):
+    """A request ending ``nan`` with no chaos drill armed fails the run
+    (non-zero exit from the CLI) instead of reporting a healthy serve."""
+    from repro import obs
+    from repro.launch import serve
+
+    class PoisonedEngine(Engine):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+
+            def wrap(fn):
+                def decode(params, tokens, cache, pos):
+                    logits, cache = fn(params, tokens, cache, pos)
+                    return logits.at[0].set(jnp.nan), cache
+                return decode
+
+            self.add_decode_wrapper(wrap)
+
+    monkeypatch.setattr(serve, "Engine", PoisonedEngine)
+    with obs.use_registry(obs.Registry()):
+        with pytest.raises(serve.ServeFailed, match="ended error/nan"):
+            serve.run(serve.parse_args(
+                _SERVE_SMOKE + ["--breaker-threshold", "100"]))
