@@ -42,20 +42,17 @@ Telemetry (repro.obs)
 Every wrapper call increments ``qgemm_calls_total{scheme,kind,shape,
 block}`` on the current registry. These are host/python-side counts: in
 eager code they count executions; inside jit they count TRACES (a useful
-retrace detector — steady-state serving holds them constant). Ragged
-grouped calls with CONCRETE ``row_counts`` additionally record executed-
-vs-total m-tiles (``qgemm_ragged_m_tiles_total{kind}``); traced counts
-are skipped here and accounted at execution time by the serving engine's
-routing sink instead. Per the repro.obs rule, nothing below reads or
-writes metrics from inside a kernel body.
+retrace detector — steady-state serving holds them constant). Executed
+vs total m-tiles of the ragged grouped calls are counted by the serving
+engine from the routed counts each step outputs
+(``engine_moe_m_tiles_total``). Per the repro.obs rule, nothing below
+reads or writes metrics from inside a kernel body.
 """
 from __future__ import annotations
 
 import dataclasses
 
 import jax
-import numpy as np
-
 import jax.numpy as jnp
 
 from repro import obs
@@ -64,7 +61,7 @@ from repro.core.recipe import QuantSpec
 from .act_quant import act_quant
 from .moe_gemm import (fg_grouped_gemm_float_scale_ragged,
                        fg_grouped_gemm_integer_scale_ragged,
-                       grouped_w4a16_gemm_ragged, ragged_tile_stats)
+                       grouped_w4a16_gemm_ragged)
 from .w4a8_gemm import fg_gemm_integer_scale
 from .w4a8_gemm_fscale import fg_gemm_float_scale
 from .w4a16_gemm import w4a16_gemm
@@ -145,36 +142,15 @@ def _scheme_of(qspec: QuantSpec) -> str:
     return f"w{qspec.w_bits}a{qspec.a_bits}-{s}"
 
 
-def _concrete(x):
-    """np array when x is host-concrete, None when traced."""
-    try:
-        return np.asarray(x)
-    except Exception:  # TracerArrayConversionError and friends
-        return None
-
-
-def _record_call(scheme: str, kind: str, shape: tuple, blk: BlockConfig,
-                 *, row_counts=None, capacity: int | None = None) -> None:
-    reg = obs.current_registry()
-    reg.counter(
+def _record_call(scheme: str, kind: str, shape: tuple,
+                 blk: BlockConfig) -> None:
+    obs.current_registry().counter(
         "qgemm_calls_total",
         "kernels.ops wrapper calls (trace-time under jit)",
         ("scheme", "kind", "shape", "block"),
     ).inc(scheme=scheme, kind=kind,
           shape="x".join(str(d) for d in shape),
           block=f"{blk.bm}x{blk.bn}x{blk.bk}")
-    if row_counts is None:
-        return
-    rc = _concrete(row_counts)
-    if rc is None:
-        return  # traced: the engine routing sink accounts these
-    st = ragged_tile_stats([int(v) for v in rc], int(capacity), blk.bm)
-    tiles = reg.counter(
-        "qgemm_ragged_m_tiles_total",
-        "host-visible ragged grouped m-tiles: executed vs dense total",
-        ("kind",))
-    tiles.inc(st["ragged_m_tiles"], kind="executed")
-    tiles.inc(st["dense_m_tiles"], kind="total")
 
 
 def qgemm(
@@ -249,8 +225,7 @@ def qgemm_grouped(
     blk = _as_block(block)
     kw = blk.kernel_kwargs()
     N = params["qvalue"].shape[-1]
-    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), blk,
-                 row_counts=row_counts, capacity=x.shape[1])
+    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), blk)
 
     if qspec.weight_only:
         if qspec.w_bits != 4:

@@ -105,19 +105,17 @@ _int8_transport.defvjp(_int8_transport_fwd, _int8_transport_bwd)
 
 
 # -- routing sinks ----------------------------------------------------------
-# Observability hook for the serving engine + benchmarks: while any sink is
-# registered, moe_apply stages a jax.debug.callback that delivers the
-# per-expert routed (capacity-clipped) counts of every MoE layer invocation
-# as {"counts": np (G, E), "capacity": int} records, so per-tick executed-
-# m-tile accounting can be derived from the LIVE engine dispatch. The
-# callback is STAGED at trace time — register a sink BEFORE the first
-# (re)compile of the function you want observed; when no sink is active at
-# trace time, compiled code carries no callback at all (zero overhead).
-# Dispatch to sinks happens at execution time, so sinks added after the
-# trace (while at least one was active) still receive records. Sinks may be
-# plain callables or weakref-wrapped methods (``weakref.WeakMethod``) —
-# dead weakrefs are pruned on delivery, letting the serving engine hook in
-# without keeping itself alive.
+# Observability hook for the serving engine + benchmarks. The routed
+# (capacity-clipped) per-expert counts of every MoE layer leave the
+# compiled step as an OUTPUT (``moe_apply``'s third result, stacked per
+# layer by ``transformer.apply(..., routing=True)``); compiled code never
+# carries a host callback. The serving engine fetches them with the data
+# it already syncs after each step and hands them to every registered sink
+# as {"counts": np (G, E), "capacity": int} records, one per MoE layer in
+# layer order (:func:`publish_routing`). A sink registered at any time
+# receives every later step's records. Sinks may be plain callables or
+# weakref-wrapped methods (``weakref.WeakMethod``) — dead weakrefs are
+# pruned on delivery.
 
 _ROUTING_SINKS: list = []
 
@@ -130,10 +128,6 @@ def add_routing_sink(sink) -> None:
 def remove_routing_sink(sink) -> None:
     if sink in _ROUTING_SINKS:
         _ROUTING_SINKS.remove(sink)
-
-
-def routing_sinks_active() -> bool:
-    return bool(_ROUTING_SINKS)
 
 
 def start_routing_trace() -> list:
@@ -164,22 +158,27 @@ def stop_routing_trace(records: list | None = None) -> list:
     return out
 
 
-def _record_routing(counts, *, capacity: int) -> None:
-    """Host-side callback target: fan one record out to every live sink."""
+def routing_records(counts, cfg: ModelConfig, tokens: int) -> list[dict]:
+    """One step's routed counts ``(L, G, E)`` (the MoE layers of a forward
+    over ``tokens`` tokens, in layer order) as per-layer sink records."""
+    C = _capacity(cfg, tokens // counts.shape[1])
+    return [{"counts": c, "capacity": C} for c in counts]
+
+
+def publish_routing(records: list[dict]) -> None:
+    """Fan routing records out to every live sink."""
     import weakref
 
-    import numpy as np
-
-    rec = {"counts": np.asarray(counts), "capacity": capacity}
-    for s in list(_ROUTING_SINKS):
-        if isinstance(s, weakref.ref):
-            live = s()
-            if live is None:
-                remove_routing_sink(s)
-                continue
-            live(rec)
-        else:
-            s(rec)
+    for rec in records:
+        for s in list(_ROUTING_SINKS):
+            if isinstance(s, weakref.ref):
+                live = s()
+                if live is None:
+                    remove_routing_sink(s)
+                    continue
+                live(rec)
+            else:
+                s(rec)
 
 
 def moe_specs(cfg: ModelConfig, recipe, base: str) -> dict:
@@ -217,7 +216,10 @@ def _capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
 
 def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, recipe,
               base: str):
-    """x: (B, S, d). Returns (y, aux_loss)."""
+    """x: (B, S, d). Returns (y, aux_loss, counts): ``counts`` int32
+    (G, E) is the routed (capacity-clipped) rows per expert of each
+    dispatch group — what the ragged kernel skips by, and what the
+    serving engine's m-tile accounting reads from the step's outputs."""
     B, Sq, d = x.shape
     E, k = cfg.num_experts, cfg.top_k
     G = max(1, cfg.dispatch_groups)
@@ -278,12 +280,6 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, recipe,
     # single per-expert count — fall back to the dense (exact) behavior.
     row_counts = counts[0] if G == 1 else None
 
-    if _ROUTING_SINKS:
-        import functools
-
-        jax.debug.callback(
-            functools.partial(_record_routing, capacity=C), counts)
-
     km = cfg.kernel_mode
 
     def expert_ffn(b):  # b: (G, E, C, d) -> (G, E, C, d)
@@ -314,4 +310,4 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, recipe,
 
     if cfg.num_shared_experts:
         y = y + mlp_apply(params["shared"], x, cfg, recipe, f"{base}/shared")
-    return y.astype(x.dtype), aux
+    return y.astype(x.dtype), aux, counts

@@ -91,6 +91,7 @@ def _block_cache_specs(cfg: ModelConfig, kind: str, batch: int,
 
 def _block_apply(params, x, cfg: ModelConfig, recipe, kind: str, base: str,
                  *, mode, cache, pos, memory):
+    """-> (x, cache, aux, routed counts (G, E) of a MoE block | None)."""
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     if kind == "cross":
@@ -101,7 +102,7 @@ def _block_apply(params, x, cfg: ModelConfig, recipe, kind: str, base: str,
         h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
         h2 = mlp_apply(params["mlp"], h2, cfg, recipe, f"{base}/mlp")
         x = x + jnp.tanh(params["gate_mlp"]).astype(x.dtype) * h2
-        return x, cache, aux
+        return x, cache, aux, None
     if cfg.attention == "mla":
         h, cache = A.mla_apply(params["attn"], h, cfg, recipe,
                                f"{base}/attn", mode=mode, cache=cache,
@@ -112,12 +113,14 @@ def _block_apply(params, x, cfg: ModelConfig, recipe, kind: str, base: str,
                                pos=pos)
     x = x + h
     h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
+    counts = None
     if kind == "moe":
-        h2, aux = moe_apply(params["mlp"], h2, cfg, recipe, f"{base}/mlp")
+        h2, aux, counts = moe_apply(params["mlp"], h2, cfg, recipe,
+                                    f"{base}/mlp")
     else:
         h2 = mlp_apply(params["mlp"], h2, cfg, recipe, f"{base}/mlp")
     x = x + h2
-    return x, cache, aux
+    return x, cache, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -177,8 +180,15 @@ def apply(
     cache: dict | None = None,
     pos=0,
     memory: jax.Array | None = None,  # (B, Sm, d) image/frame embeddings
+    routing: bool = False,
 ):
     """Returns (logits f32 (B, S, V), new_cache, aux_loss).
+
+    With ``routing=True`` a fourth result follows: the routed
+    (capacity-clipped) per-expert counts of every MoE layer, int32
+    ``(L_moe, G, E)`` in layer order (None without MoE layers). They
+    leave the step as an output so that compiled code needs no host
+    callback to observe the live dispatch (``models.moe`` routing sinks).
 
     ``cfg.kernel_mode`` (when set) selects the qlinear backend for every
     quantized linear in the forward. It is established here, inside the
@@ -188,10 +198,12 @@ def apply(
         from repro.core import qlinear
 
         with qlinear.kernel_mode(cfg.kernel_mode):
-            return _apply(params, cfg, tokens, recipe=recipe, mode=mode,
-                          cache=cache, pos=pos, memory=memory)
-    return _apply(params, cfg, tokens, recipe=recipe, mode=mode,
-                  cache=cache, pos=pos, memory=memory)
+            out = _apply(params, cfg, tokens, recipe=recipe, mode=mode,
+                         cache=cache, pos=pos, memory=memory)
+    else:
+        out = _apply(params, cfg, tokens, recipe=recipe, mode=mode,
+                     cache=cache, pos=pos, memory=memory)
+    return out if routing else out[:3]
 
 
 def _apply(
@@ -209,16 +221,19 @@ def _apply(
     x = params["embed"].astype(cfg.activation_dtype)[tokens]
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {} if cache is not None else None
+    routed: list = []  # (n, G, E) routed counts, in layer order
 
     if prefix:
         if cache is not None:
             new_cache["prefix"] = {}
         for i, kind in enumerate(prefix):
             c = cache["prefix"][str(i)] if cache is not None else None
-            x, c, a = _block_apply(
+            x, c, a, n = _block_apply(
                 params["prefix"][str(i)], x, cfg, recipe, kind,
                 f"prefix/{i}", mode=mode, cache=c, pos=pos, memory=memory)
             aux = aux + a
+            if n is not None:
+                routed.append(n[None])
             if cache is not None:
                 new_cache["prefix"][str(i)] = c
 
@@ -229,16 +244,20 @@ def _apply(
                 p_l, c_l = inp
             else:
                 p_l, c_l = inp, None
-            c_out = {}
+            c_out, n_out = {}, []
             for j, kind in enumerate(pattern):
                 cj = c_l[f"s{j}"] if c_l is not None else None
-                xc, cj, a = _block_apply(
+                xc, cj, a, n = _block_apply(
                     p_l[f"s{j}"], xc, cfg, recipe, kind, f"blocks/s{j}",
                     mode=mode, cache=cj, pos=pos, memory=memory)
                 auxc = auxc + a
+                if n is not None:
+                    n_out.append(n)
                 if cache is not None:
                     c_out[f"s{j}"] = cj
-            return (xc, auxc), (c_out if cache is not None else None)
+            # one repeat's MoE counts (n, G, E), in pattern order
+            n_out = jnp.stack(n_out) if n_out else None
+            return (xc, auxc), (c_out if cache is not None else None, n_out)
 
         if not cfg.scan_layers:
             # unrolled python loop — required for eager calibration capture
@@ -248,14 +267,19 @@ def _apply(
                 p_r = take_layer(params["blocks"], r)
                 c_r = take_layer(cache["blocks"], r) if cache is not None \
                     else None
-                (x, aux), _ = body((x, aux), (p_r, c_r)
-                                   if cache is not None else p_r)
+                (x, aux), (_, n_r) = body((x, aux), (p_r, c_r)
+                                          if cache is not None else p_r)
+                if n_r is not None:
+                    routed.append(n_r)
         else:
             if cfg.remat and mode == "train":
                 body = jax.checkpoint(body, prevent_cse=False)
             xs = (params["blocks"], cache["blocks"]) if cache is not None \
                 else params["blocks"]
-            (x, aux), scanned_cache = jax.lax.scan(body, (x, aux), xs)
+            (x, aux), (scanned_cache, n_s) = jax.lax.scan(body, (x, aux),
+                                                          xs)
+            if n_s is not None:  # (R, n, G, E) -> (R * n, G, E)
+                routed.append(n_s.reshape(-1, *n_s.shape[2:]))
             if cache is not None:
                 new_cache["blocks"] = scanned_cache
 
@@ -273,4 +297,5 @@ def _apply(
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = c * jnp.tanh(logits / c)
-    return logits, new_cache, aux
+    routed = jnp.concatenate(routed) if routed else None
+    return logits, new_cache, aux, routed

@@ -10,7 +10,9 @@ engine, the kernel dispatch wrappers, and the quantization pipeline:
   override via :func:`use_registry`).
 * :class:`Span` / :func:`span` — host-side wall-clock tick tracing that
   lands in a histogram + the event log (stamped with registry-clock
-  start times, so spans double as timeline slices).
+  start times, so spans double as timeline slices) and, when jax is
+  loaded, in a ``jax.profiler.TraceAnnotation`` on the profiler's clock
+  (one span system: there is no second tracer).
 * :mod:`repro.obs.timeline` — Perfetto/chrome://tracing export of the
   event log: engine-phase lane, per-request-slot lifecycle lanes
   (queued -> prefill -> decode ticks -> retire, TTFT/TPOT markers), and
@@ -35,11 +37,21 @@ engine, the kernel dispatch wrappers, and the quantization pipeline:
 What is instrumented where
 --------------------------
 * ``serving/engine.py``: per-tick admit/prefill/decode/retire spans
-  (``engine_phase_seconds``), tick/token/request counters, slot-occupancy
-  and queue-depth gauges, per-request TTFT/TPOT histograms, jit retrace
-  events (``engine_traces_total``), and per-tick executed-vs-total MoE
-  m-tile counters (``engine_moe_m_tiles_total``) fed by the routing sink
-  in ``models/moe.py``. Fault tolerance (PR 10):
+  (``engine_phase_seconds``; profiler annotations ``engine.<phase>``),
+  the host sections between the jitted programs
+  (``engine_host_seconds{part}``: ``admit.inputs``, ``admit.splice``,
+  ``admit.first_token``, ``admit.routing``, ``decode.inputs``,
+  ``decode.logits_to_host``, ``decode.sample``, ``decode.routing``;
+  annotations ``engine.<part>``), one ``StepTraceAnnotation``
+  ``engine.tick`` per loop iteration, device time per jitted call
+  (``engine_phase_device_seconds``), each request's queue wait from
+  submit to admission (``engine_queue_wait_seconds``),
+  tick/token/request counters, a queue-depth gauge, per-request
+  TTFT/TPOT histograms, jit retrace events (``engine_traces_total``),
+  and executed-vs-total MoE m-tile counters
+  (``engine_moe_m_tiles_total``) from the routed counts that the jitted
+  steps return; the same counts reach the ``models/moe.py`` routing
+  sinks. Fault tolerance (PR 10):
   ``engine_request_outcomes_total{outcome}`` counts every request's
   terminal outcome (``ok|timeout|cancelled|rejected|nan|error``; all
   series zero-seeded, so the conservation law — outcomes sum to
@@ -51,8 +63,7 @@ What is instrumented where
   stragglers. ``repro.serving.chaos`` injects all of the above
   deterministically.
 * ``kernels/ops.py``: ``qgemm_calls_total{scheme,kind,shape,block}`` per
-  wrapper call, plus host-side ragged executed/total m-tile accounting
-  (``qgemm_ragged_m_tiles_total``) whenever ``row_counts`` is concrete.
+  wrapper call (trace-time counts under jit).
 * ``core/qlinear.py`` / ``core/ptq.py`` / ``core/integer_scale.py`` /
   ``analysis/certify.py``: quantization health — ``alpha_cap_events_total``,
   ``qcert_verdicts_total{verdict}``, ``amax_floor_hits_total{where}``,
@@ -62,24 +73,25 @@ What is instrumented where
   documents (``benchmarks/run.py`` / ``benchmarks/serving_moe.py`` attach
   a registry snapshot + host provenance).
 
-THE RULE: no metrics inside jitted bodies
------------------------------------------
+THE RULE: no metrics and no host callbacks in compiled code
+-----------------------------------------------------------
 Never read or write a metric from code that executes inside a traced /
-jitted computation. A python-side increment inside a traced function runs
-at TRACE time (once per compilation, not once per step) and anything
-fancier would either retrace or insert host syncs into the hot path.
+jitted computation, and never stage a host callback
+(``jax.debug.callback``, ``io_callback``, ``pure_callback``) into one. A
+python-side increment inside a traced function runs at TRACE time (once
+per compilation, not once per step); a callback runs on every step,
+whether or not anyone reads it, and stalls the device each time.
 Instrument at these boundaries only:
 
-* host code around a jitted call (engine tick phases, wrapper entry
-  points — note wrapper counts are *trace-time* counts under jit, which
-  is exactly what makes them a retrace detector);
-* ``jax.debug.callback`` hooks staged at trace boundaries (the MoE
-  routing sink) whose callbacks run host-side at execution time;
+* host code around a jitted call (engine tick phases and host sections,
+  wrapper entry points — note wrapper counts are *trace-time* counts
+  under jit, which is exactly what makes them a retrace detector);
 * offline paths that are eager by construction (PTQ, certification).
 
-Data-dependent values (e.g. ragged ``row_counts``) may only be recorded
-when they are concrete — guard with a ``np.asarray`` try/except and skip
-silently when traced.
+Data-dependent counts (e.g. the MoE layers' routed ``counts``) leave the
+step as OUTPUTS of the jitted function; the host fetches them together
+with data it syncs anyway (the engine: with the sampled tokens) and
+records them there.
 
 How to add a new counter
 ------------------------

@@ -13,6 +13,7 @@ through every layer.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import threading
@@ -25,8 +26,8 @@ DEFAULT_LATENCY_BUCKETS = (
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
 )
 
-#: Events kept in memory before older ones are dropped (dropped count is
-#: tracked in the ``obs_events_dropped_total`` counter).
+#: Events kept in memory before the oldest are dropped (the count dropped
+#: is the snapshot's ``events_dropped``).
 MAX_EVENTS = 200_000
 
 
@@ -216,7 +217,8 @@ class Registry:
 
     def __init__(self, clock=None):
         self._metrics: dict[str, _Metric] = {}
-        self._events: list[dict] = []
+        self._events: collections.deque = collections.deque(
+            maxlen=MAX_EVENTS)
         self._seq = 0
         self._dropped = 0
         self._lock = threading.Lock()
@@ -264,10 +266,9 @@ class Registry:
         with self._lock:
             self._seq += 1
             ev = {"seq": self._seq, "ts": ts, **event}
+            if len(self._events) == self._events.maxlen:
+                self._dropped += 1  # the append evicts the oldest
             self._events.append(ev)
-            if len(self._events) > MAX_EVENTS:
-                del self._events[: len(self._events) - MAX_EVENTS]
-                self._dropped += 1
 
     def events(self) -> list[dict]:
         with self._lock:

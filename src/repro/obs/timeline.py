@@ -21,7 +21,7 @@ into a ``traceEvents`` JSON document loadable in https://ui.perfetto.dev
   ``kernel_failure:<phase>``, ``fallback:<reason>``, ``slow_tick``, and
   ``engine abort:<reason>`` instants.
 * **counter tracks** (pid 1): ``moe_m_tiles`` (cumulative executed vs
-  dense-total grouped-GEMM m-tiles from the live routing sink) and
+  dense-total grouped-GEMM m-tiles from the live routing) and
   ``qgemm_calls`` (trace-time wrapper calls — flat in steady state, a
   visible staircase on retraces), sampled at each tick boundary from the
   engine's ``counters`` events.

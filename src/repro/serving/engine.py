@@ -85,23 +85,34 @@ TPOT (mean inter-token time) histograms and emits ``submit``/``admit``/
 prefill/decode callables are wrapped in ``obs.device_timer`` —
 block_until_ready-bracketed, first (compile) call excluded — populating
 ``engine_phase_device_seconds{phase}`` so host overhead vs device compute
-is separable per phase. After each tick a ``counters`` event samples
+is separable per phase. The host sections between the jitted programs
+are timed into ``engine_host_seconds{part}`` (``admit.inputs``,
+``admit.splice``, ``admit.first_token``, ``admit.routing``,
+``decode.inputs``, ``decode.logits_to_host``, ``decode.sample``,
+``decode.routing``; retire is the ``retire`` phase span), and each
+request's wait from ``submit`` to the start of its admission into
+``engine_queue_wait_seconds``. Every span is also a
+``jax.profiler.TraceAnnotation`` (``engine.<phase>``,
+``engine.<part>``) and each loop iteration a ``StepTraceAnnotation``
+(``engine.tick``), so a profiler trace places them beside the device's
+ops. After each tick a ``counters`` event samples
 cumulative m-tile/qgemm counters for the timeline's counter tracks. Jit
 retraces bump ``engine_traces_total{fn}`` and emit a ``trace`` event (the
 per-engine ``prefill_traces``/``decode_traces`` properties keep their
 exact PR-2 semantics — steady-state serving must hold decode at ONE trace
-per established kernel route, asserted in tests). MoE routing records
-delivered by the ``models.moe`` sink are folded into
-``engine_moe_m_tiles_total{kind=executed|total}`` so ragged skipping is
-continuously observable from the LIVE dispatch. All of it is host-side at
-trace/tick boundaries — nothing records from inside the jitted bodies
-(see ``repro.obs``).
+per established kernel route, asserted in tests). A MoE model's jitted
+prefill and decode return each MoE layer's routed counts beside the
+logits; the engine fetches them with the data it syncs anyway, folds
+them into ``engine_moe_m_tiles_total{kind=executed|total}`` (ragged
+skipping observable from the LIVE dispatch) and hands them to the
+``models.moe`` routing sinks. All of it is host-side at trace/tick
+boundaries — nothing records from inside the jitted bodies and no host
+callback is compiled in (see ``repro.obs``).
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
-import weakref
 from typing import Any
 
 import jax
@@ -223,13 +234,9 @@ class Engine:
             HeartbeatConfig(straggler_factor=serve_cfg.slow_tick_factor),
             on_straggler=self._on_slow_tick,
             clock=lambda: obs.current_registry().now())
-        # MoE routing sink: a WeakMethod, because the jitted closures below
-        # capture ``self`` into reference cycles that delay __del__ — a
-        # strong sink would pin retired engines alive in the global list.
-        # Installed BEFORE the first trace so the callback gets staged.
+        # MoE routing records of the steps since the last drain
         self._routing_buf: list[dict] = []
-        self._routing_sink = weakref.WeakMethod(self._on_routing)
-        moe.add_routing_sink(self._routing_sink)
+        self._decode_routed = None  # routed counts of the last decode call
 
         self._build_jit_fns()
         self._cache1_specs = api.cache_specs(cfg, 1, serve_cfg.max_seq)
@@ -272,6 +279,16 @@ class Engine:
         (each re-establishment is one intentional extra trace)."""
         recipe = self.recipe
 
+        def forward(params, tokens, **kw):
+            """-> (logits, cache, routed counts (L, G, E) | None)."""
+            if not self.cfg.num_experts:
+                logits, cache, _ = self.api.apply(
+                    params, self.cfg, tokens, recipe=recipe, **kw)
+                return logits, cache, None
+            logits, cache, _, routed = self.api.apply(
+                params, self.cfg, tokens, recipe=recipe, routing=True, **kw)
+            return logits, cache, routed
+
         # jit'd single-request prefill (batch 1, fixed length).
         # mode="train" + cache: returns FULL-sequence logits (the engine
         # needs the logit at the true prompt end, which may be before the
@@ -279,10 +296,8 @@ class Engine:
         # keeps its last-token-only slicing for the serving dry-run.
         def prefill_fn(params, tokens, cache1):
             self._note_trace("prefill")
-            logits, cache1, _ = self.api.apply(
-                params, self.cfg, tokens, recipe=recipe, mode="train",
-                cache=cache1, pos=0)
-            return logits, cache1
+            return forward(params, tokens, mode="train", cache=cache1,
+                           pos=0)
 
         # device_timer wraps OUTSIDE jit (args pass through verbatim, so
         # the jit cache — and the one-decode-trace invariant — is
@@ -296,15 +311,24 @@ class Engine:
         # jit'd batched decode with per-slot positions
         def decode_fn(params, tokens, cache, pos_vec):
             self._note_trace("decode")
-            logits, cache, _ = self.api.apply(
-                params, self.cfg, tokens, recipe=recipe, mode="decode",
-                cache=cache, pos=pos_vec)
-            return logits[:, 0], cache
+            logits, cache, routed = forward(params, tokens, mode="decode",
+                                            cache=cache, pos=pos_vec)
+            return logits[:, 0], cache, routed
 
-        self._decode_base = obs.device_timer(
+        timed_decode = obs.device_timer(
             jax.jit(decode_fn), "engine_phase_device_seconds",
             help="device time (block_until_ready) per engine phase",
             phase="decode")
+
+        def decode(params, tokens, cache, pos_vec):
+            # decode wrappers see (logits, cache); the routed counts go
+            # to the tick beside them
+            logits, cache, self._decode_routed = timed_decode(
+                params, tokens, cache, pos_vec)
+            return logits, cache
+
+        decode.__wrapped__ = timed_decode
+        self._decode_base = decode
         self._rewrap_decode()
 
     def _rewrap_decode(self) -> None:
@@ -349,8 +373,20 @@ class Engine:
         ``decode_traces == 1 + fallbacks``."""
         return self._fallbacks
 
-    def _on_routing(self, rec: dict) -> None:
-        self._routing_buf.append(rec)
+    def _host(self, reg, part: str):
+        """Span of one host section between the jitted programs."""
+        return obs.span(reg, "engine_host_seconds", part=part,
+                        help="engine host work between jitted programs")
+
+    def _publish_routing(self, routed, tokens: int) -> None:
+        """Queue one step's routed counts (np (L, G, E), over ``tokens``
+        tokens) for the m-tile counters and hand them to every
+        ``models.moe`` routing sink. Nothing after :meth:`close`."""
+        if routed is None or self._closed:
+            return
+        recs = moe.routing_records(routed, self.cfg, tokens)
+        self._routing_buf.extend(recs)
+        moe.publish_routing(recs)
 
     def _on_slow_tick(self, step: int, dt: float, med: float) -> None:
         reg = obs.current_registry()
@@ -359,11 +395,11 @@ class Engine:
                   "seconds": round(dt, 6), "median_s": round(med, 6)})
 
     def _drain_routing(self) -> None:
-        """Fold buffered MoE routing records (delivered host-side by
-        jax.debug.callback during the forced computation) into the
-        executed-vs-total m-tile counters. Ragged skipping only applies on
-        the Pallas paths with a single dispatch group (G == 1) — other
-        configurations execute densely."""
+        """Fold buffered MoE routing records (the routed counts the
+        jitted steps returned) into the executed-vs-total m-tile
+        counters. Ragged skipping only applies on the Pallas paths with a
+        single dispatch group (G == 1) — other configurations execute
+        densely."""
         if not self._routing_buf:
             return
         from repro.kernels.moe_gemm import ragged_tile_stats
@@ -400,13 +436,9 @@ class Engine:
                   "qgemm_calls": calls.total()})
 
     def close(self) -> None:
-        """Detach the routing sink (tests / explicit lifecycle).
-        Idempotent; safe to skip entirely — the WeakMethod is pruned
-        automatically once the engine dies."""
-        if self._closed:
-            return
+        """Stop delivering MoE routing records (to the m-tile counters
+        and the ``models.moe`` sinks). Idempotent; safe to skip."""
         self._closed = True
-        moe.remove_routing_sink(self._routing_sink)
 
     def trace_id(self, rid: int) -> str:
         """The per-request trace/span id threaded through lifecycle
@@ -477,11 +509,13 @@ class Engine:
         try:
             while (self.queue or any(s.active for s in self.slots)) \
                     and self._steps < max_ticks:
-                self._expire_queued()
-                with obs.span(reg, "engine_phase_seconds", phase="admit",
-                              event="phase"):
-                    self._admit()
-                self._tick()
+                with jax.profiler.StepTraceAnnotation(
+                        "engine.tick", step_num=self._steps):
+                    self._expire_queued()
+                    with obs.span(reg, "engine_phase_seconds",
+                                  phase="admit", event="phase"):
+                        self._admit()
+                    self._tick()
         except Exception:
             # a crashed run leaves no slot marked active and every
             # in-flight request with a terminal outcome — the driver's
@@ -625,21 +659,29 @@ class Engine:
             if not self.queue:
                 break
             rid, prompt = self.queue.pop(0)
+            t_sub = self._submit_t.get(rid)
+            if t_sub is not None:
+                reg.histogram(
+                    "engine_queue_wait_seconds",
+                    "submit -> start of the request's admission",
+                ).observe(reg.now() - t_sub)
             poisoned = False
             traces = self._trace_counts["prefill"]
             try:
                 with obs.span(reg, "engine_phase_seconds", phase="prefill",
                               event="admit") as sp:
                     P = self.sc.prefill_len
-                    # over-length prompts were rejected at submit unless
-                    # truncate_prompts explicitly opted into this clip
-                    toks = (prompt[:P] + [0] * max(0, P - len(prompt)))
-                    true_len = min(len(prompt), P)
-                    cache1 = jax.tree.map(
-                        lambda s: jnp.zeros(s.shape, s.dtype),
-                        self._cache1_specs, is_leaf=S.is_spec)
-                    logits, cache1 = self._prefill(
-                        self.params, jnp.asarray([toks], jnp.int32), cache1)
+                    with self._host(reg, "admit.inputs"):
+                        # over-length prompts were rejected at submit
+                        # unless truncate_prompts opted into this clip
+                        toks = (prompt[:P] + [0] * max(0, P - len(prompt)))
+                        true_len = min(len(prompt), P)
+                        cache1 = jax.tree.map(
+                            lambda s: jnp.zeros(s.shape, s.dtype),
+                            self._cache1_specs, is_leaf=S.is_spec)
+                        tokens = jnp.asarray([toks], jnp.int32)
+                    logits, cache1, routed = self._prefill(
+                        self.params, tokens, cache1)
 
                     # splice the prefilled slot into the batched cache
                     # along each leaf's batch axis (scanned leaves lead
@@ -648,22 +690,31 @@ class Engine:
                         idx = tuple([slice(None)] * ax + [i])
                         return C.at[idx].set(jnp.take(c, 0, axis=ax))
 
-                    self.cache = jax.tree.map(splice, self.cache, cache1,
-                                              self._batch_axes)
-                    first_row = logits[:, true_len - 1]
-                    if self.sc.nan_guard and \
-                            not np.isfinite(np.asarray(first_row)).all():
-                        poisoned = True
+                    with self._host(reg, "admit.splice"):
+                        self.cache = jax.tree.map(
+                            splice, self.cache, cache1, self._batch_axes)
+                    with self._host(reg, "admit.first_token"):
+                        first_row = logits[:, true_len - 1]
+                        # the routed counts come over with the row
+                        row, routed = jax.device_get(
+                            (first_row if self.sc.nan_guard else None,
+                             routed))
+                        poisoned = row is not None \
+                            and not np.isfinite(row).all()
+                        if not poisoned:
+                            # token 0 must honor the sampling settings
+                            # too — greedy argmax here ignored
+                            # temperature/top_k for the first token
+                            self._key, k = jax.random.split(self._key)
+                            first = int(np.asarray(sampler.sample(
+                                first_row, k,
+                                temperature=self.sc.temperature,
+                                top_k=self.sc.top_k))[0])
+                    with self._host(reg, "admit.routing"):
+                        self._publish_routing(routed, P)
+                    if poisoned:
                         sp.fields.update(rid=rid, slot=i, outcome="nan")
                     else:
-                        # token 0 must honor the sampling settings too —
-                        # greedy argmax here ignored temperature/top_k for
-                        # the first token
-                        self._key, k = jax.random.split(self._key)
-                        first = int(np.asarray(sampler.sample(
-                            first_row, k,
-                            temperature=self.sc.temperature,
-                            top_k=self.sc.top_k))[0])
                         t_first = reg.now()
                         self.slots[i] = _Slot(request_id=rid,
                                               length=true_len,
@@ -672,7 +723,6 @@ class Engine:
                         sp.fields.update(rid=rid, slot=i,
                                          prompt_len=true_len,
                                          trace_id=self.trace_id(rid))
-                        t_sub = self._submit_t.get(rid)
                         if t_sub is not None:
                             ttft = t_first - t_sub
                             reg.histogram(
@@ -716,19 +766,26 @@ class Engine:
             with obs.span(reg, "engine_phase_seconds", phase="decode",
                           event="tick") as sp:
                 self._watchdog.start()
+                with self._host(reg, "decode.inputs"):
+                    tokens, positions = jnp.asarray(last), jnp.asarray(pos)
+                self._decode_routed = None
                 logits, new_cache = self._decode(
-                    self.params, jnp.asarray(last), self.cache,
-                    jnp.asarray(pos))
-                # host-side numeric guard (outside jit): one transfer of
-                # the (B, V) logits, reused for per-slot quarantine below
-                lg = np.asarray(logits) if self.sc.nan_guard else None
-                # the key splits AFTER decode succeeds, so failed attempts
-                # never advance the sampling stream (retries stay
-                # bit-exact vs a fault-free run)
-                self._key, k = jax.random.split(self._key)
-                nxt = np.asarray(sampler.sample(
-                    logits, k, temperature=self.sc.temperature,
-                    top_k=self.sc.top_k))  # forces the step (+ callbacks)
+                    self.params, tokens, self.cache, positions)
+                routed = self._decode_routed
+                with self._host(reg, "decode.logits_to_host"):
+                    # host-side numeric guard (outside jit): one transfer
+                    # of the (B, V) logits, reused for per-slot
+                    # quarantine below
+                    lg = np.asarray(logits) if self.sc.nan_guard else None
+                with self._host(reg, "decode.sample"):
+                    # the key splits AFTER decode succeeds, so failed
+                    # attempts never advance the sampling stream (retries
+                    # stay bit-exact vs a fault-free run)
+                    self._key, k = jax.random.split(self._key)
+                    # the routed counts come over with the tokens
+                    nxt, routed = jax.device_get((sampler.sample(
+                        logits, k, temperature=self.sc.temperature,
+                        top_k=self.sc.top_k), routed))
                 self._watchdog.stop(self._steps)
                 sp.fields.update(tick=self._steps, slots_active=active,
                                  queue_depth=len(self.queue),
@@ -753,7 +810,9 @@ class Engine:
         self._steps += 1
         reg.counter("engine_ticks_total", "").inc()
         reg.counter("engine_tokens_total", "").inc(active - len(bad))
-        self._drain_routing()
+        with self._host(reg, "decode.routing"):
+            self._publish_routing(routed, B)
+            self._drain_routing()
         self._sample_counters(reg)
         with obs.span(reg, "engine_phase_seconds", phase="retire",
                       event="phase"):
@@ -794,9 +853,6 @@ class Engine:
                                  output=s.generated,
                                  tokens=len(s.generated))
                     self.slots[i] = _Slot()
-        reg.gauge("engine_slots_active",
-                  "occupied decode slots after retire").set(
-                      sum(1 for s in self.slots if s.active))
         reg.gauge("engine_queue_depth", "requests waiting for a slot").set(
             len(self.queue))
         if self._nan_streak >= max(1, self.sc.breaker_threshold):

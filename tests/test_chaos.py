@@ -496,15 +496,42 @@ def test_mixed_fault_drill_conservation(tiny):
 # ---------------------------------------------------------------------------
 
 
-def test_teardown_removes_routing_sink_and_is_idempotent(tiny):
+def test_teardown_removes_routing_sink_and_is_idempotent():
+    """The engine registers no routing sink of its own; ``close()`` stops
+    it delivering routing records (to the sinks and to its m-tile
+    counters), and a second ``close()`` is a no-op."""
     from repro.models import moe
 
-    with obs.use_registry(obs.Registry()):
-        eng = _engine(tiny)
-        assert eng._routing_sink in moe._ROUTING_SINKS
-        eng.close()
-        assert eng._routing_sink not in moe._ROUTING_SINKS
-        eng.close()  # second close is a no-op, not an error
+    cfg = ModelConfig(name="t-moe", family="moe", num_layers=2, d_model=32,
+                      num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                      num_experts=4, top_k=2, moe_d_ff=32,
+                      capacity_factor=2.0, dtype="float32", q_chunk=16,
+                      kv_chunk=16, remat=False)
+    api = get_model(cfg)
+    params = S.materialize(api.param_specs(cfg, None), jax.random.PRNGKey(0))
+    reg = obs.Registry()
+    with obs.use_registry(reg):
+        eng = Engine(api, cfg, params,
+                     ServeConfig(max_slots=2, max_seq=32, prefill_len=8,
+                                 max_new_tokens=3))
+        records = moe.start_routing_trace()
+        try:
+            eng.submit(_prompts(1, seed=12)[0])
+            eng.run()
+            # one prefill and two decode ticks, one record per MoE layer
+            assert len(records) == 3 * cfg.num_layers
+            tiles = reg.counter("engine_moe_m_tiles_total", "", ("kind",))
+            total = tiles.get(kind="total")
+            assert total > 0
+            eng.close()
+            eng.submit(_prompts(1, seed=13)[0])
+            eng.run()
+            assert len(records) == 3 * cfg.num_layers
+            assert tiles.get(kind="total") == total
+            eng.close()  # second close is a no-op, not an error
+        finally:
+            moe.stop_routing_trace(records)
+    assert len(moe._ROUTING_SINKS) == 0
 
 
 def test_crashed_run_flushes_conserved_telemetry(tiny, tmp_path):

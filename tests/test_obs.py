@@ -238,6 +238,64 @@ class TestRegistryStackAndEvents:
         assert ev["ev"] == "tick" and ev["phase"] == "decode"
         assert ev["tick"] == 0 and "seconds" in ev
 
+    def test_full_event_log_drops_oldest(self, monkeypatch):
+        from repro.obs import metrics
+
+        monkeypatch.setattr(metrics, "MAX_EVENTS", 3)
+        reg = obs.Registry()
+        for i in range(5):
+            reg.emit({"ev": "e", "i": i})
+        assert [e["i"] for e in reg.events()] == [2, 3, 4]
+        snap = reg.snapshot()
+        assert snap["events_total"] == 5 and snap["events_dropped"] == 2
+
+    @pytest.mark.parametrize("metric,labels,name", [
+        ("engine_phase_seconds", {"phase": "decode"}, "engine.decode"),
+        ("engine_host_seconds", {"part": "admit.splice"},
+         "engine.admit.splice"),
+        ("ptq_run_seconds", {}, "ptq_run"),
+    ])
+    def test_span_profiler_annotation_name(self, metric, labels, name):
+        assert obs.Span(obs.Registry(), metric, **labels).name == name
+
+
+def test_engine_spans_on_profiler_host_plane(tmp_path):
+    """Under a CPU ``jax.profiler`` trace the engine's spans land on the
+    host plane (``engine.<phase>``, ``engine.<part>``, ``engine.tick``),
+    on the same clock as the device's events."""
+    import glob
+
+    from repro.models.config import ModelConfig
+    from repro.models.registry import get_model
+    from repro.nn import spec as S
+    from repro.serving.engine import Engine, ServeConfig
+
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=32,
+                      num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                      dtype="float32", q_chunk=16, kv_chunk=16, remat=False)
+    api = get_model(cfg)
+    params = S.materialize(api.param_specs(cfg, None), jax.random.PRNGKey(0))
+    with obs.use_registry(obs.Registry()):
+        eng = Engine(api, cfg, params,
+                     ServeConfig(max_slots=2, max_seq=32, prefill_len=8,
+                                 max_new_tokens=3))
+        eng.submit([1, 2, 3])
+        eng.run()  # compiles outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            eng.submit([4, 5, 6])
+            eng.run()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {ev.name for plane in data.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events}
+    assert {"engine.tick", "engine.admit", "engine.prefill",
+            "engine.decode", "engine.retire", "engine.admit.inputs",
+            "engine.admit.splice", "engine.admit.first_token",
+            "engine.admit.routing", "engine.decode.inputs",
+            "engine.decode.logits_to_host", "engine.decode.sample",
+            "engine.decode.routing"} <= names
+
 
 class TestEngineTelemetry:
     """Engine run (pallas_interpret, Mixtral smoke shape): ragged
@@ -317,6 +375,15 @@ class TestEngineTelemetry:
         assert h["engine_tpot_seconds"][""]["count"] == 3
         assert h["engine_phase_seconds"]['phase="decode"']["count"] \
             == eng.ticks
+        # one queue wait per admitted request; host sections per step
+        assert h["engine_queue_wait_seconds"][""]["count"] == 3
+        host = h["engine_host_seconds"]
+        for part in ("decode.inputs", "decode.logits_to_host",
+                     "decode.sample", "decode.routing"):
+            assert host[f'part="{part}"']["count"] == eng.ticks
+        for part in ("admit.inputs", "admit.splice", "admit.first_token",
+                     "admit.routing"):
+            assert host[f'part="{part}"']["count"] == 3
         # headline health keys exist in the snapshot (explicit zeros ok)
         assert c["alpha_cap_events_total"] == {"": 0.0}
         assert any('scheme="w4a8-is"' in k

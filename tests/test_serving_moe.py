@@ -110,3 +110,77 @@ def test_engine_moe_reference_route_matches_interpret(moe_quantized):
         got = eng.run()
         outs[mode] = [got[r] for r in rids]
     assert outs["reference"] == outs["pallas_interpret"]
+
+
+def _lowered_steps(eng, cfg):
+    """The lowered text of the engine's jitted prefill and decode."""
+    sc = eng.sc
+    spec1 = eng._cache1_specs
+    cache1 = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec1,
+                          is_leaf=S.is_spec)
+    prefill = eng._prefill.__wrapped__.lower(
+        eng.params, jnp.zeros((1, sc.prefill_len), jnp.int32), cache1)
+    decode = eng._decode_base.__wrapped__.__wrapped__.lower(
+        eng.params, jnp.zeros((sc.max_slots, 1), jnp.int32), eng.cache,
+        jnp.zeros((sc.max_slots,), jnp.int32))
+    return prefill.as_text(), decode.as_text()
+
+
+def test_engine_steps_compile_no_host_callback(moe_quantized):
+    """The routed counts leave the step as outputs: whatever routing sinks
+    exist, the compiled prefill and decode carry no host callback."""
+    api, cfg, qp = moe_quantized
+    sc = ServeConfig(max_slots=2, max_seq=32, prefill_len=8,
+                     max_new_tokens=MAX_NEW, kernel_mode="pallas_interpret")
+    trace = moe.start_routing_trace()
+    try:
+        eng = Engine(api, cfg, qp, sc, recipe=DEFAULT_RECIPE)
+        texts = _lowered_steps(eng, cfg)
+    finally:
+        moe.stop_routing_trace(trace)
+    for text in texts:
+        assert "callback" not in text.lower()
+    # ...and the counts are among the outputs: (layers, groups, experts)
+    for text in texts:
+        assert f"tensor<{cfg.num_layers}x1x{cfg.num_experts}xi32>" in text
+
+
+def test_late_sink_receives_each_steps_routed_counts(moe_quantized):
+    """A sink registered after the engine's first step receives one record
+    per MoE layer per step — prefill records before the tick's decode
+    records — carrying the counts the model's dispatch computes."""
+    api, cfg, qp = moe_quantized
+    sc = ServeConfig(max_slots=2, max_seq=32, prefill_len=8,
+                     max_new_tokens=MAX_NEW, kernel_mode="pallas_interpret")
+    eng = Engine(api, cfg, qp, sc, recipe=DEFAULT_RECIPE)
+    rng = np.random.default_rng(6)
+    eng.submit(rng.integers(0, cfg.vocab_size, size=8).tolist())
+    eng.run()  # compiles both steps: no sink is registered yet
+    trace = moe.start_routing_trace()
+    try:
+        prompt = rng.integers(0, cfg.vocab_size, size=8).tolist()
+        eng.submit(prompt)
+        eng.run()
+    finally:
+        moe.stop_routing_trace(trace)
+    L, E = cfg.num_layers, cfg.num_experts
+    assert len(trace) == MAX_NEW * L  # one prefill, MAX_NEW - 1 ticks
+    C_prefill = moe.capacity(sc.prefill_len, cfg.top_k, E,
+                             cfg.capacity_factor)
+    C_decode = moe.capacity(sc.max_slots, cfg.top_k, E, cfg.capacity_factor)
+    assert [r["capacity"] for r in trace] == \
+        [C_prefill] * L + [C_decode] * (MAX_NEW - 1) * L
+    for r in trace:
+        assert r["counts"].shape == (1, E) and r["counts"].dtype == np.int32
+    # the prefill's counts are the dispatch's own (eager forward)
+    cache1 = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                          eng._cache1_specs, is_leaf=S.is_spec)
+    *_, routed = api.apply(qp, eng.cfg, jnp.asarray([prompt], jnp.int32),
+                           recipe=DEFAULT_RECIPE, mode="train",
+                           cache=cache1, pos=0, routing=True)
+    np.testing.assert_array_equal(
+        np.stack([r["counts"] for r in trace[:L]]), np.asarray(routed))
+    # every slot is computed at a tick: B * top_k routed rows per layer
+    # (capacity E/top_k per token drops none)
+    for r in trace[L:]:
+        assert int(r["counts"].sum()) == sc.max_slots * cfg.top_k
