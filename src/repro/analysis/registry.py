@@ -18,8 +18,11 @@ Append a :class:`KernelEntry` in :func:`entries`:
 * set ``integer_scale=True`` (and ``alpha``) iff the kernel carries the
   Eq. 2 INT32 accumulation — it then gets an overflow certificate and
   the single-convert lint rule.
-* ragged kernels set ``prefetch_ranges`` so the index-map bounds rule
-  can seed the scalar-prefetch refs.
+* kernels with scalar-prefetch operands set ``prefetch_ranges`` so the
+  index-map bounds rule can seed those refs: the ragged row counts, and
+  the layer index that every GEMM's weight and scale maps lead with
+  (``w4a8_gemm.layer_operands``: 0 for one layer's operands, the clamped
+  ``[0, R)`` for a stack of R layers, which :func:`_stacked` registers).
 
 Shapes are kept small (tracing + interval interpretation run in CI on
 every push) but structurally faithful: multiple k-steps (nk=2) so the
@@ -46,6 +49,8 @@ G = K // GS
 # — the grouped call the serving path issues every tick is certified at
 # this small-M config (incl. a zero-routed expert in the rep row counts).
 E_DEC, C_DEC = 4, 8
+# layers of the stacked variants (a scanned model's layer stack)
+R_STACK = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -310,26 +315,49 @@ def _build_w4a16_ragged():
     return fn, args, {0: DATA, 1: Interval(0, C)}
 
 
-_RC = (Interval(0.0, float(C)),)
-_RC_DEC = (Interval(0.0, float(C_DEC)),)
+def _stacked(build, w_pos: int):
+    """``build``'s kernel reading its weight and scale (args ``w_pos`` and
+    ``w_pos + 1``) out of a stack of ``R_STACK`` layers at a traced layer
+    index, the kernels' next positional argument."""
+    def stacked_build():
+        import jax.numpy as jnp
+
+        fn, args, ranges = build()
+        args = list(args)
+        for p in (w_pos, w_pos + 1):
+            args[p] = jnp.stack([args[p]] * R_STACK)
+        layer = len(args)
+        args.append(_j(np.int32(R_STACK - 1)))
+        return fn, tuple(args), {**ranges,
+                                 layer: Interval(0.0, R_STACK - 1.0)}
+    return stacked_build
+
+
+_L0 = Interval(0.0, 0.0)  # one layer's operands: layer index 0
+_LR = Interval(0.0, R_STACK - 1.0)
+_RC = (Interval(0.0, float(C)), _L0)
+_RC_DEC = (Interval(0.0, float(C_DEC)), _L0)
+_RC_STACK = (Interval(0.0, float(C)), _LR)
 
 
 def entries() -> list:
     """All shipped kernels x configs, in lint/certify order."""
     return [
         KernelEntry("w4a8-is", f"W4A8 g{GS} K={K} alpha=1024 bk={BK}",
-                    _build_dense_is(4, 8), integer_scale=True, alpha=1024),
+                    _build_dense_is(4, 8), integer_scale=True, alpha=1024,
+                    prefetch_ranges=(_L0,)),
         KernelEntry("w8a8-is", f"W8A8 g{GS} K={K} alpha=heuristic+6",
-                    _build_dense_is(8, 8), integer_scale=True),
+                    _build_dense_is(8, 8), integer_scale=True,
+                    prefetch_ranges=(_L0,)),
         KernelEntry("w4a4-is", f"W4A4 g{GS} K={K} alpha=1024",
                     _build_dense_is(4, 4), integer_scale=True, alpha=1024,
-                    a_bits=4),
+                    a_bits=4, prefetch_ranges=(_L0,)),
         KernelEntry("w4a8-fs", f"W4A8 float-scale g{GS} K={K}",
-                    _build_dense_fs(GS)),
+                    _build_dense_fs(GS), prefetch_ranges=(_L0,)),
         KernelEntry("w4a8-coarse", f"W4A8 per-channel K={K}",
-                    _build_dense_fs(-1)),
+                    _build_dense_fs(-1), prefetch_ranges=(_L0,)),
         KernelEntry("w4a16", f"W4A16 weight-only g{GS} K={K}",
-                    _build_w4a16),
+                    _build_w4a16, prefetch_ranges=(_L0,)),
         KernelEntry("act-quant", "per-token int8, M=64 K=256",
                     _build_act_quant),
         KernelEntry("flash-attention", "causal Sq=Sk=256 bq=256 bk=128",
@@ -359,9 +387,35 @@ def entries() -> list:
         # instrumented dispatch wrappers (telemetry must not perturb jaxprs)
         KernelEntry("ops-qgemm-is",
                     f"ops.qgemm W4A8-IS g{GS} K={K} alpha=1024",
-                    _build_ops_dense, integer_scale=True, alpha=1024),
+                    _build_ops_dense, integer_scale=True, alpha=1024,
+                    prefetch_ranges=(_L0,)),
         KernelEntry("ops-qgemm-grouped-is",
                     f"ops.qgemm_grouped ragged E={E} C={C} K={K} alpha=1024",
                     _build_ops_grouped, integer_scale=True, alpha=1024,
                     prefetch_ranges=_RC),
+        # the same kernels reading a stack of R_STACK layers in place
+        KernelEntry("w4a8-is-stacked",
+                    f"W4A8 g{GS} K={K} alpha=1024 R={R_STACK}",
+                    _stacked(_build_dense_is(4, 8), 2), integer_scale=True,
+                    alpha=1024, prefetch_ranges=(_LR,)),
+        KernelEntry("w4a8-fs-stacked",
+                    f"W4A8 float-scale g{GS} K={K} R={R_STACK}",
+                    _stacked(_build_dense_fs(GS), 2),
+                    prefetch_ranges=(_LR,)),
+        KernelEntry("w4a16-stacked",
+                    f"W4A16 weight-only g{GS} K={K} R={R_STACK}",
+                    _stacked(_build_w4a16, 1), prefetch_ranges=(_LR,)),
+        KernelEntry("moe-w4a8-is-ragged-stacked",
+                    f"ragged E={E} C={C} K={K} alpha=1024 R={R_STACK}",
+                    _stacked(_build_moe_ragged(True), 2),
+                    integer_scale=True, alpha=1024,
+                    prefetch_ranges=_RC_STACK),
+        KernelEntry("moe-w4a8-fs-ragged-stacked",
+                    f"ragged E={E} C={C} K={K} float-scale R={R_STACK}",
+                    _stacked(_build_moe_ragged(False), 2),
+                    prefetch_ranges=_RC_STACK),
+        KernelEntry("moe-w4a16-ragged-stacked",
+                    f"ragged weight-only E={E} C={C} K={K} R={R_STACK}",
+                    _stacked(_build_w4a16_ragged, 2),
+                    prefetch_ranges=_RC_STACK),
     ]

@@ -14,6 +14,7 @@ for dry-run lowering and CPU tests) and the Pallas TPU kernels in
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from typing import Any, Literal
 
 import jax
@@ -209,6 +210,38 @@ def quantize_linear(
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerRef:
+    """Layer ``layer`` (traced) of a layer-stacked linear's params, left
+    unsliced. :func:`linear_apply` / :func:`grouped_linear_apply` take it
+    for their ``params`` and forward the stack and the index to the Pallas
+    kernels, which read the weights in place; any other reader gets a leaf
+    at ``layer`` from ``ref[key]``."""
+
+    params: dict
+    layer: Any
+
+    def __getitem__(self, key):
+        return self.params[key][self.layer]
+
+    def __contains__(self, key) -> bool:
+        return key in self.params
+
+
+def _layer_params(params, layer, qspec, mode):
+    """-> (params, layer) for an apply: a stacked linear's params at
+    ``layer`` — except the operands the Pallas kernels index themselves,
+    which stay stacked and keep ``layer`` beside them."""
+    if isinstance(params, LayerRef):
+        params, layer = params.params, params.layer
+    if layer is None:
+        return params, None
+    kernel = qspec is not None and mode in ("pallas", "pallas_interpret")
+    keep = ("qvalue", "scale", "alpha") if kernel else ()
+    params = {k: v if k in keep else v[layer] for k, v in params.items()}
+    return params, (layer if kernel else None)
+
+
 def _unpack(params: dict, qspec: QuantSpec, K: int) -> jax.Array:
     if qspec.w_bits == 4:
         return packing.unpack_int4(params["qvalue"])
@@ -221,12 +254,18 @@ def linear_apply(
     qspec: QuantSpec | None,
     *,
     mode: KernelMode | None = None,
+    layer=None,
 ) -> jax.Array:
     """y = x @ W (+ b), honoring the quantization spec.
 
     x: (..., K) activation (bf16/f32). Returns same float dtype as x.
+    With ``layer`` (or a :class:`LayerRef` for ``params``) the params are
+    a layer stack: the Pallas kernels read its weights in place at
+    ``layer``; the reference path slices them (XLA fuses the slice into
+    the dot).
     """
     mode = mode or current_kernel_mode()
+    params, layer = _layer_params(params, layer, qspec, mode)
     if qspec is None:
         y = x @ params["w"].astype(x.dtype)
         if "b" in params:
@@ -250,7 +289,7 @@ def linear_apply(
         # forwards it, so heuristic-amplifier layers use their certified
         # value rather than any static qspec fallback.
         y2 = kops.qgemm(
-            x2, params, qspec,
+            x2, params, qspec, layer=layer,
             block=kops.BlockConfig(interpret=(mode == "pallas_interpret")),
         )
     else:
@@ -298,6 +337,7 @@ def grouped_linear_apply(
     *,
     row_counts: jax.Array | None = None,
     mode: KernelMode | None = None,
+    layer=None,
 ) -> jax.Array:
     """Batched-expert linear: x (E, C, K) -> (E, C, N), params stacked with
     a leading expert dim (the MoE dispatch-buffer path).
@@ -311,9 +351,12 @@ def grouped_linear_apply(
     reference branch ignores it (zero rows already produce zero outputs
     there), so both branches keep identical semantics. Activation
     compensation (``pre_scale``), rotation (``rot``) and bias are applied
-    once here so both branches share the exact same semantics.
+    once here so both branches share the exact same semantics. ``layer``
+    (or a :class:`LayerRef`) reads a layer stack as :func:`linear_apply`
+    does.
     """
     mode = mode or current_kernel_mode()
+    params, layer = _layer_params(params, layer, qspec, mode)
     if qspec is None:
         y = jnp.einsum("eck,ekn->ecn", x, params["w"].astype(x.dtype))
         if "b" in params:
@@ -333,7 +376,7 @@ def grouped_linear_apply(
         from repro.kernels import ops as kops
 
         y = kops.qgemm_grouped(
-            x2, core, qspec, row_counts=row_counts,
+            x2, core, qspec, row_counts=row_counts, layer=layer,
             block=kops.BlockConfig(interpret=(mode == "pallas_interpret")))
     else:
         K = x.shape[-1]

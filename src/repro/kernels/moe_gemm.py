@@ -60,6 +60,11 @@ m-tile that starts at or past its expert's count. The contract:
     output == dense grouped output, element for element, including
     per-expert alphas (the epilogue divides by alpha with the same op
     order the dense wrapper uses when folding 1/alpha into ``sa``).
+  * the weights and scales may be a scanned model's whole layer stack
+    ``(R, E, ...)`` with a traced ``layer`` index, a second scalar-prefetch
+    operand that their index maps lead with (``w4a8_gemm.layer_operands``):
+    no copy of one layer's experts is made. The output equals the call on
+    ``qvalue[layer]``, bit for bit.
 
 VMEM at full K. The fused-quant kernels hold an m-tile's whole raw
 ``(bm, K)`` activation slab plus its ``(bm, K)`` int8 codes. At Mixtral's
@@ -94,7 +99,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .act_quant import quantize_rows
 from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, _snap_block,
-                        scale_rows, scale_spec)
+                        layer_operands, scale_rows, scale_spec)
 from .w4a16_gemm import _dequant_group_accumulate
 
 
@@ -346,10 +351,13 @@ def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows,
                   fused_quant, n_extra=0):
     """Grid + BlockSpecs for the ragged kernels.
 
-    Index maps receive the scalar-prefetch ``row_counts`` ref as a trailing
-    arg. Inactive m-tiles clamp every input block index to one that is (or
-    was just) resident so the pipeline issues no DMA for them; the output
-    map is NOT clamped (inactive tiles must write their zeros).
+    Index maps receive the scalar-prefetch ``row_counts`` ref and the
+    layer-index ref (``w4a8_gemm.layer_operands``) as trailing args; the
+    weight and scale operands are layer stacks ``(R, E, ...)`` whose
+    squeezed leading block index is that layer. Inactive m-tiles clamp
+    every input block index to one that is (or was just) resident so the
+    pipeline issues no DMA for them; the output map is NOT clamped
+    (inactive tiles must write their zeros).
     """
 
     def _last_tile(rc, e):
@@ -359,41 +367,42 @@ def _ragged_specs(E, Cp, K, N, bm, bn, bk, *, pack, s_rows,
     if fused_quant:
         # raw activations: one full-K row slab per (e, m-tile); quantized
         # into scratch at (j==0, k==0) and reused across every (j, k).
-        def x_map(e, i, j, k, rc):
+        def x_map(e, i, j, k, rc, lr):
             return (e, jnp.minimum(i, _last_tile(rc, e)), 0)
 
         x_spec = pl.BlockSpec((1, bm, K), x_map,
                               pipeline_mode=pl.Buffered(1))
     else:
-        def x_map(e, i, j, k, rc):
+        def x_map(e, i, j, k, rc, lr):
             act = i * bm < rc[e]
             return (e, jnp.minimum(i, _last_tile(rc, e)),
                     jnp.where(act, k, 0))
 
         x_spec = pl.BlockSpec((1, bm, bk), x_map)
 
-    def w_map(e, i, j, k, rc):
+    def w_map(e, i, j, k, rc, lr):
         act = i * bm < rc[e]
-        return (e, jnp.where(act, k, 0), jnp.where(act, j, 0))
+        return (lr[0], e, jnp.where(act, k, 0), jnp.where(act, j, 0))
 
-    def s_map(e, i, j, k, rc):
+    def s_map(e, i, j, k, rc, lr):
         # the whole (s_rows, bn) scale column, as in w4a8_gemm.scale_spec
-        return (e, 0, jnp.where(i * bm < rc[e], j, 0))
+        return (lr[0], e, 0, jnp.where(i * bm < rc[e], j, 0))
 
     nk = K // bk
     grid = (E, Cp // bm, N // bn, nk)
     in_specs = [
         x_spec,
-        pl.BlockSpec((1, bk // pack, bn), w_map),
-        pl.BlockSpec((1, s_rows, bn), s_map),
+        pl.BlockSpec((None, 1, bk // pack, bn), w_map),
+        pl.BlockSpec((None, 1, s_rows, bn), s_map),
     ]
     if n_extra:  # per-expert alpha: the whole (E, 1) f32 array, in SMEM
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    out_spec = pl.BlockSpec((1, bm, bn), lambda e, i, j, k, rc: (e, i, j))
+    out_spec = pl.BlockSpec((1, bm, bn),
+                            lambda e, i, j, k, rc, lr: (e, i, j))
     return grid, in_specs, out_spec, nk
 
 
-def _ragged_kernel(rc_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
+def _ragged_kernel(rc_ref, _lr_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
                    xq_s, sa_s, acc_ref, *,
                    nk: int, gs: int, groups_per_blk: int, w_bits: int,
                    integer: bool, coarse: bool, bm: int, bk: int,
@@ -446,12 +455,12 @@ def _ragged_kernel(rc_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
             o_ref[0] = jnp.zeros_like(o_ref[0])
 
 
-def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, *, integer: bool,
-                    group_size: int, a_bits: int, w_bits: int,
+def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, layer, *,
+                    integer: bool, group_size: int, a_bits: int, w_bits: int,
                     bm: int, bn: int, bk: int, interpret: bool, out_dtype):
     """Shared wrapper for the ragged integer-/float-scale W{4,8}A8 kernels."""
     E, C, K = x.shape
-    N = qvalue.shape[2]
+    N = qvalue.shape[-1]
     coarse = group_size <= 0
     gs = K if coarse else group_size
     if not coarse and K % gs:
@@ -479,13 +488,14 @@ def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, *, integer: bool,
     if Cp != C:
         x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
 
+    qvalue, scale, lr = layer_operands(qvalue, scale, layer)
     pack = 2 if w_bits == 4 else 1
     grid, in_specs, out_spec, nk = _ragged_specs(
-        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=scale.shape[1],
+        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=scale.shape[2],
         fused_quant=True, n_extra=1)
     acc_dtype = jnp.int32 if integer else jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
@@ -504,7 +514,7 @@ def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, *, integer: bool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Cp, N), out_dtype),
         interpret=interpret,
-    )(rc, x, qvalue, scale, a)
+    )(rc, lr, x, qvalue, scale, a)
     return out[:, :C]
 
 
@@ -516,8 +526,9 @@ def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, *, integer: bool,
 def fg_grouped_gemm_integer_scale_ragged(
     x: jax.Array,          # bf16/f32 (E, C, K) RAW dispatch buffer
     row_counts,            # int32 (E,) routed rows per expert, or None
-    qvalue: jax.Array,     # int8 (E, K/2, N) packed (w4) | (E, K, N) (w8)
-    int_scale: jax.Array,  # int32 (E, K/g, N)
+    qvalue: jax.Array,     # int8 ([R,] E, K/2 | K, N): w4 packed | w8
+    int_scale: jax.Array,  # int32 ([R,] E, K/g, N)
+    layer=None,            # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,
     alpha=1024.0,          # python float, or f32 (E,) per-expert amplifiers
@@ -531,7 +542,7 @@ def fg_grouped_gemm_integer_scale_ragged(
 ) -> jax.Array:
     """Ragged batched-expert Eq. 2 GEMM with fused act-quant."""
     return _ragged_a8_call(
-        x, row_counts, qvalue, int_scale, alpha, integer=True,
+        x, row_counts, qvalue, int_scale, alpha, layer, integer=True,
         group_size=group_size, a_bits=a_bits, w_bits=w_bits,
         bm=bm, bn=bn, bk=bk, interpret=interpret, out_dtype=out_dtype)
 
@@ -544,8 +555,9 @@ def fg_grouped_gemm_integer_scale_ragged(
 def fg_grouped_gemm_float_scale_ragged(
     x: jax.Array,      # bf16/f32 (E, C, K) RAW dispatch buffer
     row_counts,        # int32 (E,) routed rows per expert, or None
-    qvalue: jax.Array, # int8 (E, K/2, N) packed (w4) | (E, K, N) (w8)
-    scale: jax.Array,  # f32 (E, K/g, N) fine | (E, 1, N) coarse
+    qvalue: jax.Array, # int8 ([R,] E, K/2 | K, N): w4 packed | w8
+    scale: jax.Array,  # f32 ([R,] E, K/g, N) fine | ([R,] E, 1, N) coarse
+    layer=None,        # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,  # -1 => coarse
     a_bits: int = 8,
@@ -558,14 +570,14 @@ def fg_grouped_gemm_float_scale_ragged(
 ) -> jax.Array:
     """Ragged batched-expert Eq. 1 baseline with fused act-quant."""
     return _ragged_a8_call(
-        x, row_counts, qvalue, scale, 1.0, integer=False,
+        x, row_counts, qvalue, scale, 1.0, layer, integer=False,
         group_size=group_size, a_bits=a_bits, w_bits=w_bits,
         bm=bm, bn=bn, bk=bk, interpret=interpret, out_dtype=out_dtype)
 
 
-def _ragged_wo_kernel(rc_ref, x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
-                      nk: int, gs: int, groups_per_blk: int, bm: int,
-                      out_dtype):
+def _ragged_wo_kernel(rc_ref, _lr_ref, x_ref, wp_ref, s_ref, o_ref,
+                      facc_ref, *, nk: int, gs: int, groups_per_blk: int,
+                      bm: int, out_dtype):
     e = pl.program_id(0)
     i = pl.program_id(1)
     k = pl.program_id(3)
@@ -601,8 +613,9 @@ def _ragged_wo_kernel(rc_ref, x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
 def grouped_w4a16_gemm_ragged(
     x: jax.Array,      # bf16 (E, C, K)
     row_counts,        # int32 (E,) routed rows per expert, or None
-    qvalue: jax.Array, # int8 (E, K/2, N) packed
-    scale: jax.Array,  # f32 (E, K/g, N)
+    qvalue: jax.Array, # int8 ([R,] E, K/2, N) packed
+    scale: jax.Array,  # f32 ([R,] E, K/g, N)
+    layer=None,        # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,
     bm: int = 128,
@@ -613,7 +626,7 @@ def grouped_w4a16_gemm_ragged(
 ) -> jax.Array:
     """Ragged batched-expert weight-only Marlin-analog (no act-quant)."""
     E, C, K = x.shape
-    N = qvalue.shape[2]
+    N = qvalue.shape[-1]
     gs = group_size
     bm = min(bm, _round_up(C, 8))
     bn = _snap_block(N, bn, 128)
@@ -630,11 +643,12 @@ def grouped_w4a16_gemm_ragged(
     Cp = _round_up(C, bm)
     if Cp != C:
         x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
+    qvalue, scale, lr = layer_operands(qvalue, scale, layer)
     grid, in_specs, out_spec, nk = _ragged_specs(
         E, Cp, K, N, bm, bn, bk, pack=2, s_rows=K // gs,
         fused_quant=False)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
@@ -647,5 +661,5 @@ def grouped_w4a16_gemm_ragged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Cp, N), out_dtype),
         interpret=interpret,
-    )(rc, x.astype(jnp.bfloat16), qvalue, scale)
+    )(rc, lr, x.astype(jnp.bfloat16), qvalue, scale)
     return out[:, :C]

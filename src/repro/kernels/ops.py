@@ -16,6 +16,13 @@ directly (the dict ``qlinear.finish_quant`` / ``quantize_linear`` build:
   m-tiles past each expert's routed token count — the continuous-batching
   decode path threads the live per-tick dispatch counts here.
 
+Both take an optional traced ``layer`` index. With it, every array in
+``params`` carries a leading layer axis (a scanned model's stacked
+weights): the kernels read ``qvalue``/``scale`` in place at ``layer``
+through a scalar-prefetch index, and the tiny ``alpha`` is indexed here.
+The result equals the call on the params at ``layer``, bit for bit, and
+no per-layer copy of the weights is made.
+
 ``params["alpha"]`` (the integer-scale amplifier) may be a python float
 (static, baked into the kernel epilogue) or a traced f32 scalar / (E,)
 array (the per-layer / per-expert values stored by quantization) — traced
@@ -40,9 +47,12 @@ pass it explicitly to ``qlinear.linear_apply`` / ``grouped_linear_apply``
 Telemetry (repro.obs)
 ---------------------
 Every wrapper call increments ``qgemm_calls_total{scheme,kind,shape,
-block}`` on the current registry. These are host/python-side counts: in
-eager code they count executions; inside jit they count TRACES (a useful
-retrace detector — steady-state serving holds them constant). Executed
+block}`` on the current registry, and a call given a ``layer`` also
+``qgemm_layer_indexed_total{kind}`` (kind ``dense`` or ``grouped``): the
+mechanism that reads stacked weights in place engaged. These are
+host/python-side counts: in eager code they count executions; inside jit
+they count TRACES (a useful retrace detector — steady-state serving holds
+them constant). Executed
 vs total m-tiles of the ragged grouped calls are counted by the serving
 engine from the routed counts each step outputs
 (``engine_moe_m_tiles_total``). Per the repro.obs rule, nothing below
@@ -113,8 +123,9 @@ def _as_block(block) -> BlockConfig:
                     "with the v1 shims)")
 
 
-def _resolve_alpha(alpha, qspec: QuantSpec):
-    """Amplifier for the integer-scale epilogue.
+def _resolve_alpha(alpha, qspec: QuantSpec, layer=None):
+    """Amplifier for the integer-scale epilogue (a stacked stored alpha is
+    taken at ``layer``).
 
     The stored per-layer/per-expert ``params["alpha"]`` always wins — it is
     the value the PR-3 overflow certificate covers (possibly capped below
@@ -124,7 +135,9 @@ def _resolve_alpha(alpha, qspec: QuantSpec):
     the output by an arbitrary factor AND bypass certification — raise.
     """
     if alpha is not None:
-        return alpha
+        if layer is None or isinstance(alpha, (int, float)):
+            return alpha
+        return alpha[layer]
     if isinstance(qspec.amplifier, int):
         return float(qspec.amplifier)
     raise ValueError(
@@ -143,14 +156,21 @@ def _scheme_of(qspec: QuantSpec) -> str:
 
 
 def _record_call(scheme: str, kind: str, shape: tuple,
-                 blk: BlockConfig) -> None:
-    obs.current_registry().counter(
+                 blk: BlockConfig, layer) -> None:
+    reg = obs.current_registry()
+    reg.counter(
         "qgemm_calls_total",
         "kernels.ops wrapper calls (trace-time under jit)",
         ("scheme", "kind", "shape", "block"),
     ).inc(scheme=scheme, kind=kind,
           shape="x".join(str(d) for d in shape),
           block=f"{blk.bm}x{blk.bn}x{blk.bk}")
+    reg.counter(
+        "qgemm_layer_indexed_total",
+        "kernels.ops calls reading layer-stacked weights in place by a "
+        "layer index (trace-time under jit)",
+        ("kind",),
+    ).inc(0 if layer is None else 1, kind=kind)
 
 
 def qgemm(
@@ -158,12 +178,14 @@ def qgemm(
     params: dict,         # qlinear param dict: qvalue, scale, alpha?
     qspec: QuantSpec = None,
     *,
+    layer=None,           # int32 scalar: layer of stacked params | None
     block: BlockConfig | None = None,
 ) -> jax.Array:
     """Quantized GEMM honoring ``qspec``; returns f32 (M, N).
 
     Scheme dispatch (weight-only W4A16 / fine-grained integer scale /
-    float scale) comes from the qspec; operands from the param dict.
+    float scale) comes from the qspec; operands from the param dict (a
+    layer stack read at ``layer`` when given).
     """
     if not isinstance(params, dict):
         raise TypeError(
@@ -172,28 +194,28 @@ def qgemm(
     blk = _as_block(block)
     kw = blk.kernel_kwargs()
     N = params["qvalue"].shape[-1]
-    _record_call(_scheme_of(qspec), "dense", (*x.shape, N), blk)
+    _record_call(_scheme_of(qspec), "dense", (*x.shape, N), blk, layer)
 
     if qspec.weight_only:
         if qspec.w_bits != 4:
             raise NotImplementedError("weight-only kernel is W4A16")
-        return w4a16_gemm(x, params["qvalue"], params["scale"],
+        return w4a16_gemm(x, params["qvalue"], params["scale"], layer,
                           group_size=qspec.group_size, **kw)
 
     xq, sa = act_quant(x, bits=qspec.a_bits, interpret=blk.interpret)
     if qspec.scale_mode == "integer" and qspec.fine_grained:
-        a = _resolve_alpha(params.get("alpha"), qspec)
+        a = _resolve_alpha(params.get("alpha"), qspec, layer)
         if not isinstance(a, (int, float)):
             # traced per-layer amplifier: fold 1/alpha into sa (exact for
             # the power-of-two alphas the heuristic emits)
             sa = sa / jnp.asarray(a, jnp.float32)
             a = 1.0
         return fg_gemm_integer_scale(
-            xq, sa, params["qvalue"], params["scale"],
+            xq, sa, params["qvalue"], params["scale"], layer,
             group_size=qspec.group_size, alpha=float(a),
             w_bits=qspec.w_bits, **kw)
     return fg_gemm_float_scale(
-        xq, sa, params["qvalue"], params["scale"],
+        xq, sa, params["qvalue"], params["scale"], layer,
         group_size=qspec.group_size, w_bits=qspec.w_bits, **kw)
 
 
@@ -203,6 +225,7 @@ def qgemm_grouped(
     qspec: QuantSpec = None,
     *,
     row_counts=None,      # int32 (E,) routed rows per expert | None=all C
+    layer=None,           # int32 scalar: layer of stacked params | None
     block: BlockConfig | None = None,
 ) -> jax.Array:
     """Batched-expert quantized GEMM; returns f32 (E, C, N).
@@ -225,22 +248,22 @@ def qgemm_grouped(
     blk = _as_block(block)
     kw = blk.kernel_kwargs()
     N = params["qvalue"].shape[-1]
-    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), blk)
+    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), blk, layer)
 
     if qspec.weight_only:
         if qspec.w_bits != 4:
             raise NotImplementedError("weight-only kernel is W4A16")
         return grouped_w4a16_gemm_ragged(
-            x, row_counts, params["qvalue"], params["scale"],
+            x, row_counts, params["qvalue"], params["scale"], layer,
             group_size=qspec.group_size, **kw)
 
     if qspec.scale_mode == "integer" and qspec.fine_grained:
-        a = _resolve_alpha(params.get("alpha"), qspec)
+        a = _resolve_alpha(params.get("alpha"), qspec, layer)
         return fg_grouped_gemm_integer_scale_ragged(
-            x, row_counts, params["qvalue"], params["scale"],
+            x, row_counts, params["qvalue"], params["scale"], layer,
             group_size=qspec.group_size, alpha=a,
             a_bits=qspec.a_bits, w_bits=qspec.w_bits, **kw)
     return fg_grouped_gemm_float_scale_ragged(
-        x, row_counts, params["qvalue"], params["scale"],
+        x, row_counts, params["qvalue"], params["scale"], layer,
         group_size=qspec.group_size, a_bits=qspec.a_bits,
         w_bits=qspec.w_bits, **kw)

@@ -15,10 +15,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .w4a8_gemm import (_round_up, _snap_block, _unpack_wblock, scale_rows,
-                        scale_spec)
+from .w4a8_gemm import (_round_up, _snap_block, _unpack_wblock, dense_call,
+                        scale_rows)
 
 
 def _dequant_group_accumulate(x, wp, srows, facc, *, gs: int,
@@ -40,7 +39,7 @@ def _dequant_group_accumulate(x, wp, srows, facc, *, gs: int,
     return facc
 
 
-def _kernel(x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
+def _kernel(_lr_ref, x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
             nk: int, gs: int, groups_per_blk: int, out_dtype):
     k = pl.program_id(2)
 
@@ -63,8 +62,9 @@ def _kernel(x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
 )
 def w4a16_gemm(
     x: jax.Array,      # bf16 (M, K)
-    qvalue: jax.Array, # int8 (K/2, N) packed
-    scale: jax.Array,  # f32 (K/g, N)
+    qvalue: jax.Array, # int8 ([R,] K/2, N) packed
+    scale: jax.Array,  # f32 ([R,] K/g, N)
+    layer=None,        # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,
     bm: int = 128,
@@ -74,7 +74,7 @@ def w4a16_gemm(
     out_dtype=jnp.float32,
 ) -> jax.Array:
     M, K = x.shape
-    N = qvalue.shape[1]
+    N = qvalue.shape[-1]
     gs = group_size
     bm = min(bm, _round_up(M, 8))
     bn = _snap_block(N, bn, 128)
@@ -84,22 +84,9 @@ def w4a16_gemm(
     nk = K // bk
     groups_per_blk = bk // gs
 
-    Mp = _round_up(M, bm)
-    if Mp != M:
-        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
-
-    out = pl.pallas_call(
+    return dense_call(
         functools.partial(_kernel, nk=nk, gs=gs,
                           groups_per_blk=groups_per_blk, out_dtype=out_dtype),
-        grid=(Mp // bm, N // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            scale_spec(K // gs, bn),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x.astype(jnp.bfloat16), qvalue, scale)
-    return out[:M]
+        x.astype(jnp.bfloat16), None, qvalue, scale, layer, bm=bm, bn=bn,
+        bk=bk, pack=2, acc_dtype=jnp.float32, out_dtype=out_dtype,
+        interpret=interpret)

@@ -21,6 +21,11 @@ TPU adaptation (see DESIGN.md §2/§4):
     per k-step is one Mosaic refuses whenever bk/g is not a multiple of 8.
     Interpret mode does not apply the TPU compiler's tiling and VMEM rules;
     ``tests/test_tpu_compile.py`` compiles the kernels for a v5e to do so.
+  * weights and scales may be the whole layer stack ``(R, ...)`` of a
+    scanned model plus a traced ``layer`` index, a scalar-prefetch operand
+    that the weight and scale index maps lead with (:func:`layer_operands`).
+    A Pallas call cannot take a slice fused into it, so slicing the layer
+    out first would copy every layer's weights each step.
 
 Weight-bit generality: the same kernel body serves W8A8 (``w_bits=8``,
 no unpack) — Integer Scale is bit-width agnostic (paper §7).
@@ -57,18 +62,87 @@ def _unpack_wblock(wp: jax.Array, bk: int) -> jax.Array:
     return out.astype(jnp.int8)
 
 
+def layer_operands(qvalue: jax.Array, scale: jax.Array, layer):
+    """A weight and its scale with a leading layer axis, and the layer
+    index as the ``(1,)`` int32 scalar-prefetch operand that
+    :func:`weight_spec` and :func:`scale_spec` read.
+
+    A layer-stacked ``(R, ...)`` pair is read in place at ``layer``
+    (clamped to ``[0, R)``, as XLA clamps a dynamic slice), so no copy of
+    one layer's weights is ever made; an unstacked pair (``layer=None``)
+    gains a unit axis, a bitcast, and index 0.
+    """
+    if layer is None:
+        return qvalue[None], scale[None], jnp.zeros((1,), jnp.int32)
+    idx = jnp.clip(jnp.asarray(layer, jnp.int32), 0, qvalue.shape[0] - 1)
+    return qvalue, scale, idx.reshape(1)
+
+
+def weight_spec(rows: int, bn: int):
+    """BlockSpec of a layer-stacked ``(R, K/pack, N)`` weight on the dense
+    grid ``(i, j, k)``: the ``(rows, bn)`` block of layer ``lr[0]`` (the
+    scalar-prefetch ref of :func:`layer_operands`), layer axis squeezed."""
+    return pl.BlockSpec((None, rows, bn), lambda i, j, k, lr: (lr[0], k, j))
+
+
 def scale_spec(n_groups: int, bn: int, *, expert: bool = False):
-    """BlockSpec of a ``(K/g, N)`` (or ``(E, K/g, N)``) scale operand: the
-    whole ``(K/g, bn)`` column of the current n-tile, resident across the
-    k axis (its block index ignores ``k``, so it is fetched once per
-    output tile). A ``(bk/g, bn)`` block per k-step would be refused by
-    Mosaic whenever ``bk/g`` is neither a multiple of 8 (the sublane tile)
-    nor ``K/g`` — e.g. bk=512, g=128 gives 4. Kernels read their groups'
-    rows out of the column with :func:`scale_rows`.
+    """BlockSpec of a layer-stacked ``(R, K/g, N)`` scale operand (or of an
+    ``(E, K/g, N)`` one on the expert grid): the whole ``(K/g, bn)``
+    column of the current n-tile, resident across the k axis (its block
+    index ignores ``k``, so it is fetched once per output tile). A
+    ``(bk/g, bn)`` block per k-step would be refused by Mosaic whenever
+    ``bk/g`` is neither a multiple of 8 (the sublane tile) nor ``K/g`` —
+    e.g. bk=512, g=128 gives 4. Kernels read their groups' rows out of the
+    column with :func:`scale_rows`.
     """
     if expert:
         return pl.BlockSpec((1, n_groups, bn), lambda e, i, j, k: (e, 0, j))
-    return pl.BlockSpec((n_groups, bn), lambda i, j, k: (0, j))
+    return pl.BlockSpec((None, n_groups, bn),
+                        lambda i, j, k, lr: (lr[0], 0, j))
+
+
+def dense_call(kernel, x: jax.Array, sa, qvalue: jax.Array,
+               scale: jax.Array, layer, *, bm: int, bn: int, bk: int,
+               pack: int, acc_dtype, out_dtype, interpret: bool):
+    """Launch a dense GEMM body over the grid ``(M/bm, N/bn, K/bk)``.
+
+    Operands, after the layer-index ref: the ``(bm, bk)`` activation
+    block, the packed weight (:func:`weight_spec`), the scale column
+    (:func:`scale_spec`) and, unless ``sa`` is None (weight-only), the
+    ``(bm, 1)`` per-token scales. ``qvalue``/``scale`` are one layer's, or
+    a layer stack read at ``layer`` (:func:`layer_operands`). Rows are
+    padded to a multiple of ``bm`` and cut back after.
+    """
+    M, K = x.shape
+    Mp = _round_up(M, bm)
+    if Mp != M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
+        if sa is not None:
+            sa = jnp.pad(sa, ((0, Mp - M), (0, 0)))
+    qvalue, scale, lr = layer_operands(qvalue, scale, layer)
+    N = qvalue.shape[-1]
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, k, lr: (i, k)),
+        weight_spec(bk // pack, bn),
+        scale_spec(scale.shape[1], bn),
+    ]
+    operands = [x, qvalue, scale]
+    if sa is not None:
+        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, k, lr: (i, 0)))
+        operands.append(sa)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // bm, N // bn, K // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, lr: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+        interpret=interpret,
+    )(lr, *operands)
+    return out[:M]
 
 
 def scale_rows(s_ref, k, groups_per_blk: int, *, coarse: bool = False,
@@ -116,7 +190,7 @@ def _group_accumulate(x, wp, srows, acc, *, gs: int, groups_per_blk: int,
     return acc
 
 
-def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
+def _kernel(_lr_ref, x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
             nk: int, gs: int, groups_per_blk: int, w_bits: int,
             alpha: float, out_dtype):
     k = pl.program_id(2)
@@ -146,8 +220,9 @@ def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
 def fg_gemm_integer_scale(
     xq: jax.Array,        # int8 (M, K)
     sa: jax.Array,        # f32 (M, 1) per-token scales
-    qvalue: jax.Array,    # int8 (K/2, N) packed (w4) | (K, N) (w8)
-    int_scale: jax.Array, # int32 (K/g, N)
+    qvalue: jax.Array,    # int8 ([R,] K/2, N) packed (w4) | ([R,] K, N) (w8)
+    int_scale: jax.Array, # int32 ([R,] K/g, N)
+    layer=None,           # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,
     alpha: float = 1024.0,
@@ -159,7 +234,7 @@ def fg_gemm_integer_scale(
     out_dtype=jnp.float32,
 ) -> jax.Array:
     M, K = xq.shape
-    N = qvalue.shape[1]
+    N = qvalue.shape[-1]
     gs = group_size
     if K % gs:
         raise ValueError(f"K={K} % group={gs}")
@@ -171,30 +246,14 @@ def fg_gemm_integer_scale(
     nk = K // bk
     groups_per_blk = bk // gs
 
-    Mp = _round_up(M, bm)
-    if Mp != M:
-        xq = jnp.pad(xq, ((0, Mp - M), (0, 0)))
-        sa = jnp.pad(sa, ((0, Mp - M), (0, 0)))
-
     pack = 2 if w_bits == 4 else 1
-    out = pl.pallas_call(
+    return dense_call(
         functools.partial(
             _kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
             w_bits=w_bits, alpha=alpha, out_dtype=out_dtype,
         ),
-        grid=(Mp // bm, N // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // pack, bn), lambda i, j, k: (k, j)),
-            scale_spec(K // gs, bn),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(xq, qvalue, int_scale, sa)
-    return out[:M]
+        xq, sa, qvalue, int_scale, layer, bm=bm, bn=bn, bk=bk, pack=pack,
+        acc_dtype=jnp.int32, out_dtype=out_dtype, interpret=interpret)
 
 
 def _snap_block(dim: int, blk: int, align: int) -> int:

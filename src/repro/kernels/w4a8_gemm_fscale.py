@@ -20,13 +20,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .w4a8_gemm import (_group_accumulate, _round_up, _snap_block,
-                        scale_rows, scale_spec)
+                        dense_call, scale_rows)
 
 
-def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
+def _kernel(_lr_ref, x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
             nk: int, gs: int, groups_per_blk: int, w_bits: int,
             coarse: bool, out_dtype):
     k = pl.program_id(2)
@@ -53,8 +52,9 @@ def _kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
 def fg_gemm_float_scale(
     xq: jax.Array,     # int8 (M, K)
     sa: jax.Array,     # f32 (M, 1)
-    qvalue: jax.Array, # int8 (K/2, N) packed (w4) | (K, N) (w8)
-    scale: jax.Array,  # f32 (K/g, N) fine | (1, N) coarse
+    qvalue: jax.Array, # int8 ([R,] K/2, N) packed (w4) | ([R,] K, N) (w8)
+    scale: jax.Array,  # f32 ([R,] K/g, N) fine | ([R,] 1, N) coarse
+    layer=None,        # int32 scalar: layer of a stacked qvalue/scale
     *,
     group_size: int = 128,  # -1 => coarse
     w_bits: int = 4,
@@ -65,7 +65,7 @@ def fg_gemm_float_scale(
     out_dtype=jnp.float32,
 ) -> jax.Array:
     M, K = xq.shape
-    N = qvalue.shape[1]
+    N = qvalue.shape[-1]
     coarse = group_size <= 0
     gs = K if coarse else group_size
     bm = min(bm, _round_up(M, 8))
@@ -78,27 +78,11 @@ def fg_gemm_float_scale(
     nk = K // bk
     groups_per_blk = bk // gs
 
-    Mp = _round_up(M, bm)
-    if Mp != M:
-        xq = jnp.pad(xq, ((0, Mp - M), (0, 0)))
-        sa = jnp.pad(sa, ((0, Mp - M), (0, 0)))
-
     pack = 2 if w_bits == 4 else 1
-    out = pl.pallas_call(
+    return dense_call(
         functools.partial(
             _kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
             w_bits=w_bits, coarse=coarse, out_dtype=out_dtype,
         ),
-        grid=(Mp // bm, N // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // pack, bn), lambda i, j, k: (k, j)),
-            scale_spec(scale.shape[0], bn),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(xq, qvalue, scale, sa)
-    return out[:M]
+        xq, sa, qvalue, scale, layer, bm=bm, bn=bn, bk=bk, pack=pack,
+        acc_dtype=jnp.float32, out_dtype=out_dtype, interpret=interpret)

@@ -51,10 +51,10 @@ def apply_linear(recipe: QuantRecipe | None, path: str,
         x2 = np.asarray(x, dtype=np.float32).reshape(-1, x.shape[-1])
         step = max(1, x2.shape[0] // _CAPTURE_SAMPLES)
         _CAPTURE.setdefault(path, []).append(x2[::step][:_CAPTURE_SAMPLES])
-    # params may be stacked (scan): qlinear handles only per-layer; scan
-    # bodies receive the already-sliced layer params, so shapes are 2D here.
-    # ``mode`` is cfg.kernel_mode threaded from the model block; None defers
-    # to the ambient default inside qlinear.
+    # params are one layer's, or a qlinear.LayerRef into the layer stack
+    # (the Pallas serving scan, :func:`layer_view`). ``mode`` is
+    # cfg.kernel_mode threaded from the model block; None defers to the
+    # ambient default inside qlinear.
     return qlinear.linear_apply(params, x, qspec, mode=mode)
 
 
@@ -109,3 +109,16 @@ def stack_specs(tree: Any, n: int, axis_name: str = "layers") -> Any:
 def take_layer(stacked: Any, i) -> Any:
     """Slice layer i out of a stacked param tree (for unscanned access)."""
     return jax.tree.map(lambda a: a[i], stacked)
+
+
+def layer_view(stacked: Any, layer) -> Any:
+    """Layer ``layer`` (traced) of a stacked param tree without copying its
+    quantized weights: each quantized linear (a dict holding ``qvalue``)
+    becomes a :class:`qlinear.LayerRef`, which the Pallas kernels read in
+    place by the index; every other leaf (norms, router, unquantized
+    weights) is sliced here."""
+    if isinstance(stacked, dict):
+        if "qvalue" in stacked:
+            return qlinear.LayerRef(stacked, layer)
+        return {k: layer_view(v, layer) for k, v in stacked.items()}
+    return stacked[layer]

@@ -5,6 +5,13 @@ repeating pattern is scanned with ``lax.scan`` over stacked params so 100-
 layer configs lower to a compact HLO, with optional per-block remat for
 training. Heterogeneous stacks (DeepSeek's leading dense layer, the vision
 model's every-5th cross-attention layer) fall out of the same mechanism.
+
+A forward on the Pallas kernels that is not differentiated (it serves: it
+is not ``mode="train"``, or it fills a cache) scans the layer index
+instead of the weights. The quantized weights then stay stacked and the
+kernels read them in place (``common.layer_view``); slicing them out would
+copy every layer's weights each step, since a Pallas call cannot take a
+fused slice.
 """
 from __future__ import annotations
 
@@ -12,9 +19,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import qlinear
 from repro.nn import spec as S
 from . import attention as A
-from .common import rmsnorm, rmsnorm_spec, stack_specs
+from .common import layer_view, rmsnorm, rmsnorm_spec, stack_specs
 from .config import ModelConfig
 from .mlp import mlp_apply, mlp_specs
 from .moe import moe_apply, moe_specs
@@ -195,8 +203,6 @@ def apply(
     (possibly jitted) function body, so retraces re-apply it.
     """
     if cfg.kernel_mode:
-        from repro.core import qlinear
-
         with qlinear.kernel_mode(cfg.kernel_mode):
             out = _apply(params, cfg, tokens, recipe=recipe, mode=mode,
                          cache=cache, pos=pos, memory=memory)
@@ -238,12 +244,21 @@ def _apply(
                 new_cache["prefix"][str(i)] = c
 
     if R:
+        # Scan the layer index rather than the weights where nothing is
+        # differentiated: a training scan that closed over the stack would
+        # accumulate a full-size gradient of it at every step.
+        indexed = (cfg.scan_layers
+                   and qlinear.current_kernel_mode() != "reference"
+                   and (mode != "train" or cache is not None))
+
         def body(carry, inp):
             xc, auxc = carry
             if cache is not None:
                 p_l, c_l = inp
             else:
                 p_l, c_l = inp, None
+            if indexed:
+                p_l = layer_view(params["blocks"], p_l)
             c_out, n_out = {}, []
             for j, kind in enumerate(pattern):
                 cj = c_l[f"s{j}"] if c_l is not None else None
@@ -263,19 +278,24 @@ def _apply(
             # unrolled python loop — required for eager calibration capture
             from .common import take_layer
 
+            caches = []
             for r in range(R):
                 p_r = take_layer(params["blocks"], r)
                 c_r = take_layer(cache["blocks"], r) if cache is not None \
                     else None
-                (x, aux), (_, n_r) = body((x, aux), (p_r, c_r)
-                                          if cache is not None else p_r)
+                (x, aux), (c_r, n_r) = body((x, aux), (p_r, c_r)
+                                            if cache is not None else p_r)
+                caches.append(c_r)
                 if n_r is not None:
                     routed.append(n_r)
+            if cache is not None:  # restack, as the scan returns it
+                new_cache["blocks"] = jax.tree.map(
+                    lambda *a: jnp.stack(a), *caches)
         else:
             if cfg.remat and mode == "train":
                 body = jax.checkpoint(body, prevent_cse=False)
-            xs = (params["blocks"], cache["blocks"]) if cache is not None \
-                else params["blocks"]
+            layers = jnp.arange(R) if indexed else params["blocks"]
+            xs = (layers, cache["blocks"]) if cache is not None else layers
             (x, aux), (scanned_cache, n_s) = jax.lax.scan(body, (x, aux),
                                                           xs)
             if n_s is not None:  # (R, n, G, E) -> (R * n, G, E)

@@ -63,7 +63,9 @@ What is instrumented where
   stragglers. ``repro.serving.chaos`` injects all of the above
   deterministically.
 * ``kernels/ops.py``: ``qgemm_calls_total{scheme,kind,shape,block}`` per
-  wrapper call (trace-time counts under jit).
+  wrapper call, and ``qgemm_layer_indexed_total{kind}`` per call that
+  reads layer-stacked weights in place by a layer index (trace-time
+  counts under jit).
 * ``core/qlinear.py`` / ``core/ptq.py`` / ``core/integer_scale.py`` /
   ``analysis/certify.py``: quantization health — ``alpha_cap_events_total``,
   ``qcert_verdicts_total{verdict}``, ``amax_floor_hits_total{where}``,

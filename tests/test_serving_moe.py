@@ -1,9 +1,12 @@
 """Engine-driven quantized MoE: parity, ragged decode, no-retrace."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import ptq
 from repro.core.recipe import DEFAULT_RECIPE
 from repro.models import moe
@@ -184,3 +187,66 @@ def test_late_sink_receives_each_steps_routed_counts(moe_quantized):
     # (capacity E/top_k per token drops none)
     for r in trace[L:]:
         assert int(r["counts"].sum()) == sc.max_slots * cfg.top_k
+
+
+def _indexed_and_calls(reg, kind):
+    indexed = reg.counter("qgemm_layer_indexed_total", "", ("kind",))
+    calls = reg.counter("qgemm_calls_total", "",
+                        ("scheme", "kind", "shape", "block"))
+    n_calls = sum(v for key, v in calls.items()
+                  if dict(zip(calls.labelnames, key))["kind"] == kind)
+    return indexed.get(kind=kind), n_calls
+
+
+def test_engine_layer_indexed_scan_matches_unrolled(moe_quantized):
+    """The serving scan reads the stacked quantized weights in place by
+    the layer index: every quantized linear of the scanned blocks is
+    layer-indexed in the engine's prefill and decode traces, decode still
+    traces once, and a decode step gives bit-identical logits, routed
+    counts and cache to the unrolled (``scan_layers=False``) path."""
+    api, cfg, qp = moe_quantized
+    sc = ServeConfig(max_slots=2, max_seq=32, prefill_len=8,
+                     max_new_tokens=MAX_NEW, kernel_mode="pallas_interpret")
+    rng = np.random.default_rng(7)
+    reg = obs.Registry()
+    with obs.use_registry(reg):
+        eng = Engine(api, cfg, qp, sc, recipe=DEFAULT_RECIPE)
+        for _ in range(2):
+            eng.submit(rng.integers(0, cfg.vocab_size, size=8).tolist())
+        eng.run()
+    assert eng.decode_traces == 1
+    for kind in ("dense", "grouped"):
+        indexed, calls = _indexed_and_calls(reg, kind)
+        # 4 attention projections and 3 expert GEMMs per block, per trace
+        per_block = 4 if kind == "dense" else 3
+        assert indexed == calls == 2 * per_block, (kind, indexed, calls)
+
+    unrolled = Engine(api, dataclasses.replace(cfg, scan_layers=False), qp,
+                      sc, recipe=DEFAULT_RECIPE)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 1)),
+                       jnp.int32)
+    pos = jnp.asarray([8, 9], jnp.int32)
+    outs = [e._decode_base.__wrapped__.__wrapped__(qp, toks, eng.cache, pos)
+            for e in (eng, unrolled)]
+    assert eng.decode_traces == 1
+    for a, b in zip(*(jax.tree.leaves(o) for o in outs)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("route", ["train", "reference"])
+def test_layer_index_not_used_for_training_or_reference(moe_quantized,
+                                                        route):
+    """Training (``mode="train"`` without a cache) keeps scanning over the
+    weights, as does the reference kernel mode: no call is layer-indexed."""
+    api, cfg, qp = moe_quantized
+    km = "reference" if route == "reference" else "pallas_interpret"
+    c = dataclasses.replace(cfg, kernel_mode=km)
+    reg = obs.Registry()
+    with obs.use_registry(reg):
+        jax.jit(lambda p, t: api.apply(p, c, t, recipe=DEFAULT_RECIPE,
+                                       mode="train")[0]).lower(
+            qp, jnp.zeros((1, 8), jnp.int32))
+    for kind in ("dense", "grouped"):
+        indexed, calls = _indexed_and_calls(reg, kind)
+        assert indexed == 0
+        assert (calls == 0) == (route == "reference"), (kind, calls)

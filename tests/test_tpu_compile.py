@@ -10,18 +10,23 @@ worker collects the same tests; where it cannot be described the tests
 skip. The persistent compilation cache is off around the compiles: their
 entries could not be read back without a chip.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.recipe import DEFAULT_RECIPE
 from repro.kernels.act_quant import act_quant
 from repro.kernels.moe_gemm import fg_grouped_gemm_integer_scale_ragged
 from repro.kernels.w4a8_gemm import fg_gemm_integer_scale
 from repro.kernels.w4a8_gemm_fscale import fg_gemm_float_scale
 from repro.kernels.w4a16_gemm import w4a16_gemm
+from repro.models.registry import get_arch, get_model
+from repro.nn import spec as S
 
 GS = 128
 # (K, N) of the dense layers: Llama-3.2-3B fused QKV-ish (4096 -> 6144),
@@ -97,3 +102,77 @@ def test_ragged_grouped_is_compiles(one_chip, proj, C, dtype):
 @pytest.mark.parametrize("K", [4096, 14336])
 def test_act_quant_compiles(one_chip, K, M):
     _compile(lambda x: act_quant(x), one_chip, ((M, K), jnp.bfloat16))
+
+
+# Mixtral-8x7B's 8 layers of the benchmark, stacked as the layer scan holds
+# them; the kernels read layer ``l`` of the stack in place
+R_LAYERS = 8
+
+
+def test_layer_stacked_dense_is_compiles(one_chip):
+    K, N, M = 4096, 4096, 32  # attention q/o projections at 32 slots
+    _compile(
+        lambda x, sa, q, s, lyr: fg_gemm_integer_scale(
+            x, sa, q, s, lyr, group_size=GS),
+        one_chip, ((M, K), jnp.int8), ((M, 1), jnp.float32),
+        ((R_LAYERS, K // 2, N), jnp.int8),
+        ((R_LAYERS, K // GS, N), jnp.int32), ((), jnp.int32))
+
+
+@pytest.mark.parametrize("proj", ["up", "down"])
+def test_layer_stacked_ragged_is_compiles(one_chip, proj):
+    K, N = EXPERT_KN[proj]
+    E, C = 8, 8  # decode: 32 slots x top-2 over 8 experts
+    _compile(
+        lambda x, rc, q, s, lyr, a: fg_grouped_gemm_integer_scale_ragged(
+            x, rc, q, s, lyr, group_size=GS, alpha=a),
+        one_chip, ((E, C, K), jnp.bfloat16), ((E,), jnp.int32),
+        ((R_LAYERS, E, K // 2, N), jnp.int8),
+        ((R_LAYERS, E, K // GS, N), jnp.int32), ((), jnp.int32),
+        ((E,), jnp.float32))
+
+
+def _hlo_types(dtype, shape) -> set:
+    dt = {jnp.dtype(jnp.int8): "s8", jnp.dtype(jnp.int32): "s32"}[
+        jnp.dtype(dtype)]
+    return {f"{dt}[{','.join(str(d) for d in s)}]{{"
+            for s in ((1, *shape), shape)}
+
+
+def test_scanned_decode_reads_stacked_weights_in_place(one_chip):
+    """A scanned Pallas decode step at small widths: no ``dynamic-slice``
+    (fused or not) in the compiled program takes a stacked quantized
+    weight or its scale as operand — the kernels index the stack."""
+    cfg = dataclasses.replace(get_arch("mixtral-8x7b", smoke=True),
+                              num_layers=4, kernel_mode="pallas")
+    api = get_model(cfg)
+    B, max_seq = 8, 64
+
+    def abstract(specs):
+        return jax.tree.map(
+            lambda sp: jax.ShapeDtypeStruct(sp.shape, sp.dtype,
+                                            sharding=one_chip),
+            specs, is_leaf=S.is_spec)
+
+    params = abstract(api.param_specs(cfg, DEFAULT_RECIPE))
+    cache = abstract(api.cache_specs(cfg, B, max_seq))
+    toks = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+
+    def decode(p, t, c, q):
+        return api.apply(p, cfg, t, recipe=DEFAULT_RECIPE, mode="decode",
+                         cache=c, pos=q, routing=True)
+
+    text = jax.jit(decode).lower(params, toks, cache, pos).compile().as_text()
+    assert "tpu_custom_call" in text
+    # one layer of a stacked weight or scale, with its layer axis or without
+    layer_types = set().union(*(
+        _hlo_types(a.dtype, a.shape[1:])
+        for path, a in jax.tree_util.tree_leaves_with_path(params["blocks"])
+        if path[-1].key in ("qvalue", "scale")))
+    slices = [ln.strip() for ln in text.splitlines()
+              if re.search(r"\bdynamic-slice\(", ln)]
+    assert slices  # the KV cache still rides the scan
+    copies = [ln for ln in slices
+              if any(f" = {t}" in ln for t in layer_types)]
+    assert not copies, copies[:3]
