@@ -41,10 +41,11 @@ What is instrumented where
   the host sections between the jitted programs
   (``engine_host_seconds{part}``: ``admit.inputs``, ``admit.splice``,
   ``admit.first_token``, ``admit.routing``, ``decode.inputs``,
-  ``decode.logits_to_host``, ``decode.sample``, ``decode.routing``;
-  annotations ``engine.<part>``), one ``StepTraceAnnotation``
-  ``engine.tick`` per loop iteration, device time per jitted call
-  (``engine_phase_device_seconds``), each request's queue wait from
+  ``decode.logits_to_host``, ``decode.sample``, ``decode.nan_guard``,
+  ``decode.routing``; annotations ``engine.<part>``), one
+  ``StepTraceAnnotation`` ``engine.tick`` per loop iteration, device
+  time per jitted call (``engine_phase_device_seconds``), each
+  request's queue wait from
   submit to admission (``engine_queue_wait_seconds``),
   tick/token/request counters, a queue-depth gauge, per-request
   TTFT/TPOT histograms, jit retrace events (``engine_traces_total``),

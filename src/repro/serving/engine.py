@@ -89,9 +89,9 @@ is separable per phase. The host sections between the jitted programs
 are timed into ``engine_host_seconds{part}`` (``admit.inputs``,
 ``admit.splice``, ``admit.first_token``, ``admit.routing``,
 ``decode.inputs``, ``decode.logits_to_host``, ``decode.sample``,
-``decode.routing``; retire is the ``retire`` phase span), and each
-request's wait from ``submit`` to the start of its admission into
-``engine_queue_wait_seconds``. Every span is also a
+``decode.nan_guard``, ``decode.routing``; retire is the ``retire`` phase
+span), and each request's wait from ``submit`` to the start of its
+admission into ``engine_queue_wait_seconds``. Every span is also a
 ``jax.profiler.TraceAnnotation`` (``engine.<phase>``,
 ``engine.<part>``) and each loop iteration a ``StepTraceAnnotation``
 (``engine.tick``), so a profiler trace places them beside the device's
@@ -800,9 +800,10 @@ class Engine:
             return
         self.cache = new_cache
         self._fail_streak = 0
-        bad = {i for i, s in enumerate(self.slots)
-               if s.active and lg is not None
-               and not np.isfinite(lg[i]).all()}
+        with self._host(reg, "decode.nan_guard"):
+            bad = {i for i, s in enumerate(self.slots)
+                   if s.active and lg is not None
+                   and not np.isfinite(lg[i]).all()}
         if bad:
             self._nan_streak += 1
         else:

@@ -124,6 +124,10 @@ def test_nan_quarantine_cobatch_bit_exact(tiny):
     c = reg.snapshot()["counters"]
     assert c["engine_request_outcomes_total"]['outcome="nan"'] == 1
     assert c["engine_request_outcomes_total"]['outcome="ok"'] == 2
+    # the host scan that found the poisoned row is timed once per tick
+    host = reg.snapshot()["histograms"]["engine_host_seconds"]
+    assert host['part="decode.nan_guard"']["count"] == eng.ticks \
+        == c["engine_ticks_total"][""]
 
 
 def test_nan_slot_reuse_after_quarantine(tiny):

@@ -294,7 +294,7 @@ def test_engine_spans_on_profiler_host_plane(tmp_path):
             "engine.admit.splice", "engine.admit.first_token",
             "engine.admit.routing", "engine.decode.inputs",
             "engine.decode.logits_to_host", "engine.decode.sample",
-            "engine.decode.routing"} <= names
+            "engine.decode.nan_guard", "engine.decode.routing"} <= names
 
 
 class TestEngineTelemetry:
@@ -379,7 +379,7 @@ class TestEngineTelemetry:
         assert h["engine_queue_wait_seconds"][""]["count"] == 3
         host = h["engine_host_seconds"]
         for part in ("decode.inputs", "decode.logits_to_host",
-                     "decode.sample", "decode.routing"):
+                     "decode.sample", "decode.nan_guard", "decode.routing"):
             assert host[f'part="{part}"']["count"] == eng.ticks
         for part in ("admit.inputs", "admit.splice", "admit.first_token",
                      "admit.routing"):

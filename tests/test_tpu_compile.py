@@ -2,13 +2,13 @@
 
 Interpret mode (every other kernel test) cannot see what the chip's
 compiler refuses: unaligned block shapes, fast-memory (VMEM) overruns.
-These tests lower and compile each GEMM kernel, at Mixtral-8x7B and
-Llama-3.2-3B widths, for one chip of a *described* v5e topology (the TPU
-compiler is installed; no chip is attached, nothing runs). The topology
-is described inside a fixture, never at import, so every pytest-xdist
-worker collects the same tests; where it cannot be described the tests
-skip. The persistent compilation cache is off around the compiles: their
-entries could not be read back without a chip.
+These tests lower and compile each GEMM kernel, at Mixtral-8x7B,
+Qwen2-72B and Llama-3.2-3B widths, for one chip of a *described* v5e
+topology (the TPU compiler is installed; no chip is attached, nothing
+runs). The topology is described inside a fixture, never at import, so
+every pytest-xdist worker collects the same tests; where it cannot be
+described the tests skip. The persistent compilation cache is off around
+the compiles: their entries could not be read back without a chip.
 """
 import dataclasses
 import os
@@ -30,8 +30,11 @@ from repro.nn import spec as S
 
 GS = 128
 # (K, N) of the dense layers: Llama-3.2-3B fused QKV-ish (4096 -> 6144),
-# Mixtral-8x7B FFN up (4096 -> 14336) and down (14336 -> 4096)
-DENSE_KN = [(4096, 6144), (4096, 14336), (14336, 4096)]
+# Mixtral-8x7B FFN up (4096 -> 14336) and down (14336 -> 4096), Qwen2-72B
+# MLP gate/up (8192 -> 29568: bn snaps to 128), down (29568 -> 8192: bk
+# snaps to 384, three groups a block) and k/v (8192 -> 1024)
+DENSE_KN = [(4096, 6144), (4096, 14336), (14336, 4096),
+            (8192, 29568), (29568, 8192), (8192, 1024)]
 # Mixtral-8x7B expert projections (K, N); E=8 experts
 EXPERT_KN = {"up": (4096, 14336), "down": (14336, 4096)}
 
@@ -99,7 +102,7 @@ def test_ragged_grouped_is_compiles(one_chip, proj, C, dtype):
 
 
 @pytest.mark.parametrize("M", [8, 512])
-@pytest.mark.parametrize("K", [4096, 14336])
+@pytest.mark.parametrize("K", [4096, 14336, 29568])
 def test_act_quant_compiles(one_chip, K, M):
     _compile(lambda x: act_quant(x), one_chip, ((M, K), jnp.bfloat16))
 
@@ -109,14 +112,24 @@ def test_act_quant_compiles(one_chip, K, M):
 R_LAYERS = 8
 
 
-def test_layer_stacked_dense_is_compiles(one_chip):
-    K, N, M = 4096, 4096, 32  # attention q/o projections at 32 slots
+def _stacked_dense_is(one_chip, R, K, N, M):
     _compile(
         lambda x, sa, q, s, lyr: fg_gemm_integer_scale(
             x, sa, q, s, lyr, group_size=GS),
         one_chip, ((M, K), jnp.int8), ((M, 1), jnp.float32),
-        ((R_LAYERS, K // 2, N), jnp.int8),
-        ((R_LAYERS, K // GS, N), jnp.int32), ((), jnp.int32))
+        ((R, K // 2, N), jnp.int8),
+        ((R, K // GS, N), jnp.int32), ((), jnp.int32))
+
+
+def test_layer_stacked_dense_is_compiles(one_chip):
+    # attention q/o projections at 32 slots
+    _stacked_dense_is(one_chip, R_LAYERS, 4096, 4096, 32)
+
+
+def test_layer_stacked_dense_is_compiles_qwen2_down(one_chip):
+    # Qwen2-72B's 10 layers of the benchmark, MLP down at 32 slots: bk
+    # snaps to 384 (29568 = 77 * 384), three groups a block
+    _stacked_dense_is(one_chip, 10, 29568, 8192, 32)
 
 
 @pytest.mark.parametrize("proj", ["up", "down"])
