@@ -51,6 +51,9 @@ G = K // GS
 E_DEC, C_DEC = 4, 8
 # layers of the stacked variants (a scanned model's layer stack)
 R_STACK = 3
+# decode-sized dense call given no blocks: the shape rule
+# (``w4a8_gemm.stream_blocks``) takes bk = 2048, 16 groups a block, nk = 2
+K_STREAM = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,21 +113,23 @@ def _j(x):
     return jnp.asarray(x)
 
 
-def _build_dense_is(w_bits: int, a_bits: int):
+def _build_dense_is(w_bits: int, a_bits: int, k: int = K,
+                    bk: int | None = BK):
+    """``bk=None``: the launch the serving path makes, blocks by shape."""
     def build():
         from repro.kernels import w4a8_gemm as W
 
         rng = np.random.default_rng(0)
         if w_bits == 4:
-            wq, _, ints = _w4_operands(rng)
+            wq, _, ints = _w4_operands(rng, k=k)
             alpha = 1024.0
         else:
             wq, _, ints, alpha = _w8_operands(rng)
         qa = 2 ** (a_bits - 1) - 1
         fn = functools.partial(
             W.fg_gemm_integer_scale, group_size=GS, alpha=float(alpha),
-            w_bits=w_bits, bk=BK)
-        args = (_j(np.zeros((M, K), np.int8)), _j(_sa(rng, M)),
+            w_bits=w_bits, bk=bk)
+        args = (_j(np.zeros((M, k), np.int8)), _j(_sa(rng, M)),
                 _j(wq), _j(ints))
         return fn, args, {0: Interval(-qa, qa)}
     return build
@@ -346,6 +351,10 @@ def entries() -> list:
         KernelEntry("w4a8-is", f"W4A8 g{GS} K={K} alpha=1024 bk={BK}",
                     _build_dense_is(4, 8), integer_scale=True, alpha=1024,
                     prefetch_ranges=(_L0,)),
+        KernelEntry("w4a8-is-stream",
+                    f"W4A8 g{GS} M={M} K={K_STREAM} alpha=1024 by shape",
+                    _build_dense_is(4, 8, k=K_STREAM, bk=None),
+                    integer_scale=True, alpha=1024, prefetch_ranges=(_L0,)),
         KernelEntry("w8a8-is", f"W8A8 g{GS} K={K} alpha=heuristic+6",
                     _build_dense_is(8, 8), integer_scale=True,
                     prefetch_ranges=(_L0,)),

@@ -98,8 +98,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .act_quant import quantize_rows
-from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, _snap_block,
-                        layer_operands, scale_rows, scale_spec)
+from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, layer_operands,
+                        scale_rows, scale_spec, snap_blocks)
 from .w4a16_gemm import _dequant_group_accumulate
 
 
@@ -179,11 +179,8 @@ def fg_grouped_gemm_integer_scale(
     gs = group_size
     if K % gs:
         raise ValueError(f"K={K} % group={gs}")
-    bm = min(bm, _round_up(C, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), gs)
-    if bk % gs:
-        bk = gs  # block must hold whole groups
+    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     groups_per_blk = bk // gs
 
     # Fold per-expert 1/alpha into the activation scales (exact for the
@@ -234,11 +231,8 @@ def fg_grouped_gemm_float_scale(
     N = qvalue.shape[2]
     coarse = group_size <= 0
     gs = K if coarse else group_size
-    bm = min(bm, _round_up(C, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), 1 if coarse else gs)
-    if not coarse and bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     if coarse:
         gs = bk  # each K-block is one "group" with the constant scale
     groups_per_blk = bk // gs
@@ -301,11 +295,8 @@ def grouped_w4a16_gemm(
     E, C, K = x.shape
     N = qvalue.shape[2]
     gs = group_size
-    bm = min(bm, _round_up(C, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), gs)
-    if bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     groups_per_blk = bk // gs
 
     Cp = _round_up(C, bm)
@@ -465,11 +456,8 @@ def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, layer, *,
     gs = K if coarse else group_size
     if not coarse and K % gs:
         raise ValueError(f"K={K} % group={gs}")
-    bm = min(bm, _round_up(C, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), 1 if coarse else gs)
-    if not coarse and bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     if coarse:
         gs = bk  # each K-block is one "group" with the constant scale
     groups_per_blk = bk // gs
@@ -628,11 +616,8 @@ def grouped_w4a16_gemm_ragged(
     E, C, K = x.shape
     N = qvalue.shape[-1]
     gs = group_size
-    bm = min(bm, _round_up(C, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), gs)
-    if bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     groups_per_blk = bk // gs
 
     if row_counts is None:

@@ -47,15 +47,16 @@ pass it explicitly to ``qlinear.linear_apply`` / ``grouped_linear_apply``
 Telemetry (repro.obs)
 ---------------------
 Every wrapper call increments ``qgemm_calls_total{scheme,kind,shape,
-block}`` on the current registry, and a call given a ``layer`` also
-``qgemm_layer_indexed_total{kind}`` (kind ``dense`` or ``grouped``): the
-mechanism that reads stacked weights in place engaged. These are
-host/python-side counts: in eager code they count executions; inside jit
-they count TRACES (a useful retrace detector — steady-state serving holds
-them constant). Executed
-vs total m-tiles of the ragged grouped calls are counted by the serving
-engine from the routed counts each step outputs
-(``engine_moe_m_tiles_total``). Per the repro.obs rule, nothing below
+block}`` on the current registry (``block``: the ``bm x bn x bk`` the
+kernel launches, after the shape rule and snapping), and a call given a
+``layer`` also ``qgemm_layer_indexed_total{kind}`` (kind ``dense`` or
+``grouped``): the mechanism that reads stacked weights in place engaged.
+These are host/python-side counts: in eager code they count executions;
+inside jit they count TRACES (a useful retrace detector — steady-state
+serving holds them constant). Executed vs total m-tiles of the ragged
+grouped calls are counted by the serving engine from the routed counts
+each step outputs (``engine_moe_m_tiles_total``). Per the repro.obs
+rule, nothing below
 reads or writes metrics from inside a kernel body.
 """
 from __future__ import annotations
@@ -72,7 +73,8 @@ from .act_quant import act_quant
 from .moe_gemm import (fg_grouped_gemm_float_scale_ragged,
                        fg_grouped_gemm_integer_scale_ragged,
                        grouped_w4a16_gemm_ragged)
-from .w4a8_gemm import fg_gemm_integer_scale
+from .w4a8_gemm import (DEFAULT_BK, DEFAULT_BN, dense_blocks,
+                        fg_gemm_integer_scale, snap_blocks)
 from .w4a8_gemm_fscale import fg_gemm_float_scale
 from .w4a16_gemm import w4a16_gemm
 
@@ -87,26 +89,27 @@ class BlockConfig:
     ``bn``/``bk`` multiples of 128 (the lane tile; ``bk`` must also hold
     whole quantization groups, which the kernels enforce against the
     qspec's ``group_size`` since that is a property of the weights, not of
-    the launch). The defaults mirror every GEMM kernel's own defaults.
+    the launch). ``bn = bk = None`` (the default) leaves them to the
+    kernel: the dense integer-scale GEMM chooses them from the call's
+    shape (``w4a8_gemm.dense_blocks``), every other kernel takes
+    256 and 512. A block given here is launched as given, snapped only
+    to tile the call.
     """
 
     bm: int = 128
-    bn: int = 256
-    bk: int = 512
+    bn: int | None = None
+    bk: int | None = None
     interpret: bool = False
 
     def __post_init__(self):
         for name, val, mult in (("bm", self.bm, 8), ("bn", self.bn, 128),
                                 ("bk", self.bk, 128)):
+            if name != "bm" and val is None:
+                continue
             if not isinstance(val, int) or val <= 0 or val % mult:
                 raise ValueError(
                     f"BlockConfig.{name}={val!r}: must be a positive "
                     f"multiple of {mult} (BlockSpec tile divisibility)")
-
-    def kernel_kwargs(self) -> dict:
-        """Splat into the underlying Pallas wrapper call."""
-        return {"bm": self.bm, "bn": self.bn, "bk": self.bk,
-                "interpret": self.interpret}
 
 
 #: Default launch config for CPU-validated kernels (tests/benchmarks).
@@ -155,8 +158,31 @@ def _scheme_of(qspec: QuantSpec) -> str:
     return f"w{qspec.w_bits}a{qspec.a_bits}-{s}"
 
 
-def _record_call(scheme: str, kind: str, shape: tuple,
-                 blk: BlockConfig, layer) -> None:
+def _launch(blk: BlockConfig, rows: int, K: int, N: int, qspec: QuantSpec,
+            *, by_shape: bool) -> dict:
+    """Kernel kwargs with the ``(bm, bn, bk)`` the kernel launches:
+    ``by_shape`` (the dense integer-scale GEMM) picks unset blocks from
+    the call's shape, any other kernel takes the defaults; both snapped
+    to tile the call, as the kernels would."""
+    if by_shape:
+        bm, bn, bk = dense_blocks(rows, K, N, group_size=qspec.group_size,
+                                  w_bits=qspec.w_bits, bm=blk.bm, bn=blk.bn,
+                                  bk=blk.bk)
+    else:
+        bm, bn, bk = snap_blocks(rows, K, N, bm=blk.bm,
+                                 bn=blk.bn or DEFAULT_BN,
+                                 bk=blk.bk or DEFAULT_BK,
+                                 group_size=qspec.group_size)
+    return {"bm": bm, "bn": bn, "bk": bk, "interpret": blk.interpret}
+
+
+def _is_integer_scale(qspec: QuantSpec) -> bool:
+    return (not qspec.weight_only and qspec.scale_mode == "integer"
+            and qspec.fine_grained)
+
+
+def _record_call(scheme: str, kind: str, shape: tuple, kw: dict,
+                 layer) -> None:
     reg = obs.current_registry()
     reg.counter(
         "qgemm_calls_total",
@@ -164,7 +190,7 @@ def _record_call(scheme: str, kind: str, shape: tuple,
         ("scheme", "kind", "shape", "block"),
     ).inc(scheme=scheme, kind=kind,
           shape="x".join(str(d) for d in shape),
-          block=f"{blk.bm}x{blk.bn}x{blk.bk}")
+          block=f"{kw['bm']}x{kw['bn']}x{kw['bk']}")
     reg.counter(
         "qgemm_layer_indexed_total",
         "kernels.ops calls reading layer-stacked weights in place by a "
@@ -191,10 +217,10 @@ def qgemm(
         raise TypeError(
             "qgemm takes the qlinear param dict as its second argument "
             "(the v1 positional qvalue/scale form was removed)")
-    blk = _as_block(block)
-    kw = blk.kernel_kwargs()
     N = params["qvalue"].shape[-1]
-    _record_call(_scheme_of(qspec), "dense", (*x.shape, N), blk, layer)
+    kw = _launch(_as_block(block), *x.shape, N, qspec,
+                 by_shape=_is_integer_scale(qspec))
+    _record_call(_scheme_of(qspec), "dense", (*x.shape, N), kw, layer)
 
     if qspec.weight_only:
         if qspec.w_bits != 4:
@@ -202,8 +228,8 @@ def qgemm(
         return w4a16_gemm(x, params["qvalue"], params["scale"], layer,
                           group_size=qspec.group_size, **kw)
 
-    xq, sa = act_quant(x, bits=qspec.a_bits, interpret=blk.interpret)
-    if qspec.scale_mode == "integer" and qspec.fine_grained:
+    xq, sa = act_quant(x, bits=qspec.a_bits, interpret=kw["interpret"])
+    if _is_integer_scale(qspec):
         a = _resolve_alpha(params.get("alpha"), qspec, layer)
         if not isinstance(a, (int, float)):
             # traced per-layer amplifier: fold 1/alpha into sa (exact for
@@ -245,10 +271,10 @@ def qgemm_grouped(
             "qgemm_grouped takes the stacked qlinear param dict as its "
             "second argument (the v1 positional qvalue/scale form was "
             "removed)")
-    blk = _as_block(block)
-    kw = blk.kernel_kwargs()
+    C, K = x.shape[1:]
     N = params["qvalue"].shape[-1]
-    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), blk, layer)
+    kw = _launch(_as_block(block), C, K, N, qspec, by_shape=False)
+    _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), kw, layer)
 
     if qspec.weight_only:
         if qspec.w_bits != 4:

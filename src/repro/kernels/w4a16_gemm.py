@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .w4a8_gemm import (_round_up, _snap_block, _unpack_wblock, dense_call,
-                        scale_rows)
+from .w4a8_gemm import _unpack_wblock, dense_call, scale_rows, snap_blocks
 
 
 def _dequant_group_accumulate(x, wp, srows, facc, *, gs: int,
@@ -76,11 +75,7 @@ def w4a16_gemm(
     M, K = x.shape
     N = qvalue.shape[-1]
     gs = group_size
-    bm = min(bm, _round_up(M, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), gs)
-    if bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(M, K, N, bm=bm, bn=bn, bk=bk, group_size=gs)
     nk = K // bk
     groups_per_blk = bk // gs
 

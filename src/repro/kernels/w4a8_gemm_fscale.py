@@ -21,8 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .w4a8_gemm import (_group_accumulate, _round_up, _snap_block,
-                        dense_call, scale_rows)
+from .w4a8_gemm import _group_accumulate, dense_call, scale_rows, snap_blocks
 
 
 def _kernel(_lr_ref, x_ref, wp_ref, s_ref, sa_ref, o_ref, facc_ref, *,
@@ -68,11 +67,8 @@ def fg_gemm_float_scale(
     N = qvalue.shape[-1]
     coarse = group_size <= 0
     gs = K if coarse else group_size
-    bm = min(bm, _round_up(M, 8))
-    bn = _snap_block(N, bn, 128)
-    bk = _snap_block(K, min(bk, K), 1 if coarse else gs)
-    if not coarse and bk % gs:
-        bk = gs
+    bm, bn, bk = snap_blocks(M, K, N, bm=bm, bn=bn, bk=bk,
+                             group_size=group_size)
     if coarse:
         gs = bk  # treat each K-block as one "group" with the constant scale
     nk = K // bk

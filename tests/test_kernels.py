@@ -9,6 +9,7 @@ from repro.core import integer_scale as isc
 from repro.core import packing, quant
 from repro.kernels import ref as KR
 from repro.kernels.act_quant import act_quant
+from repro.kernels import w4a8_gemm as W
 from repro.kernels.w4a8_gemm import fg_gemm_integer_scale
 from repro.kernels.w4a8_gemm_fscale import fg_gemm_float_scale
 from repro.kernels.w4a16_gemm import w4a16_gemm
@@ -122,6 +123,88 @@ def test_kernel_block_shape_sweep():
             bm=bm, bn=bn, bk=bk, interpret=True)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(ref),
                                       err_msg=f"blocks={(bm, bn, bk)}")
+
+
+# Qwen2-72B's divisibility at reduced size, at the engine's 32 decode
+# slots: N = 2688 = 21 * 128 as gate/up's 29568 = 231 * 128, and K = 2688
+# as down's; the last case reads layer 1 of a stack at a traced index
+CHOSEN = [(32, 2048, 2688, None), (32, 2688, 1024, None),
+          (32, 2688, 1024, 1)]
+
+
+@pytest.mark.parametrize("M,K,N,layer", CHOSEN)
+def test_is_kernel_chosen_blocks_bit_identical(M, K, N, layer):
+    """The blocks chosen by call shape give the old default launch's
+    (bn=256, bk=512) output bit for bit: per-group int32 products summed
+    in int32 are exact in any blocking, and the epilogue is one convert."""
+    g = 128
+    qw, packed, xq, sa = _mk(13, M, K, N, g)
+    scale = isc.integerize(qw, 1024).int_scale
+    if layer is not None:
+        qw1, packed1, _, _ = _mk(14, M, K, N, g)
+        packed = jnp.stack([packed, packed1])
+        scale = jnp.stack([scale, isc.integerize(qw1, 1024).int_scale])
+    chosen = W.dense_blocks(M, K, N, group_size=g, w_bits=4)
+    old = W.dense_blocks(M, K, N, group_size=g, w_bits=4, bn=256, bk=512)
+    assert chosen[1:] != old[1:] and K // chosen[2] < K // old[2]
+
+    def run(lyr, **blocks):
+        return jax.jit(lambda lyr: fg_gemm_integer_scale(
+            xq, sa, packed, scale, lyr, group_size=g, alpha=1024.0,
+            interpret=True, **blocks))(lyr)
+
+    y = run(layer)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(run(layer, bn=256, bk=512)))
+    if layer is not None:
+        y_r = KR.fg_gemm_is_ref(xq, sa, packed1, scale[layer],
+                                group_size=g, alpha=1024.0)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y_r))
+
+
+RULE_KN = [(8192, 29568), (29568, 8192), (8192, 8192), (8192, 1024),
+           (4096, 14336), (14336, 4096), (2048, 2688), (2688, 1024),
+           (512, 256), (384, 128), (1024, 96)]
+
+
+@pytest.mark.parametrize("w_bits", [4, 8])
+@pytest.mark.parametrize("K,N,g", [(K, N, g) for K, N in RULE_KN
+                                   for g in (128, 256) if K % g == 0])
+def test_dense_blocks_rule(K, N, g, w_bits):
+    """Up to one m-tile of rows the blocks divide N and K, hold whole
+    groups and 128-row packing units, and keep the packed block within
+    the byte cap; above it, and for any explicit block, the launch is
+    the one the defaults or the caller's blocks gave before."""
+    old = W.snap_blocks(512, K, N, bm=128, bn=256, bk=512, group_size=g)
+    for M in (1, 32, 128):
+        bm, bn, bk = W.dense_blocks(M, K, N, group_size=g, w_bits=w_bits)
+        assert bm == -(-M // 8) * 8
+        assert N % bn == 0 and K % bk == 0 and bk % g == 0
+        if N % 128 == 0:
+            assert bn % 128 == 0 and bn <= W.STREAM_BN
+        if K % 128 == 0:
+            assert bk % 128 == 0 and bk <= W.STREAM_BK
+            assert bk * bn // (2 if w_bits == 4 else 1) <= W.STREAM_BYTES
+        assert (N // bn) * (K // bk) <= (N // old[1]) * (K // old[2])
+    for M in (129, 512):
+        assert W.dense_blocks(M, K, N, group_size=g, w_bits=w_bits) == old
+    for blocks in (dict(bn=256, bk=512), dict(bn=128), dict(bk=256)):
+        want = W.snap_blocks(32, K, N, bm=128, bn=blocks.get("bn", 256),
+                             bk=blocks.get("bk", 512), group_size=g)
+        assert W.dense_blocks(32, K, N, group_size=g, w_bits=w_bits,
+                              **blocks) == want
+
+
+@pytest.mark.parametrize("K,N,bn,bk", [
+    (8192, 29568, 1408, 2048),   # gate, up
+    (29568, 8192, 2048, 1408),   # down
+    (8192, 8192, 2048, 2048),    # q, o
+    (8192, 1024, 1024, 2048),    # k, v
+])
+def test_stream_blocks_qwen2_decode(K, N, bn, bk):
+    """The blocks Qwen2-72B's decode launches at 32 slots: 292 grid steps
+    a layer, where the (256, 512) defaults snapped to 11,008."""
+    assert W.dense_blocks(32, K, N, group_size=128, w_bits=4) == (32, bn, bk)
 
 
 def test_linear_apply_pallas_honors_stored_alpha():

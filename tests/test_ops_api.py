@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import qlinear
 from repro.core.recipe import QuantSpec
 from repro.kernels import ops
@@ -22,8 +23,30 @@ def _dense_case(seed=0, K=512, N=256, M=8):
 
 class TestBlockConfig:
     def test_defaults_match_kernel_defaults(self):
+        """``bn``/``bk`` default to None, as the dense integer-scale
+        kernel's own (chosen by shape); every other GEMM kernel defaults
+        to the blocks ops fills in for None."""
+        import inspect
+
+        from repro.kernels import moe_gemm, w4a8_gemm, w4a8_gemm_fscale
+        from repro.kernels import w4a16_gemm
+
         b = ops.BlockConfig()
-        assert (b.bm, b.bn, b.bk, b.interpret) == (128, 256, 512, False)
+        assert (b.bm, b.bn, b.bk, b.interpret) == (128, None, None, False)
+
+        def defaults(fn):
+            p = inspect.signature(fn).parameters
+            return tuple(p[n].default for n in ("bm", "bn", "bk"))
+
+        assert defaults(w4a8_gemm.fg_gemm_integer_scale) == (128, None, None)
+        fixed = (128, w4a8_gemm.DEFAULT_BN, w4a8_gemm.DEFAULT_BK)
+        assert fixed == (128, 256, 512)
+        for fn in (w4a8_gemm_fscale.fg_gemm_float_scale,
+                   w4a16_gemm.w4a16_gemm,
+                   moe_gemm.fg_grouped_gemm_integer_scale_ragged,
+                   moe_gemm.fg_grouped_gemm_float_scale_ragged,
+                   moe_gemm.grouped_w4a16_gemm_ragged):
+            assert defaults(fn) == fixed, fn.__name__
 
     @pytest.mark.parametrize("kw", [dict(bm=7), dict(bm=0), dict(bn=100),
                                     dict(bk=-128), dict(bn=64)])
@@ -109,6 +132,45 @@ class TestAlphaResolution:
     def test_heuristic_amplifier_without_stored_alpha_raises(self, amp):
         with pytest.raises(ValueError, match="per layer"):
             ops._resolve_alpha(None, QuantSpec(amplifier=amp))
+
+
+class TestBlockLabel:
+    # Qwen2-72B's gate projection over its 10-layer stack: the decode call
+    # (32 slots) takes the blocks chosen by shape, prefill (512 rows) the
+    # defaults, with bn snapped to 128 (29568 = 231 * 128)
+    @pytest.mark.parametrize("M,block", [(32, "32x1408x2048"),
+                                         (512, "128x128x512")])
+    def test_traced_call_records_launched_block(self, M, block):
+        R, K, N = 10, 8192, 29568
+        spec = QuantSpec()
+
+        def call(x, q, s, layer):
+            return ops.qgemm(x, {"qvalue": q, "scale": s, "alpha": 1024.0},
+                             spec, layer=layer)
+
+        reg = obs.Registry()
+        with obs.use_registry(reg):
+            jax.eval_shape(call, jax.ShapeDtypeStruct((M, K), jnp.bfloat16),
+                           jax.ShapeDtypeStruct((R, K // 2, N), jnp.int8),
+                           jax.ShapeDtypeStruct((R, K // 128, N), jnp.int32),
+                           jax.ShapeDtypeStruct((), jnp.int32))
+        calls = reg.counter("qgemm_calls_total", "",
+                            ("scheme", "kind", "shape", "block"))
+        labels = [dict(zip(calls.labelnames, key)) for key, _ in calls.items()]
+        assert labels == [{"scheme": "w4a8-is", "kind": "dense",
+                           "shape": f"{M}x{K}x{N}", "block": block}]
+
+    def test_explicit_block_is_recorded_as_launched(self):
+        spec, params, x = _dense_case(K=512, N=384, M=8)
+        reg = obs.Registry()
+        with obs.use_registry(reg):
+            ops.qgemm(x, params, spec,
+                      block=ops.BlockConfig(bn=256, bk=512, interpret=True))
+        calls = reg.counter("qgemm_calls_total", "",
+                            ("scheme", "kind", "shape", "block"))
+        (key, _), = calls.items()
+        # bn=256 does not tile N=384: the kernel snaps it to 128
+        assert dict(zip(calls.labelnames, key))["block"] == "8x128x512"
 
 
 class TestKernelModeContext:

@@ -31,8 +31,10 @@ from repro.nn import spec as S
 GS = 128
 # (K, N) of the dense layers: Llama-3.2-3B fused QKV-ish (4096 -> 6144),
 # Mixtral-8x7B FFN up (4096 -> 14336) and down (14336 -> 4096), Qwen2-72B
-# MLP gate/up (8192 -> 29568: bn snaps to 128), down (29568 -> 8192: bk
-# snaps to 384, three groups a block) and k/v (8192 -> 1024)
+# MLP gate/up (8192 -> 29568), down (29568 -> 8192) and k/v (8192 -> 1024).
+# At these M the integer-scale kernel takes its blocks by shape
+# (``w4a8_gemm.stream_blocks``); the others take the (256, 512) defaults,
+# snapped: gate/up's bn to 128, down's bk to 384 (three groups a block)
 DENSE_KN = [(4096, 6144), (4096, 14336), (14336, 4096),
             (8192, 29568), (29568, 8192), (8192, 1024)]
 # Mixtral-8x7B expert projections (K, N); E=8 experts
@@ -127,9 +129,17 @@ def test_layer_stacked_dense_is_compiles(one_chip):
 
 
 def test_layer_stacked_dense_is_compiles_qwen2_down(one_chip):
-    # Qwen2-72B's 10 layers of the benchmark, MLP down at 32 slots: bk
-    # snaps to 384 (29568 = 77 * 384), three groups a block
+    # Qwen2-72B's 10 layers of the benchmark, MLP down at 32 slots: blocks
+    # by shape (2048, 1408), eleven groups a block (29568 = 21 * 1408)
     _stacked_dense_is(one_chip, 10, 29568, 8192, 32)
+
+
+@pytest.mark.parametrize("K,N", [(8192, 29568), (8192, 8192)],
+                         ids=["gate_up", "q"])
+def test_layer_stacked_dense_is_compiles_qwen2(one_chip, K, N):
+    # the same stack's gate/up and q at 32 slots, blocks by shape:
+    # (1408, 2048) and (2048, 2048), 1.4 and 2 MiB of packed int4 a step
+    _stacked_dense_is(one_chip, 10, K, N, 32)
 
 
 @pytest.mark.parametrize("proj", ["up", "down"])
