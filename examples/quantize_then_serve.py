@@ -1,6 +1,7 @@
 """End-to-end driver (the paper's kind: INFERENCE): post-training-quantize
-the trained bench LM to fine-grained W4A8 with Integer Scale, then serve
-batched requests through the continuous-batching engine.
+Mixtral-8x7B at its CPU-sized smoke shape to fine-grained W4A8 with
+Integer Scale, then serve batched requests through the continuous-batching
+engine.
 
     PYTHONPATH=src python examples/quantize_then_serve.py [--algo gptq]
 
@@ -13,15 +14,14 @@ import sys
 import time
 
 sys.path.insert(0, "src")
-sys.path.insert(0, ".")
 
-import jax  # noqa: E402
-
-from benchmarks.common import calib_batches, load_bench_model  # noqa: E402
 from repro.core import ptq  # noqa: E402
 from repro.core.recipe import QuantRecipe, QuantSpec  # noqa: E402
 from repro.data.pipeline import DataConfig, SyntheticPipeline  # noqa: E402
+from repro.launch.serve import load_model  # noqa: E402
 from repro.serving.engine import Engine, ServeConfig  # noqa: E402
+
+ARCH = "mixtral-8x7b"
 
 
 def build_engine(api, cfg, params, recipe, max_slots=4):
@@ -37,9 +37,11 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=6)
     args = ap.parse_args()
 
-    api, cfg, params, trained = load_bench_model()
-    print(f"[serve] model={cfg.name} trained={trained}")
-    cal = calib_batches(1)
+    api, cfg, params = load_model(ARCH, smoke=True)
+    print(f"[serve] model={cfg.name}")
+    cal = [SyntheticPipeline(DataConfig(
+        vocab_size=cfg.vocab_size, seq_len=128,
+        batch_size=8)).global_batch(50_000)]
     pipe = SyntheticPipeline(DataConfig(vocab_size=cfg.vocab_size,
                                         seq_len=32, batch_size=1))
     prompts = [pipe.batch(200_000 + i)["tokens"][0].tolist()

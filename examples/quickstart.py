@@ -2,8 +2,7 @@
 
     PYTHONPATH=src python examples/quickstart.py --steps 250
 
-Produces results/bench_lm_ckpt/ — the trained model every quality
-benchmark (Tables 1/3/4/7 reproductions) quantizes and evaluates.
+Produces results/bench_lm_ckpt/.
 Training is fault-tolerant: rerunning resumes from the latest checkpoint;
 `--fail-at-step N` demonstrates the injected-failure restart drill.
 """

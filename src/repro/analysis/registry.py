@@ -27,7 +27,7 @@ Append a :class:`KernelEntry` in :func:`entries`:
 Shapes are kept small (tracing + interval interpretation run in CI on
 every push) but structurally faithful: multiple k-steps (nk=2) so the
 accumulator carry across the minor grid axis is analyzed, multiple
-groups per block, packed int4 weights, padded+ragged expert slabs.
+groups per block, packed int4 weights, ragged expert slabs.
 """
 from __future__ import annotations
 
@@ -186,28 +186,6 @@ def _moe_w4(rng, alpha=1024):
         packed.append(p)
         ints.append(i)
     return np.stack(packed), np.stack(ints)
-
-
-def _build_moe_dense(integer: bool):
-    def build():
-        from repro.kernels import moe_gemm as MG
-
-        rng = np.random.default_rng(4)
-        packed, ints = _moe_w4(rng)
-        if integer:
-            fn = functools.partial(
-                MG.fg_grouped_gemm_integer_scale, group_size=GS,
-                alpha=1024.0, w_bits=4, bk=BK)
-            scale_arg = ints
-        else:
-            fn = functools.partial(
-                MG.fg_grouped_gemm_float_scale, group_size=GS,
-                w_bits=4, bk=BK)
-            scale_arg = (ints / 1024.0).astype(np.float32)
-        args = (_j(np.zeros((E, C, K), np.int8)), _j(_sa(rng, E, C)),
-                _j(packed), _j(scale_arg))
-        return fn, args, {0: Interval(-127, 127)}
-    return build
 
 
 def _build_moe_ragged(integer: bool):
@@ -371,10 +349,6 @@ def entries() -> list:
                     _build_act_quant),
         KernelEntry("flash-attention", "causal Sq=Sk=256 bq=256 bk=128",
                     _build_flash),
-        KernelEntry("moe-w4a8-is", f"grouped E={E} C={C} K={K} alpha=1024",
-                    _build_moe_dense(True), integer_scale=True, alpha=1024),
-        KernelEntry("moe-w4a8-fs", f"grouped E={E} C={C} K={K} float-scale",
-                    _build_moe_dense(False)),
         KernelEntry("moe-w4a8-is-ragged",
                     f"ragged fused-quant E={E} C={C} K={K} alpha=1024",
                     _build_moe_ragged(True), integer_scale=True, alpha=1024,

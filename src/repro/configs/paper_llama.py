@@ -1,6 +1,6 @@
 """The paper's own model family: LLaMA-2-7B structure (dry-run scale) and
-a ~110M trainable variant used by examples/quickstart.py + the
-quantization benchmarks (Tables 1/3/4/7 reproductions)."""
+small CPU-trainable variants (``launch/train.py``,
+``examples/quickstart.py``)."""
 from repro.models.config import ModelConfig
 from repro.models.registry import register_arch
 
@@ -23,7 +23,7 @@ def smoke() -> ModelConfig:
 
 
 def tiny_lm() -> ModelConfig:
-    """~100M llama-style LM, trainable on CPU for the paper benchmarks.
+    """~100M llama-style LM, trainable on CPU.
 
     All K dims (d_model=768, d_ff=2048) are multiples of 128 so every
     linear supports fine-grained group-128 quantization. f32 on CPU
@@ -40,8 +40,7 @@ register_arch("llama2-7b", full, smoke)
 
 
 def bench_lm() -> ModelConfig:
-    """~30M llama-style LM — the CPU-trainable model all quality
-    benchmarks (Tables 1/3/4/7 reproductions) quantize and evaluate.
+    """~30M llama-style LM, trainable on CPU (``examples/quickstart.py``).
     K dims (512, 1536) are multiples of 128 for group-128 quantization."""
     return ModelConfig(
         name="bench-lm-30m", family="dense",

@@ -40,7 +40,7 @@ def shape_applicable(cfg: ModelConfig, shape: Shape) -> tuple[bool, str]:
         return False, (
             "full softmax attention: a 524288-token dense KV cache is "
             "architecturally quadratic in attention reads; skipped per "
-            "assignment (see DESIGN.md §5)")
+            "assignment")
     return True, ""
 
 

@@ -5,7 +5,7 @@ quantization (finer effective resolution) and the activations scaled DOWN
 correspondingly at runtime (the ``pre_scale`` in qlinear). The exponent
 alpha is grid-searched per layer to minimize the quantized output MSE —
 exactly AWQ's search, with the scale realized online instead of folded
-into the previous layer (equivalent math; see DESIGN.md).
+into the previous layer (equivalent math).
 """
 from __future__ import annotations
 

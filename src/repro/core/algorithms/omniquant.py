@@ -3,8 +3,7 @@
 The full Omniquant learns clipping + smoothing by gradient descent; this
 lite version grid-searches the clip ratio per layer against the calibrated
 output MSE — the same "learnable weight clipping" degree of freedom,
-optimized by direct search (adequate at this model scale; documented
-deviation in DESIGN.md).
+optimized by direct search (adequate at this model scale).
 """
 from __future__ import annotations
 

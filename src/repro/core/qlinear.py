@@ -31,8 +31,8 @@ KernelMode = Literal["reference", "pallas", "pallas_interpret"]
 
 # The mode is threaded explicitly: ModelConfig.kernel_mode -> apply_linear /
 # expert_linear_apply -> here, and the serving engine sets it on its
-# ServeConfig. ``kernel_mode`` below is the scoped default for scripts and
-# benchmarks that don't thread a ``mode=`` kwarg.
+# ServeConfig. ``kernel_mode`` below is the scoped default for scripts
+# that don't thread a ``mode=`` kwarg.
 _MODE_STACK: list[KernelMode] = []
 
 
@@ -42,7 +42,7 @@ def kernel_mode(mode: KernelMode):
 
     Prefer threading the mode explicitly (ModelConfig.kernel_mode /
     ServeConfig.kernel_mode / the ``mode=`` kwarg); this context manager
-    exists so scripts and benchmarks keep a one-liner.
+    exists so scripts keep a one-liner.
     """
     if mode not in ("reference", "pallas", "pallas_interpret"):
         raise ValueError(f"unknown kernel mode {mode!r}")

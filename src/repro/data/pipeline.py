@@ -1,6 +1,6 @@
 """Deterministic synthetic-corpus data pipeline (offline container: no real
 datasets). Produces a learnable token stream so the quickstart model's loss
-actually falls and the quantization benchmarks have a meaningful perplexity.
+actually falls.
 
 Generator: a fixed random 2nd-order Markov chain over the vocab with Zipfian
 marginals + periodic copy motifs — enough structure that an LM beats the

@@ -10,37 +10,33 @@ extra (outermost) grid dimension selecting the weight/scale slabs.
 
 All three quantization schemes ride the same structure:
 
-  * ``fg_grouped_gemm_integer_scale`` — Eq. 2 per expert: int32 group
-    accumulation, ONE convert per output tile. Per-expert amplifiers
-    (heuristic recipes give each expert its own alpha) are folded into the
-    per-token activation scale ``sa`` before the kernel, so the epilogue is
-    identical to the single-expert kernel.
-  * ``fg_grouped_gemm_float_scale`` — Eq. 1 baseline (per-group converts),
-    also serves coarse per-channel scales (``group_size=-1``).
-  * ``grouped_w4a16_gemm`` — weight-only Marlin-analog (in-VMEM dequant to
-    bf16, fp MXU matmul).
+  * ``fg_grouped_gemm_integer_scale_ragged`` — Eq. 2 per expert: int32
+    group accumulation, ONE convert per output tile. Per-expert amplifiers
+    (heuristic recipes give each expert its own alpha) arrive as an
+    ``(E, 1)`` SMEM operand that the epilogue divides the per-token scale
+    by.
+  * ``fg_grouped_gemm_float_scale_ragged`` — Eq. 1 baseline (per-group
+    converts), also serves coarse per-channel scales (``group_size=-1``).
+  * ``grouped_w4a16_gemm_ragged`` — weight-only Marlin-analog (in-VMEM
+    dequant to bf16, fp MXU matmul).
 
 The block bodies are the SAME helpers the dense kernels use
 (``w4a8_gemm._group_accumulate`` / ``w4a16_gemm._dequant_group_accumulate``)
-— the grouped kernels add only the expert grid dimension and blocked
-indexing, so dense-vs-grouped can never drift numerically.
+— the grouped kernels add only the expert grid dimension, the ragged
+skipping and the fused activation quantization.
 
-Capacity slots beyond the routed token count arrive zero-filled from the
-MoE dispatch; int8 zero rows contribute zero partials, so padded slots cost
-MXU work but stay exact.
+Ragged scalar-prefetch contract
+-------------------------------
 
-Ragged scalar-prefetch variants (the ``*_ragged`` entry points)
----------------------------------------------------------------
-
-The dense kernels above burn a full m-tile of MACs per capacity-padded
-tile. The ragged variants take the per-expert routed row counts as a
-scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``) and skip every
-m-tile that starts at or past its expert's count. The contract:
+The kernels take the per-expert routed row counts as a scalar-prefetch
+operand (``pltpu.PrefetchScalarGridSpec``) and skip every m-tile that
+starts at or past its expert's count:
 
   * ``row_counts`` is int32 ``(E,)``; rows ``[0, row_counts[e])`` of expert
     ``e``'s capacity slab are routed tokens, every row at or past
     ``row_counts[e]`` MUST be zero-filled (exactly what the sort-based
-    dispatch in ``models.moe`` produces). Counts are clamped to ``C``.
+    dispatch in ``models.moe`` produces). Counts are clamped to ``C``;
+    ``None`` takes every capacity slot as routed.
   * the grid still statically covers ``(E, C/bm, N/bn, K/bk)``, but for an
     inactive m-tile the block index maps clamp every operand to an
     already-resident block (no DMA is issued for a revisited block) and
@@ -56,10 +52,12 @@ m-tile that starts at or past its expert's count. The contract:
     the padded slots are never even quantized. The in-kernel math is
     ``act_quant.quantize_rows`` verbatim, which keeps fused and unfused
     paths bit-identical.
-  * bit-exactness invariant: for any zero-filled-past-count input, ragged
-    output == dense grouped output, element for element, including
-    per-expert alphas (the epilogue divides by alpha with the same op
-    order the dense wrapper uses when folding 1/alpha into ``sa``).
+  * bit-exactness invariant: for any zero-filled-past-count input, the
+    output equals the per-expert reference of ``kernels/ref.py`` on the
+    ``act_quant`` codes on every row below ``row_counts[e]`` (element for
+    element on the integer-scale path, including per-expert alphas: the
+    epilogue divides by alpha with the reference's op order), and is
+    exact zeros past it.
   * the weights and scales may be a scanned model's whole layer stack
     ``(R, E, ...)`` with a traced ``layer`` index, a second scalar-prefetch
     operand that their index maps lead with (``w4a8_gemm.layer_operands``):
@@ -99,236 +97,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .act_quant import quantize_rows
 from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, layer_operands,
-                        scale_rows, scale_spec, snap_blocks)
+                        scale_rows, snap_blocks)
 from .w4a16_gemm import _dequant_group_accumulate
-
-
-def _grouped_kernel(x_ref, wp_ref, s_ref, sa_ref, o_ref, acc_ref, *,
-                    nk: int, gs: int, groups_per_blk: int, w_bits: int,
-                    integer: bool, coarse: bool, out_dtype):
-    """One (expert, m, n) output tile; k innermost accumulates groups."""
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] = _group_accumulate(
-        x_ref[0], wp_ref[0],
-        scale_rows(s_ref, k, groups_per_blk, coarse=coarse, expert=True),
-        acc_ref[...], gs=gs, groups_per_blk=groups_per_blk, w_bits=w_bits,
-        integer=integer)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        if integer:
-            # ONE I32->F32 convert per output tile; 1/alpha pre-folded
-            # into sa by the wrapper (per-expert alphas supported).
-            o_ref[0] = (acc_ref[...].astype(jnp.float32)
-                        * sa_ref[0]).astype(out_dtype)
-        else:
-            o_ref[0] = (acc_ref[...] * sa_ref[0]).astype(out_dtype)
-
-
-def _grouped_blocks(E, Cp, K, N, bm, bn, bk, *, pack, s_rows):
-    """Grid + BlockSpecs shared by the int- and float-scale variants
-    (``s_rows``: rows of the scale operand, K/g or 1 for coarse)."""
-    nk = K // bk
-    grid = (E, Cp // bm, N // bn, nk)
-    in_specs = [
-        pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
-        pl.BlockSpec((1, bk // pack, bn), lambda e, i, j, k: (e, k, j)),
-        scale_spec(s_rows, bn, expert=True),
-        pl.BlockSpec((1, bm, 1), lambda e, i, j, k: (e, i, 0)),
-    ]
-    out_spec = pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j))
-    return grid, in_specs, out_spec, nk
-
-
-def _pad_tokens(x, sa, C, bm):
-    Cp = _round_up(C, bm)
-    if Cp != C:
-        x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
-        sa = jnp.pad(sa, ((0, 0), (0, Cp - C), (0, 0)))
-    return x, sa, Cp
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "w_bits", "bm", "bn", "bk", "interpret",
-                     "out_dtype"),
-)
-def fg_grouped_gemm_integer_scale(
-    xq: jax.Array,        # int8 (E, C, K) dispatch buffer
-    sa: jax.Array,        # f32 (E, C, 1) per-token scales
-    qvalue: jax.Array,    # int8 (E, K/2, N) packed (w4) | (E, K, N) (w8)
-    int_scale: jax.Array, # int32 (E, K/g, N)
-    *,
-    group_size: int = 128,
-    alpha=1024.0,         # python float, or f32 (E,) per-expert amplifiers
-    w_bits: int = 4,
-    bm: int = 128,
-    bn: int = 256,
-    bk: int = 512,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
-    """Batched-expert Eq. 2 GEMM: (E,C,K) x (E,K,N) -> (E,C,N) f32."""
-    E, C, K = xq.shape
-    N = qvalue.shape[2]
-    gs = group_size
-    if K % gs:
-        raise ValueError(f"K={K} % group={gs}")
-    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
-                             group_size=group_size)
-    groups_per_blk = bk // gs
-
-    # Fold per-expert 1/alpha into the activation scales (exact for the
-    # power-of-two amplifiers Listing 1 produces).
-    a = jnp.asarray(alpha, jnp.float32)
-    sa = sa / (a.reshape(E, 1, 1) if a.ndim == 1 else a)
-
-    xq, sa, Cp = _pad_tokens(xq, sa, C, bm)
-    pack = 2 if w_bits == 4 else 1
-    grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=K // gs)
-    out = pl.pallas_call(
-        functools.partial(
-            _grouped_kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
-            w_bits=w_bits, integer=True, coarse=False, out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((E, Cp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(xq, qvalue, int_scale, sa)
-    return out[:, :C]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "w_bits", "bm", "bn", "bk", "interpret",
-                     "out_dtype"),
-)
-def fg_grouped_gemm_float_scale(
-    xq: jax.Array,     # int8 (E, C, K)
-    sa: jax.Array,     # f32 (E, C, 1)
-    qvalue: jax.Array, # int8 (E, K/2, N) packed (w4) | (E, K, N) (w8)
-    scale: jax.Array,  # f32 (E, K/g, N) fine | (E, 1, N) coarse
-    *,
-    group_size: int = 128,  # -1 => coarse
-    w_bits: int = 4,
-    bm: int = 128,
-    bn: int = 256,
-    bk: int = 512,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
-    """Batched-expert Eq. 1 baseline (per-group converts in the loop)."""
-    E, C, K = xq.shape
-    N = qvalue.shape[2]
-    coarse = group_size <= 0
-    gs = K if coarse else group_size
-    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
-                             group_size=group_size)
-    if coarse:
-        gs = bk  # each K-block is one "group" with the constant scale
-    groups_per_blk = bk // gs
-
-    xq, sa, Cp = _pad_tokens(xq, sa, C, bm)
-    pack = 2 if w_bits == 4 else 1
-    grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=pack, s_rows=scale.shape[1])
-    out = pl.pallas_call(
-        functools.partial(
-            _grouped_kernel, nk=nk, gs=gs, groups_per_blk=groups_per_blk,
-            w_bits=w_bits, integer=False, coarse=coarse, out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((E, Cp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(xq, qvalue, scale, sa)
-    return out[:, :C]
-
-
-def _grouped_wo_kernel(x_ref, wp_ref, s_ref, o_ref, facc_ref, *,
-                       nk: int, gs: int, groups_per_blk: int, out_dtype):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        facc_ref[...] = jnp.zeros_like(facc_ref)
-
-    facc_ref[...] = _dequant_group_accumulate(
-        x_ref[0], wp_ref[0],
-        scale_rows(s_ref, k, groups_per_blk, expert=True),
-        facc_ref[...], gs=gs, groups_per_blk=groups_per_blk)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        o_ref[0] = facc_ref[...].astype(out_dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "bm", "bn", "bk", "interpret",
-                     "out_dtype"),
-)
-def grouped_w4a16_gemm(
-    x: jax.Array,      # bf16 (E, C, K)
-    qvalue: jax.Array, # int8 (E, K/2, N) packed
-    scale: jax.Array,  # f32 (E, K/g, N)
-    *,
-    group_size: int = 128,
-    bm: int = 128,
-    bn: int = 256,
-    bk: int = 512,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
-    """Batched-expert weight-only Marlin-analog: (E,C,K) -> (E,C,N)."""
-    E, C, K = x.shape
-    N = qvalue.shape[2]
-    gs = group_size
-    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
-                             group_size=group_size)
-    groups_per_blk = bk // gs
-
-    Cp = _round_up(C, bm)
-    if Cp != C:
-        x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
-    grid, in_specs, out_spec, nk = _grouped_blocks(
-        E, Cp, K, N, bm, bn, bk, pack=2, s_rows=K // gs)
-    out = pl.pallas_call(
-        functools.partial(_grouped_wo_kernel, nk=nk, gs=gs,
-                          groups_per_blk=groups_per_blk,
-                          out_dtype=out_dtype),
-        grid=grid,
-        in_specs=in_specs[:3],  # no sa operand on the weight-only path
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((E, Cp, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x.astype(jnp.bfloat16), qvalue, scale)
-    return out[:, :C]
-
-
-# ---------------------------------------------------------------------------
-# Ragged scalar-prefetch variants (skip m-tiles past each expert's row count)
-# ---------------------------------------------------------------------------
 
 
 def ragged_tile_stats(row_counts, C: int, bm: int = 128) -> dict:
     """Executed-m-tile accounting for a ragged launch (python ints).
 
-    ``dense_m_tiles`` is what the capacity-padded kernel runs; per m-tile
-    the full (N/bn, K/bk) inner grid does MXU work, so the ratio is the
-    MAC-savings of ragged skipping. Used by benchmarks/CI reporting.
+    ``dense_m_tiles`` is every m-tile of the grid, as if none were
+    skipped; per m-tile the full (N/bn, K/bk) inner grid does MXU work, so
+    the ratio is the MAC-savings of ragged skipping.
     """
     bm = min(bm, _round_up(C, 8))
     Cp = _round_up(C, bm)
@@ -432,8 +210,8 @@ def _ragged_kernel(rc_ref, _lr_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
     def _epilogue():
         @pl.when(active)
         def _write():
-            # same op order as the dense wrapper's 1/alpha folding
-            # (sa / alpha, then ONE multiply) so ragged == dense bitwise.
+            # same op order as ``ref.fg_gemm_is_ref`` (sa / alpha, then
+            # ONE multiply) so the output equals the reference bitwise.
             sa = sa_s[...] / a_ref[e, 0]
             if integer:
                 o_ref[0] = (acc_ref[...].astype(jnp.float32)

@@ -112,7 +112,7 @@ class BlockConfig:
                     f"multiple of {mult} (BlockSpec tile divisibility)")
 
 
-#: Default launch config for CPU-validated kernels (tests/benchmarks).
+#: Default launch config for CPU-validated kernels (tests).
 INTERPRET = BlockConfig(interpret=True)
 
 

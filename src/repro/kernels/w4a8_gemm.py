@@ -5,7 +5,7 @@ Implements paper Eq. 2 / Table 2 "Ours":
     C_g = A_g * W_g * s_g^INT + C_{g-1}     (all INT32, MXU + VPU)
     O   = FLOAT(C_G) * s_a / alpha          (ONE convert per output tile)
 
-TPU adaptation (see DESIGN.md §2/§4):
+TPU adaptation:
   * per-group int8 x int8 -> int32 matmuls run on the MXU
     (``preferred_element_type=int32``), iterated over the K grid dimension;
   * the per-group *integer* scale multiply + add stays on VPU int32 lanes —

@@ -6,8 +6,7 @@ whole point. Per group it must
     2. FMA with the group's float scale into an f32 accumulator.
 That is ``K/group`` converts + f32 FMAs per output tile (paper Fig. 2b,
 Table 2 "Atom" column) versus ONE convert total for Integer Scale. Keeping
-the two kernels diff-minimal isolates the paper's claim structurally; the
-HLO op-count benchmark (benchmarks/kernel_latency.py) counts exactly this.
+the two kernels diff-minimal isolates the paper's claim structurally.
 
 Also serves coarse-grained W4A8/W8A8 (group_size=-1): the single per-channel
 scale is applied per K-block (mathematically identical since it is constant

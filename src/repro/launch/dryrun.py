@@ -14,8 +14,7 @@ production meshes and record memory/cost/collective analysis.
 Training cells lower ``train_step`` (fp bf16 + AdamW); prefill/decode cells
 lower the quantized serving step (paper W4A8 Integer Scale recipe) — that
 is the deployment the paper targets. Failures here are bugs in the
-framework's sharding; the roofline analysis (benchmarks/roofline.py) reads
-the JSONL this writes.
+framework's sharding.
 """
 import argparse
 import dataclasses
@@ -103,17 +102,9 @@ def parse_collectives(hlo_text: str) -> dict:
     return out
 
 
-def build_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
-               cfg_overrides: dict | None = None, rules=None,
-               token_sharding=None):
-    """Returns (lower_fn, meta) — lower_fn() does the actual lowering.
-
-    cfg_overrides / rules / token_sharding support the §Perf hillclimb
-    variants (e.g. int8 KV, int8 MoE dispatch, replicated-weight serving).
-    """
+def build_cell(arch: str, shape_name: str, mesh, multi_pod: bool):
+    """Returns (lower_fn, meta) — lower_fn() does the actual lowering."""
     cfg = get_arch(arch)
-    if cfg_overrides:
-        cfg = dataclasses.replace(cfg, **cfg_overrides)
     shp = SHAPES[shape_name]
     sizes = axis_sizes(mesh)
     data_ways = sizes.get("pod", 1) * sizes["data"]
@@ -124,17 +115,11 @@ def build_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
     api = get_model(cfg)
     mode = shp.kind
     recipe = None if mode == "train" else DEFAULT_RECIPE
-    if rules is None:
-        rules = shard.rules_for(mode, multi_pod)
+    rules = shard.rules_for(mode, multi_pod)
     pspecs = api.param_specs(cfg, recipe)
     pshard = shard.named_tree(mesh, pspecs, rules)
     inputs = input_specs(cfg, shp)
     ishard = shard.input_shardings(mesh, inputs, multi_pod)
-    if token_sharding is not None:
-        from jax.sharding import NamedSharding
-
-        ishard = dict(ishard)
-        ishard["tokens"] = NamedSharding(mesh, token_sharding)
     mem_key = ("image_embeds" if "image_embeds" in inputs
                else "frames" if "frames" in inputs else None)
 
@@ -218,8 +203,7 @@ def _qcert_worst(verdicts: dict) -> str:
 
 
 def run_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
-             collect_hlo: bool = True, cfg_overrides: dict | None = None,
-             rules=None, token_sharding=None) -> dict:
+             collect_hlo: bool = True) -> dict:
     rec: dict = {"arch": arch, "shape": shape_name,
                  "mesh": "2x16x16" if multi_pod else "16x16",
                  "multi_pod": multi_pod}
@@ -231,10 +215,7 @@ def run_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
         return rec
     t0 = time.time()
     try:
-        lower_fn, cfg2 = build_cell(arch, shape_name, mesh, multi_pod,
-                                    cfg_overrides=cfg_overrides,
-                                    rules=rules,
-                                    token_sharding=token_sharding)
+        lower_fn, cfg2 = build_cell(arch, shape_name, mesh, multi_pod)
         with mesh:
             lowered = lower_fn()
             t1 = time.time()

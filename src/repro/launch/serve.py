@@ -1,15 +1,14 @@
 """Serving driver: build or load a model, PTQ per recipe, run the
 continuous-batching engine over a stream of requests.
 
-    PYTHONPATH=src:. python -m repro.launch.serve --arch mixtral-8x7b \
+    PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b \
         --num-layers 2 --prefill-len 512 --max-seq 640 --max-new 16
 
 ``--arch`` names a registry architecture, built at its published widths
 with random weights from a fixed seed; ``--num-layers N`` cuts its depth
 (printed as ``reduced: num_layers <published>-><N>``) and ``--smoke`` swaps in the
-architecture's CPU-sized smoke shape instead. ``--arch bench-lm`` (the
-default) serves the paper's small benchmark LM, trained if its checkpoint
-exists. Quantized layers run the Pallas kernels (``--kernel-mode
+architecture's CPU-sized smoke shape instead; ``--arch`` defaults to
+``mixtral-8x7b``. Quantized layers run the Pallas kernels (``--kernel-mode
 pallas``, the default); CPU callers pass ``pallas_interpret`` or
 ``reference``. ``chip_smoke.py`` drives this same path in-process
 through :func:`parse_args` and :func:`run`.
@@ -86,15 +85,9 @@ def use_compile_cache() -> str:
 
 def load_model(arch: str, *, smoke: bool = False,
                num_layers: int | None = None):
-    """(api, cfg, fp_params, trained). Registry architectures are built at
+    """(api, cfg, fp_params). Registry architectures are built at
     their published widths (or their smoke shape) with random weights
     from a fixed seed; ``num_layers`` cuts depth only."""
-    if arch == "bench-lm":
-        if num_layers is not None:
-            raise ValueError("--num-layers applies to registry archs only")
-        from benchmarks.common import load_bench_model
-
-        return load_bench_model()
     from repro.models.registry import get_arch, get_model
 
     cfg = get_arch(arch, smoke=smoke)
@@ -103,7 +96,7 @@ def load_model(arch: str, *, smoke: bool = False,
         cfg = dataclasses.replace(cfg, num_layers=num_layers)
     api = get_model(cfg)
     params = S.materialize(api.param_specs(cfg, None), jax.random.PRNGKey(0))
-    return api, cfg, params, False
+    return api, cfg, params
 
 
 def _fmt_hist(h: dict) -> str:
@@ -177,10 +170,9 @@ def _telemetry_cell(reg: obs.Registry) -> None:
 
 def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="bench-lm",
-                    help="bench-lm (trained ckpt if present) or a registry "
-                         "architecture at its published widths, e.g. "
-                         "mixtral-8x7b")
+    ap.add_argument("--arch", default="mixtral-8x7b",
+                    help="a registry architecture, built at its published "
+                         "widths")
     ap.add_argument("--smoke", action="store_true",
                     help="build the registry architecture at its CPU-sized "
                          "smoke shape instead of its published widths")
@@ -265,10 +257,10 @@ class ServeFailed(RuntimeError):
 
 def run(args: argparse.Namespace) -> ServeResult:
     reg = obs.current_registry()
-    api, cfg, params, trained = load_model(
+    api, cfg, params = load_model(
         args.arch, smoke=args.smoke, num_layers=args.num_layers)
     print(f"[serve] model={cfg.name} layers={cfg.num_layers} "
-          f"d_model={cfg.d_model} trained={trained}")
+          f"d_model={cfg.d_model}")
     if args.fp:
         recipe, qparams = None, params
     else:
@@ -278,14 +270,8 @@ def run(args: argparse.Namespace) -> ServeResult:
                          group_size=args.group, scale_mode=args.scale_mode,
                          amplifier=amp, algo=args.algo)
         recipe = QuantRecipe(rules=(("*", spec),), name=spec.name)
-        calib = None
-        if args.arch == "bench-lm":
-            from benchmarks.common import calib_batches
-
-            calib = calib_batches(1)
         t0 = time.time()
-        qparams = ptq.post_training_quantize(api, cfg, params, recipe,
-                                             calib)
+        qparams = ptq.post_training_quantize(api, cfg, params, recipe)
         del params  # the fp weights are not served
         print(f"[serve] quantized ({spec.name}) in {time.time()-t0:.1f}s")
 

@@ -1,6 +1,6 @@
 """Mixture-of-Experts with sort-based capacity dispatch + expert parallelism.
 
-Design (DESIGN.md §3):
+Design:
   router (fp32) -> top-k -> stable sort by expert -> gather into a dense
   (groups, E, C, d) dispatch buffer -> batched expert GEMMs with the expert
   axis sharded on the ``model`` mesh axis (EP; GSPMD inserts the all-to-all)
@@ -64,7 +64,7 @@ def expert_linear_apply(params: dict, x: jax.Array, qspec,
 # ---------------------------------------------------------------------------
 
 
-# -- int8 dispatch compression (§Perf hillclimb, DeepSeek-V3-style) ---------
+# -- int8 dispatch compression (DeepSeek-V3-style) ---------------------------
 # The dispatch buffer crosses the data->expert resharding boundary (the
 # all-to-all). Quantizing per token to int8 (+ f32 scale) halves the wire
 # bytes vs bf16. The sharding constraint below is what forces GSPMD to run
@@ -105,7 +105,7 @@ _int8_transport.defvjp(_int8_transport_fwd, _int8_transport_bwd)
 
 
 # -- routing sinks ----------------------------------------------------------
-# Observability hook for the serving engine + benchmarks. The routed
+# Observability hook for the serving engine. The routed
 # (capacity-clipped) per-expert counts of every MoE layer leave the
 # compiled step as an OUTPUT (``moe_apply``'s third result, stacked per
 # layer by ``transformer.apply(..., routing=True)``); compiled code never
@@ -203,8 +203,7 @@ def moe_specs(cfg: ModelConfig, recipe, base: str) -> dict:
 
 def capacity(tokens: int, top_k: int, num_experts: int,
              capacity_factor: float) -> int:
-    """Per-expert capacity (8-aligned) — also imported by benchmarks so
-    their ragged-tile accounting can never drift from the model's."""
+    """Per-expert capacity (8-aligned)."""
     c = int(tokens * top_k * capacity_factor / max(num_experts, 1))
     return max(8, -(-c // 8) * 8)
 

@@ -133,7 +133,7 @@ def _mlstm_chunked(q, k, v, i_raw, f_raw, C0, n0, m0, chunk: int):
     F_t = cumsum(log sigmoid(f_raw)), so intra-chunk outputs become a
     decay-masked attention matmul and only a LIGHT scan over S/chunk
     summaries remains — sequential depth drops 32768 -> 128 for the
-    prefill_32k cell (see EXPERIMENTS.md §Perf).
+    prefill_32k cell.
 
     q,k,v: (B,S,H,dh) f32; i_raw,f_raw: (B,S,H) f32.
     Returns (h (B,S,H,dh), (C,n,m) final state).

@@ -29,10 +29,7 @@ engine, the kernel dispatch wrappers, and the quantization pipeline:
   ["quantiles"]`` — p50/p95/p99 derived from the fixed cumulative
   buckets (Prometheus ``histogram_quantile`` interpolation; overflow
   clamps to the last finite edge), surfaced in the serve telemetry
-  cell, ``launch/dryrun.py``, and benchmark JSON.
-* ``benchmarks/regression.py`` (consumer, not part of this package)
-  turns two ``benchmarks.run --json`` documents into an enforced perf
-  contract — see its docstring for the baseline-refresh procedure.
+  cell and ``launch/dryrun.py``.
 
 What is instrumented where
 --------------------------
@@ -72,9 +69,7 @@ What is instrumented where
   ``qcert_verdicts_total{verdict}``, ``amax_floor_hits_total{where}``,
   ``int_scale_floor_hits_total``, ``quantized_layers_total{scheme}``.
 * Surfacing: ``launch/serve.py --metrics-out`` (JSONL trace + final
-  snapshot line), ``launch/dryrun.py`` telemetry cell, and benchmark JSON
-  documents (``benchmarks/run.py`` / ``benchmarks/serving_moe.py`` attach
-  a registry snapshot + host provenance).
+  snapshot line) and ``launch/dryrun.py`` telemetry cell.
 
 THE RULE: no metrics and no host callbacks in compiled code
 -----------------------------------------------------------

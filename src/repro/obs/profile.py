@@ -12,10 +12,10 @@ bookkeeping, all mixed. This module separates the device component:
   so the series reflects steady-state device time. Subtracting it from
   the enclosing host span gives host overhead per phase.
 * :func:`trace_window` is the ``jax.profiler.trace`` capture window
-  behind ``launch/serve.py --profile-dir`` / ``benchmarks/run.py
-  --profile-dir``: a no-op when the dir is falsy, otherwise the XLA
-  profiler writes ``plugins/profile/<ts>/*.xplane.pb`` under the dir
-  (open in TensorBoard's profile plugin or convert for Perfetto).
+  behind ``launch/serve.py --profile-dir``: a no-op when the dir is
+  falsy, otherwise the XLA profiler writes
+  ``plugins/profile/<ts>/*.xplane.pb`` under the dir (open in
+  TensorBoard's profile plugin or convert for Perfetto).
 
 The "no metrics inside jitted bodies" rule holds: both helpers sit on
 the host side of the jit boundary — the wrapped callable's jit cache is

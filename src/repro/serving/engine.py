@@ -24,9 +24,7 @@ Every submitted request ends in EXACTLY ONE terminal outcome::
 The conservation law — ``sum(engine_request_outcomes_total) ==
 engine_requests_total{event="submitted"}`` once the engine drains — is a
 hard invariant: outcomes are recorded through one chokepoint
-(:meth:`Engine._finish`) that raises on a double retire, and
-``benchmarks/regression.py`` enforces the law over benchmark metric
-snapshots.
+(:meth:`Engine._finish`) that raises on a double retire.
 
 * **Backpressure**: ``ServeConfig.max_queue`` bounds the admission queue;
   surplus submits are *rejected* (terminal outcome, structured retire

@@ -1,5 +1,5 @@
-"""Correctness of the §Perf hillclimb optimizations (EXPERIMENTS.md):
-chunkwise-parallel mLSTM, int8 MoE dispatch, int8 KV cache."""
+"""Correctness of the model-level optimizations: chunkwise-parallel mLSTM,
+int8 MoE dispatch, int8 KV cache."""
 import dataclasses
 
 import jax

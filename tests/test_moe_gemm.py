@@ -1,6 +1,8 @@
-"""Grouped (batched-expert) Pallas kernel validation: interpret-mode parity
-vs the per-expert oracles/vmapped reference, ragged-capacity behavior, and
-the int32 overflow audit on the grouped accumulator."""
+"""Grouped (batched-expert) Pallas kernel validation: the ragged kernels
+with ``row_counts=None`` (every capacity slot routed) against the
+per-expert oracles of ``kernels/ref.py``, zero-filled capacity slots, the
+qlinear/MoE entry points against the reference route, and the int32
+overflow audit on the grouped accumulator."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,9 +12,10 @@ from repro.core import integer_scale as isc
 from repro.core import packing, qlinear, quant
 from repro.core.recipe import QuantSpec
 from repro.kernels import ref as KR
-from repro.kernels.moe_gemm import (fg_grouped_gemm_float_scale,
-                                    fg_grouped_gemm_integer_scale,
-                                    grouped_w4a16_gemm)
+from repro.kernels.act_quant import act_quant
+from repro.kernels.moe_gemm import (fg_grouped_gemm_float_scale_ragged,
+                                    fg_grouped_gemm_integer_scale_ragged,
+                                    grouped_w4a16_gemm_ragged)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -44,17 +47,19 @@ def _mk_experts(seed, E, K, N, g, w_bits=4, amplifier=1024):
 
 
 def _mk_acts(seed, E, C, K):
+    """Raw activations and their int8 codes and per-token scales, from the
+    act_quant kernel whose block body the ragged kernels fuse."""
     x = jax.random.normal(jax.random.PRNGKey(seed), (E, C, K))
-    xq, sa = quant.quantize_activation(x.reshape(E * C, K))
+    xq, sa = act_quant(x.reshape(E * C, K), interpret=True)
     return x, xq.reshape(E, C, K), sa.reshape(E, C, 1)
 
 
 @pytest.mark.parametrize("E,C,K,N,g", SHAPES)
 def test_grouped_is_kernel_bit_exact_vs_vmapped_ref(E, C, K, N, g):
     qv, iscale, _, alphas, _ = _mk_experts(0, E, K, N, g)
-    _, xq, sa = _mk_acts(1, E, C, K)
-    y_k = fg_grouped_gemm_integer_scale(
-        xq, sa, qv, iscale, group_size=g, alpha=1024.0, interpret=True)
+    x, xq, sa = _mk_acts(1, E, C, K)
+    y_k = fg_grouped_gemm_integer_scale_ragged(
+        x, None, qv, iscale, group_size=g, alpha=1024.0, interpret=True)
     y_r = jnp.stack([
         KR.fg_gemm_is_ref(xq[e], sa[e], qv[e], iscale[e],
                           group_size=g, alpha=1024.0) for e in range(E)])
@@ -64,14 +69,15 @@ def test_grouped_is_kernel_bit_exact_vs_vmapped_ref(E, C, K, N, g):
 
 def test_grouped_is_kernel_per_expert_alpha():
     """Heuristic amplifiers give each expert its OWN alpha; the grouped
-    kernel folds 1/alpha_e into sa and must stay bit-exact per expert."""
+    kernel divides sa by alpha_e in its epilogue and must stay bit-exact
+    per expert."""
     E, C, K, N, g = 4, 16, 256, 256, 128
     qv, iscale, _, alphas, _ = _mk_experts(2, E, K, N, g,
                                            amplifier="heuristic+6")
     assert len(set(alphas)) > 1, "want distinct per-expert amplifiers"
-    _, xq, sa = _mk_acts(3, E, C, K)
-    y_k = fg_grouped_gemm_integer_scale(
-        xq, sa, qv, iscale, group_size=g,
+    x, xq, sa = _mk_acts(3, E, C, K)
+    y_k = fg_grouped_gemm_integer_scale_ragged(
+        x, None, qv, iscale, group_size=g,
         alpha=jnp.asarray(alphas, jnp.float32), interpret=True)
     y_r = jnp.stack([
         KR.fg_gemm_is_ref(xq[e], sa[e], qv[e], iscale[e],
@@ -82,9 +88,9 @@ def test_grouped_is_kernel_per_expert_alpha():
 @pytest.mark.parametrize("E,C,K,N,g", SHAPES[:2])
 def test_grouped_fs_kernel_vs_vmapped_ref(E, C, K, N, g):
     qv, _, fscale, _, _ = _mk_experts(4, E, K, N, g)
-    _, xq, sa = _mk_acts(5, E, C, K)
-    y_k = fg_grouped_gemm_float_scale(
-        xq, sa, qv, fscale, group_size=g, interpret=True)
+    x, xq, sa = _mk_acts(5, E, C, K)
+    y_k = fg_grouped_gemm_float_scale_ragged(
+        x, None, qv, fscale, group_size=g, interpret=True)
     y_r = jnp.stack([
         KR.fg_gemm_fs_ref(xq[e], sa[e], qv[e], fscale[e], group_size=g)
         for e in range(E)])
@@ -96,9 +102,9 @@ def test_grouped_w8_is_kernel_vs_vmapped_ref():
     E, C, K, N, g = 2, 16, 256, 128, 128
     qv, iscale, _, alphas, _ = _mk_experts(6, E, K, N, g, w_bits=8,
                                            amplifier="heuristic+6")
-    _, xq, sa = _mk_acts(7, E, C, K)
-    y_k = fg_grouped_gemm_integer_scale(
-        xq, sa, qv, iscale, group_size=g, w_bits=8,
+    x, xq, sa = _mk_acts(7, E, C, K)
+    y_k = fg_grouped_gemm_integer_scale_ragged(
+        x, None, qv, iscale, group_size=g, w_bits=8,
         alpha=jnp.asarray(alphas, jnp.float32), interpret=True)
     y_r = jnp.stack([
         KR.fg_gemm_is_ref(xq[e], sa[e], qv[e], iscale[e], group_size=g,
@@ -111,7 +117,8 @@ def test_grouped_w4a16_kernel_vs_vmapped_ref():
     qv, _, fscale, _, _ = _mk_experts(8, E, K, N, g)
     x = jax.random.normal(jax.random.PRNGKey(9), (E, C, K)).astype(
         jnp.bfloat16)
-    y_k = grouped_w4a16_gemm(x, qv, fscale, group_size=g, interpret=True)
+    y_k = grouped_w4a16_gemm_ragged(x, None, qv, fscale, group_size=g,
+                                    interpret=True)
     y_r = jnp.stack([
         KR.w4a16_gemm_ref(x[e], qv[e], fscale[e], group_size=g)
         for e in range(E)])
@@ -121,42 +128,25 @@ def test_grouped_w4a16_kernel_vs_vmapped_ref():
 
 def test_grouped_is_kernel_ragged_capacity_padding():
     """Dispatch buffers zero-fill capacity slots past each expert's routed
-    token count; padded rows must produce exactly-zero outputs and leave
-    the valid rows bit-identical to an unpadded run."""
+    token count; with every slot taken as routed, the padded rows must
+    produce exactly-zero outputs and the valid rows must equal the
+    per-expert reference of the unpadded activations."""
     E, C, K, N, g = 3, 24, 256, 128, 128
     qv, iscale, _, _, _ = _mk_experts(10, E, K, N, g)
-    _, xq, sa = _mk_acts(11, E, C, K)
+    x, xq, sa = _mk_acts(11, E, C, K)
     counts = [5, 24, 0]  # ragged per-expert occupancy, incl. empty expert
     rows = jnp.arange(C)[None, :, None]
     mask = rows < jnp.asarray(counts)[:, None, None]
-    xq_ragged = jnp.where(mask, xq, 0).astype(jnp.int8)
-    sa_ragged = jnp.where(mask, sa, 0.0)
-    y = fg_grouped_gemm_integer_scale(
-        xq_ragged, sa_ragged, qv, iscale, group_size=g, alpha=1024.0,
-        interpret=True)
-    y_full = fg_grouped_gemm_integer_scale(
-        xq, sa, qv, iscale, group_size=g, alpha=1024.0, interpret=True)
+    y = fg_grouped_gemm_integer_scale_ragged(
+        jnp.where(mask, x, 0.0), None, qv, iscale, group_size=g,
+        alpha=1024.0, interpret=True)
     for e, c in enumerate(counts):
+        y_r = KR.fg_gemm_is_ref(xq[e], sa[e], qv[e], iscale[e],
+                                group_size=g, alpha=1024.0)
         np.testing.assert_array_equal(np.asarray(y[e, :c]),
-                                      np.asarray(y_full[e, :c]))
+                                      np.asarray(y_r[:c]))
         np.testing.assert_array_equal(np.asarray(y[e, c:]),
                                       np.zeros((C - c, N), np.float32))
-
-
-def test_grouped_kernel_block_shape_sweep():
-    """BlockSpec tiling (incl. capacity padding to bm) must not change
-    results."""
-    E, C, K, N, g = 2, 20, 512, 256, 128
-    qv, iscale, _, _, _ = _mk_experts(12, E, K, N, g)
-    _, xq, sa = _mk_acts(13, E, C, K)
-    ref = fg_grouped_gemm_integer_scale(
-        xq, sa, qv, iscale, group_size=g, alpha=1024.0, interpret=True)
-    for bm, bn, bk in [(8, 128, 128), (16, 256, 256), (128, 128, 512)]:
-        y = fg_grouped_gemm_integer_scale(
-            xq, sa, qv, iscale, group_size=g, alpha=1024.0,
-            bm=bm, bn=bn, bk=bk, interpret=True)
-        np.testing.assert_array_equal(np.asarray(y), np.asarray(ref),
-                                      err_msg=f"blocks={(bm, bn, bk)}")
 
 
 def test_grouped_accumulator_overflow_audit():
