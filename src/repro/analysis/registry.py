@@ -51,8 +51,9 @@ G = K // GS
 E_DEC, C_DEC = 4, 8
 # layers of the stacked variants (a scanned model's layer stack)
 R_STACK = 3
-# decode-sized dense call given no blocks: the shape rule
-# (``w4a8_gemm.stream_blocks``) takes bk = 2048, 16 groups a block, nk = 2
+# decode-sized call given no blocks, dense or ragged grouped: the shape
+# rule (``w4a8_gemm.stream_blocks``) takes bk = 2048, 16 groups a block,
+# nk = 2
 K_STREAM = 4096
 
 
@@ -211,28 +212,30 @@ def _build_moe_ragged(integer: bool):
     return build
 
 
-def _build_moe_ragged_decode(integer: bool):
+def _build_moe_ragged_decode(integer: bool, k: int = K):
+    """The integer-scale call takes the launch the serving path makes,
+    blocks by shape; the float-scale one a fixed ``bk``."""
     def build():
         from repro.kernels import moe_gemm as MG
 
         rng = np.random.default_rng(7)
         packed, ints = [], []
         for _ in range(E_DEC):
-            p, _, i = _w4_operands(rng)
+            p, _, i = _w4_operands(rng, k=k)
             packed.append(p)
             ints.append(i)
         rc = np.asarray([0, 3, C_DEC, 5], np.int32)  # incl. idle expert
         if integer:
             fn = functools.partial(
                 MG.fg_grouped_gemm_integer_scale_ragged, group_size=GS,
-                alpha=1024.0, a_bits=8, w_bits=4, bk=BK)
+                alpha=1024.0, a_bits=8, w_bits=4)
             scale_arg = np.stack(ints)
         else:
             fn = functools.partial(
                 MG.fg_grouped_gemm_float_scale_ragged, group_size=GS,
                 a_bits=8, w_bits=4, bk=BK)
             scale_arg = (np.stack(ints) / 1024.0).astype(np.float32)
-        args = (_j(np.zeros((E_DEC, C_DEC, K), np.float32)), _j(rc),
+        args = (_j(np.zeros((E_DEC, C_DEC, k), np.float32)), _j(rc),
                 _j(np.stack(packed)), _j(scale_arg))
         return fn, args, {0: DATA, 1: Interval(0, C_DEC)}
     return build
@@ -360,9 +363,11 @@ def entries() -> list:
                     f"ragged weight-only E={E} C={C} K={K}",
                     _build_w4a16_ragged, prefetch_ranges=_RC),
         KernelEntry("moe-w4a8-is-ragged-decode",
-                    f"engine decode E={E_DEC} C={C_DEC} K={K} alpha=1024",
-                    _build_moe_ragged_decode(True), integer_scale=True,
-                    alpha=1024, prefetch_ranges=_RC_DEC),
+                    f"engine decode E={E_DEC} C={C_DEC} K={K_STREAM} "
+                    "alpha=1024 by shape",
+                    _build_moe_ragged_decode(True, k=K_STREAM),
+                    integer_scale=True, alpha=1024,
+                    prefetch_ranges=_RC_DEC),
         KernelEntry("moe-w4a8-fs-ragged-decode",
                     f"engine decode E={E_DEC} C={C_DEC} K={K} float-scale",
                     _build_moe_ragged_decode(False),

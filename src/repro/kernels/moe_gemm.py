@@ -79,6 +79,20 @@ whole slab of VMEM. Capping bm by K would halve the MXU rows of prefill;
 raising ``vmem_limit_bytes`` would tie the kernel to one chip's VMEM and
 grow with K. ``tests/test_tpu_compile.py`` compiles every capacity.
 
+Blocks. ``bm`` is C rounded up to 8, at most 128. The integer-scale
+kernel, given no ``bn``/``bk``, takes them by call shape
+(``w4a8_gemm.dense_blocks``). Where C fits one m-tile (decode) the grid
+is ``(E, 1, N/bn, K/bk)`` and each routed expert's weights are read once,
+so the blocks set only the number of grid steps: up to 2 MiB of packed
+weight a step (``w4a8_gemm.stream_blocks``; Mixtral's expert projections
+at C = 32 run 112 steps a call, not 3584). The slab and its codes stay
+resident beside those blocks, so the rule charges them, with the
+double-buffered scale column, to the same VMEM budget
+(``w4a8_gemm.step_vmem``) and narrows the blocks where they would not
+fit (down at C = 128 with an f32 slab: ``(1024, 512)``). Above one
+m-tile (prefill), and in the float-scale and weight-only kernels,
+``(bn, bk) = (256, 512)``, snapped to tile the call.
+
 With ragged skipping in place the ``act_quant`` ``maximum(amax, 1e-8)``
 floor is no longer what keeps padded slots sane on the grouped path (they
 are skipped outright, and partial-tile zero rows quantize to zero codes
@@ -96,8 +110,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .act_quant import quantize_rows
-from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, layer_operands,
-                        scale_rows, snap_blocks)
+from .w4a8_gemm import (_cdiv, _group_accumulate, _round_up, dense_blocks,
+                        layer_operands, scale_rows, snap_blocks)
 from .w4a16_gemm import _dequant_group_accumulate
 
 
@@ -226,16 +240,19 @@ def _ragged_kernel(rc_ref, _lr_ref, x_ref, wp_ref, s_ref, a_ref, o_ref,
 
 def _ragged_a8_call(x, row_counts, qvalue, scale, alpha, layer, *,
                     integer: bool, group_size: int, a_bits: int, w_bits: int,
-                    bm: int, bn: int, bk: int, interpret: bool, out_dtype):
-    """Shared wrapper for the ragged integer-/float-scale W{4,8}A8 kernels."""
+                    bm: int, bn: int | None, bk: int | None, interpret: bool,
+                    out_dtype):
+    """Shared wrapper for the ragged integer-/float-scale W{4,8}A8 kernels.
+    ``bn = bk = None``: blocks by shape (``w4a8_gemm.dense_blocks``)."""
     E, C, K = x.shape
     N = qvalue.shape[-1]
     coarse = group_size <= 0
     gs = K if coarse else group_size
     if not coarse and K % gs:
         raise ValueError(f"K={K} % group={gs}")
-    bm, bn, bk = snap_blocks(C, K, N, bm=bm, bn=bn, bk=bk,
-                             group_size=group_size)
+    bm, bn, bk = dense_blocks(C, K, N, group_size=group_size, w_bits=w_bits,
+                              bm=bm, bn=bn, bk=bk,
+                              slab_itemsize=x.dtype.itemsize)
     if coarse:
         gs = bk  # each K-block is one "group" with the constant scale
     groups_per_blk = bk // gs
@@ -301,8 +318,8 @@ def fg_grouped_gemm_integer_scale_ragged(
     a_bits: int = 8,
     w_bits: int = 4,
     bm: int = 128,
-    bn: int = 256,
-    bk: int = 512,
+    bn: int | None = None,  # None (with bk): chosen by shape, dense_blocks
+    bk: int | None = None,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:

@@ -48,9 +48,10 @@ Telemetry (repro.obs)
 ---------------------
 Every wrapper call increments ``qgemm_calls_total{scheme,kind,shape,
 block}`` on the current registry (``block``: the ``bm x bn x bk`` the
-kernel launches, after the shape rule and snapping), and a call given a
-``layer`` also ``qgemm_layer_indexed_total{kind}`` (kind ``dense`` or
-``grouped``): the mechanism that reads stacked weights in place engaged.
+kernel launches, dense or grouped, after the shape rule and snapping),
+and a call given a ``layer`` also ``qgemm_layer_indexed_total{kind}``
+(kind ``dense`` or ``grouped``): the mechanism that reads stacked weights
+in place engaged.
 These are host/python-side counts: in eager code they count executions;
 inside jit they count TRACES (a useful retrace detector — steady-state
 serving holds them constant). Executed vs total m-tiles of the ragged
@@ -90,10 +91,10 @@ class BlockConfig:
     whole quantization groups, which the kernels enforce against the
     qspec's ``group_size`` since that is a property of the weights, not of
     the launch). ``bn = bk = None`` (the default) leaves them to the
-    kernel: the dense integer-scale GEMM chooses them from the call's
-    shape (``w4a8_gemm.dense_blocks``), every other kernel takes
-    256 and 512. A block given here is launched as given, snapped only
-    to tile the call.
+    kernel: the integer-scale GEMMs, dense and ragged grouped, choose them
+    from the call's shape (``w4a8_gemm.dense_blocks``), every other
+    kernel takes 256 and 512. A block given here is launched as given,
+    snapped only to tile the call.
     """
 
     bm: int = 128
@@ -159,15 +160,16 @@ def _scheme_of(qspec: QuantSpec) -> str:
 
 
 def _launch(blk: BlockConfig, rows: int, K: int, N: int, qspec: QuantSpec,
-            *, by_shape: bool) -> dict:
-    """Kernel kwargs with the ``(bm, bn, bk)`` the kernel launches:
-    ``by_shape`` (the dense integer-scale GEMM) picks unset blocks from
-    the call's shape, any other kernel takes the defaults; both snapped
-    to tile the call, as the kernels would."""
-    if by_shape:
+            *, slab_itemsize: int = 0) -> dict:
+    """Kernel kwargs with the ``(bm, bn, bk)`` the kernel launches: the
+    integer-scale GEMMs, dense and ragged grouped (``slab_itemsize``: the
+    grouped call's dispatch buffer's), pick unset blocks from the call's
+    shape; the float-scale and weight-only kernels take the defaults.
+    Both snapped to tile the call, as the kernels would."""
+    if _is_integer_scale(qspec):
         bm, bn, bk = dense_blocks(rows, K, N, group_size=qspec.group_size,
                                   w_bits=qspec.w_bits, bm=blk.bm, bn=blk.bn,
-                                  bk=blk.bk)
+                                  bk=blk.bk, slab_itemsize=slab_itemsize)
     else:
         bm, bn, bk = snap_blocks(rows, K, N, bm=blk.bm,
                                  bn=blk.bn or DEFAULT_BN,
@@ -218,8 +220,7 @@ def qgemm(
             "qgemm takes the qlinear param dict as its second argument "
             "(the v1 positional qvalue/scale form was removed)")
     N = params["qvalue"].shape[-1]
-    kw = _launch(_as_block(block), *x.shape, N, qspec,
-                 by_shape=_is_integer_scale(qspec))
+    kw = _launch(_as_block(block), *x.shape, N, qspec)
     _record_call(_scheme_of(qspec), "dense", (*x.shape, N), kw, layer)
 
     if qspec.weight_only:
@@ -273,7 +274,8 @@ def qgemm_grouped(
             "removed)")
     C, K = x.shape[1:]
     N = params["qvalue"].shape[-1]
-    kw = _launch(_as_block(block), C, K, N, qspec, by_shape=False)
+    kw = _launch(_as_block(block), C, K, N, qspec,
+                 slab_itemsize=x.dtype.itemsize)
     _record_call(_scheme_of(qspec), "grouped", (*x.shape, N), kw, layer)
 
     if qspec.weight_only:
@@ -283,7 +285,7 @@ def qgemm_grouped(
             x, row_counts, params["qvalue"], params["scale"], layer,
             group_size=qspec.group_size, **kw)
 
-    if qspec.scale_mode == "integer" and qspec.fine_grained:
+    if _is_integer_scale(qspec):
         a = _resolve_alpha(params.get("alpha"), qspec, layer)
         return fg_grouped_gemm_integer_scale_ragged(
             x, row_counts, params["qvalue"], params["scale"], layer,

@@ -18,9 +18,11 @@ TPU adaptation:
   * BlockSpec tiles are chosen from the call's shape (:func:`dense_blocks`):
     (bm=128, bn=256, bk=512) above one m-tile of rows, and at decode,
     where every weight byte is read once, blocks of up to 2 MiB of packed
-    weight (:func:`stream_blocks`); the ``(K/g, bn)`` scale column stays
-    resident across k (:func:`scale_spec`), since a ``(bk/g, bn)`` block
-    per k-step is one Mosaic refuses whenever bk/g is not a multiple of 8.
+    weight that fit the VMEM budget (:func:`stream_blocks`, which the
+    ragged grouped kernel of ``moe_gemm`` shares); the ``(K/g, bn)``
+    scale column stays resident across k (:func:`scale_spec`), since a
+    ``(bk/g, bn)`` block per k-step is one Mosaic refuses whenever bk/g
+    is not a multiple of 8.
     Interpret mode does not apply the TPU compiler's tiling and VMEM rules;
     ``tests/test_tpu_compile.py`` compiles the kernels for a v5e to do so.
   * weights and scales may be the whole layer stack ``(R, ...)`` of a
@@ -263,37 +265,81 @@ STREAM_ROWS = 128
 #: Caps of :func:`stream_blocks`: the widest n-block, the deepest k-block,
 #: and the packed weight bytes of one grid step.
 STREAM_BN, STREAM_BK, STREAM_BYTES = 2048, 2048, 2 << 20
+#: VMEM that one grid step's blocks and scratch (:func:`step_vmem`) may
+#: take under :func:`stream_blocks`: three quarters of Mosaic's default
+#: scoped limit on a v5e (16 MiB), which no kernel here raises. The rest
+#: is left to the kernel body's temporaries, which are not blocks; at
+#: Mixtral's expert down projection (bm = 128, bn = 2048) they took
+#: 1.9 MiB (compiles for a v5e).
+VMEM_BUDGET = 12 << 20
 
 
-def stream_blocks(K: int, N: int, *, group_size: int,
-                  w_bits: int) -> tuple[int, int]:
-    """``(bn, bk)`` of a dense integer-scale call whose rows fit one
-    m-tile (decode: M = the engine's slots).
+def step_vmem(rows: int, K: int, bn: int, bk: int, *, group_size: int,
+              w_bits: int, slab_itemsize: int = 0) -> int:
+    """Bytes of VMEM one grid step of an integer-scale GEMM holds in
+    blocks and scratch, at ``bm = rows``: the packed ``(bk/pack, bn)``
+    weight block and the ``(K/g, bn)`` int32 scale column
+    (:func:`scale_spec`), both double-buffered; the int32 accumulator and
+    the double-buffered f32 output tile; the per-token scales, a lane
+    tile a row; and the activations. The dense kernel streams
+    ``(rows, bk)`` int8 blocks, double-buffered (``slab_itemsize=0``);
+    the ragged grouped kernels keep an m-tile's raw ``(rows, K)`` slab,
+    single-buffered, at ``slab_itemsize`` bytes a value, and its int8
+    codes (``moe_gemm``)."""
+    pack = 2 if w_bits == 4 else 1
+    if slab_itemsize:
+        acts = rows * K * (slab_itemsize + 1)
+    else:
+        acts = 2 * rows * bk
+    scale = _round_up(K // group_size, 8) * bn * 4
+    return (acts + 2 * rows * 128 * 4 + 2 * (bk // pack) * bn
+            + 2 * scale + 3 * rows * bn * 4)
 
-    Its grid is ``(1, N/bn, K/bk)`` and every weight byte is read once,
-    so the bytes do not depend on the blocks; the number of grid steps
-    does. Each step costs ≈ 0.35 us of its own, while a ``(512, 256)``
-    int4 block moves 64 KB, 0.08 us at 819 GB/s: small blocks leave such
-    calls bound by steps, not bytes. So take the widest ``bn`` (a
-    multiple of 128 dividing N, up to ``STREAM_BN``), then the deepest
-    ``bk`` (whole groups and packing units, dividing K, up to
-    ``STREAM_BK``) that keeps the packed block within ``STREAM_BYTES``;
-    where no such divisor exists, the defaults, snapped as ever. On a
-    v5e at M = 32, Qwen2-72B's gate projection (8192 -> 29568) takes
-    1295 us a call at ``(bn, bk) = (128, 512)`` and 225 us at the
-    ``(1408, 2048)`` chosen here.
+
+def stream_blocks(rows: int, K: int, N: int, *, group_size: int,
+                  w_bits: int, slab_itemsize: int = 0) -> tuple[int, int]:
+    """``(bn, bk)`` of an integer-scale call whose rows fit one m-tile:
+    a dense call at decode (M = the engine's slots), or a ragged grouped
+    call whose per-expert capacity C fits one (``moe_gemm``; the grid is
+    then ``(E, 1, N/bn, K/bk)``).
+
+    Every weight byte of the call (of each routed expert) is then read
+    once, so the bytes do not depend on the blocks; the number of grid
+    steps does. Each step costs ≈ 0.35 us of its own, while a
+    ``(512, 256)`` int4 block moves 64 KB, 0.08 us at 819 GB/s: small
+    blocks leave such calls bound by steps, not bytes. So take the widest
+    ``bn`` (a multiple of 128 dividing N, up to ``STREAM_BN``), then the
+    deepest ``bk`` (whole groups and packing units, dividing K, up to
+    ``STREAM_BK``) that keeps the packed block within ``STREAM_BYTES``
+    and the step within ``VMEM_BUDGET`` (:func:`step_vmem`, with the
+    call's ``rows`` and ``slab_itemsize``); a narrower ``bn`` only where
+    no ``bk`` fits. Where no such divisor exists, the defaults, snapped
+    as ever. On a v5e at M = 32, Qwen2-72B's gate projection
+    (8192 -> 29568) takes 1295 us a call at ``(bn, bk) = (128, 512)`` and
+    225 us at the ``(1408, 2048)`` chosen here. Mixtral's expert
+    projections take ``(2048, 2048)`` at C = 32; its down projection
+    (K = 14336) at C = 128 with an f32 slab, whose 9 MiB leave no room
+    for a 2 MiB block, takes ``(1024, 512)``.
     """
     pack = 2 if w_bits == 4 else 1
-    bn = _largest_divisor(N, STREAM_BN, LAYOUT_UNIT) or DEFAULT_BN
-    bk_cap = min(STREAM_BK, STREAM_BYTES * pack // bn)
-    bk = _largest_divisor(K, bk_cap, math.lcm(group_size, LAYOUT_UNIT))
-    return bn, bk or DEFAULT_BK
+    align = math.lcm(group_size, LAYOUT_UNIT)
+    for bn in _divisors(N, STREAM_BN, LAYOUT_UNIT) or [DEFAULT_BN]:
+        cap = min(STREAM_BK, STREAM_BYTES * pack // bn)
+        for bk in _divisors(K, cap, align) or [DEFAULT_BK]:
+            if step_vmem(rows, K, bn, bk, group_size=group_size,
+                         w_bits=w_bits,
+                         slab_itemsize=slab_itemsize) <= VMEM_BUDGET:
+                return bn, bk
+    return DEFAULT_BN, DEFAULT_BK
 
 
 def dense_blocks(M: int, K: int, N: int, *, group_size: int, w_bits: int,
                  bm: int = 128, bn: int | None = None,
-                 bk: int | None = None) -> tuple[int, int, int]:
-    """The ``(bm, bn, bk)`` a dense integer-scale call launches with.
+                 bk: int | None = None,
+                 slab_itemsize: int = 0) -> tuple[int, int, int]:
+    """The ``(bm, bn, bk)`` an integer-scale call launches with: the
+    dense GEMM (``M`` rows) or the ragged grouped one (``M`` = the
+    per-expert capacity C, ``slab_itemsize`` its dispatch buffer's).
 
     Blocks the caller gives are honoured, the other at its default
     (``DEFAULT_BN``/``DEFAULT_BK``). With neither given, a call of at
@@ -301,7 +347,9 @@ def dense_blocks(M: int, K: int, N: int, *, group_size: int, w_bits: int,
     the defaults. Either way :func:`snap_blocks` makes them tile the call.
     """
     if bn is None and bk is None and _round_up(M, 8) <= STREAM_ROWS:
-        bn, bk = stream_blocks(K, N, group_size=group_size, w_bits=w_bits)
+        bn, bk = stream_blocks(min(bm, _round_up(M, 8)), K, N,
+                               group_size=group_size, w_bits=w_bits,
+                               slab_itemsize=slab_itemsize)
     return snap_blocks(M, K, N, bm=bm, bn=bn or DEFAULT_BN,
                        bk=bk or DEFAULT_BK, group_size=group_size)
 
@@ -322,13 +370,11 @@ def snap_blocks(rows: int, K: int, N: int, *, bm: int, bn: int, bk: int,
     return bm, bn, bk
 
 
-def _largest_divisor(dim: int, cap: int, align: int) -> int | None:
-    """Largest multiple of ``align`` that divides ``dim`` and is at most
-    ``cap``; None if there is none."""
-    for cand in range(min(cap, dim) // align * align, 0, -align):
-        if dim % cand == 0:
-            return cand
-    return None
+def _divisors(dim: int, cap: int, align: int) -> list[int]:
+    """Multiples of ``align`` that divide ``dim`` and are at most ``cap``,
+    largest first."""
+    return [cand for cand in range(min(cap, dim) // align * align, 0, -align)
+            if dim % cand == 0]
 
 
 def _snap_block(dim: int, blk: int, align: int) -> int:

@@ -207,6 +207,44 @@ def test_stream_blocks_qwen2_decode(K, N, bn, bk):
     assert W.dense_blocks(32, K, N, group_size=128, w_bits=4) == (32, bn, bk)
 
 
+@pytest.mark.parametrize("C,K,N,itemsize,bm,bn,bk", [
+    (32, 4096, 14336, 2, 32, 2048, 2048),    # up (gate): 112 steps a call
+    (32, 14336, 4096, 2, 32, 2048, 2048),    # down: 112 steps a call
+    (512, 4096, 14336, 2, 128, 256, 512),    # prefill: the defaults
+    (512, 14336, 4096, 2, 128, 256, 512),
+    (128, 14336, 4096, 4, 128, 1024, 512),   # f32 slab: 9 MiB resident
+], ids=["up-decode", "down-decode", "up-prefill", "down-prefill",
+        "down-128-f32"])
+def test_stream_blocks_mixtral_experts(C, K, N, itemsize, bm, bn, bk):
+    """The blocks Mixtral's ragged expert GEMM launches: at decode's
+    C = 32 the widest and deepest blocks (112 grid steps a call, where
+    the (256, 512) defaults took 3584); above one m-tile the defaults;
+    and where the resident (bm, K) slab and its codes leave no room for
+    a 2 MiB block, a smaller one that fits the budget."""
+    got = W.dense_blocks(C, K, N, group_size=128, w_bits=4,
+                         slab_itemsize=itemsize)
+    assert got == (bm, bn, bk)
+    if C <= W.STREAM_ROWS:
+        assert W.step_vmem(bm, K, bn, bk, group_size=128, w_bits=4,
+                           slab_itemsize=itemsize) <= W.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("K,N", [(4096, 14336), (14336, 4096),
+                                 (2048, 2688), (2688, 1024)])
+def test_stream_blocks_fit_vmem(K, N, itemsize):
+    """Up to one m-tile of rows every chosen step fits the VMEM budget
+    with the ragged kernel's resident slab charged, and takes no more
+    grid steps than the defaults."""
+    for C in (8, 32, 64, 128):
+        bm, bn, bk = W.dense_blocks(C, K, N, group_size=128, w_bits=4,
+                                    slab_itemsize=itemsize)
+        assert W.step_vmem(bm, K, bn, bk, group_size=128, w_bits=4,
+                           slab_itemsize=itemsize) <= W.VMEM_BUDGET
+        old = W.snap_blocks(C, K, N, bm=128, bn=256, bk=512, group_size=128)
+        assert (N // bn) * (K // bk) <= (N // old[1]) * (K // old[2])
+
+
 def test_linear_apply_pallas_honors_stored_alpha():
     """Regression: the Pallas branch of linear_apply used to drop the
     stored per-layer ``alpha`` (heuristic amplifiers then rescaled by the

@@ -189,6 +189,35 @@ def test_ragged_block_shape_sweep(counts):
         _assert_rows(y, y_ref, rows)
 
 
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one_layer", "layer_stacked"])
+def test_ragged_is_stream_blocks_bit_exact(stacked):
+    """At one m-tile of capacity the kernel takes its blocks by shape: here
+    the whole N and a 2048-deep K block (16 groups a step, two k-steps),
+    and its output still equals the per-expert oracle bit for bit, with a
+    zero-routed expert, and reading layer 1 of a stack at a traced index."""
+    from repro.kernels import w4a8_gemm as W
+
+    E, C, K, N, g = 3, 8, 4096, 256, 128
+    assert W.dense_blocks(C, K, N, group_size=g, w_bits=4,
+                          slab_itemsize=4) == (8, 256, 2048)
+    qv, iscale, _, alphas = _mk_experts(20, E, K, N, g)
+    al = jnp.asarray(alphas, jnp.float32)
+    counts = [0, 5, C]
+    x = _ragged_acts(21, E, C, K, counts)
+    xq, sa = _dense_quant(x)
+    y_ref = _is_ref(xq, sa, qv, iscale, g, alphas)
+    layer = None
+    if stacked:
+        qv0, is0, _, _ = _mk_experts(22, E, K, N, g)
+        qv, iscale = jnp.stack([qv0, qv]), jnp.stack([is0, iscale])
+        layer = jnp.int32(1)
+    y = jax.jit(lambda lyr: fg_grouped_gemm_integer_scale_ragged(
+        x, jnp.asarray(counts, jnp.int32), qv, iscale, lyr, group_size=g,
+        alpha=al, interpret=True))(layer)
+    _assert_rows(y, y_ref, counts)
+
+
 def test_ragged_row_counts_none_vs_reference():
     """row_counts=None treats every capacity slot as routed (fused quant
     only — must still equal the per-expert reference on every row)."""

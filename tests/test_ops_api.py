@@ -23,9 +23,9 @@ def _dense_case(seed=0, K=512, N=256, M=8):
 
 class TestBlockConfig:
     def test_defaults_match_kernel_defaults(self):
-        """``bn``/``bk`` default to None, as the dense integer-scale
-        kernel's own (chosen by shape); every other GEMM kernel defaults
-        to the blocks ops fills in for None."""
+        """``bn``/``bk`` default to None, as the integer-scale kernels'
+        own, dense and ragged grouped (chosen by shape); every other GEMM
+        kernel defaults to the blocks ops fills in for None."""
         import inspect
 
         from repro.kernels import moe_gemm, w4a8_gemm, w4a8_gemm_fscale
@@ -38,12 +38,13 @@ class TestBlockConfig:
             p = inspect.signature(fn).parameters
             return tuple(p[n].default for n in ("bm", "bn", "bk"))
 
-        assert defaults(w4a8_gemm.fg_gemm_integer_scale) == (128, None, None)
+        for fn in (w4a8_gemm.fg_gemm_integer_scale,
+                   moe_gemm.fg_grouped_gemm_integer_scale_ragged):
+            assert defaults(fn) == (128, None, None), fn.__name__
         fixed = (128, w4a8_gemm.DEFAULT_BN, w4a8_gemm.DEFAULT_BK)
         assert fixed == (128, 256, 512)
         for fn in (w4a8_gemm_fscale.fg_gemm_float_scale,
                    w4a16_gemm.w4a16_gemm,
-                   moe_gemm.fg_grouped_gemm_integer_scale_ragged,
                    moe_gemm.fg_grouped_gemm_float_scale_ragged,
                    moe_gemm.grouped_w4a16_gemm_ragged):
             assert defaults(fn) == fixed, fn.__name__
@@ -159,6 +160,34 @@ class TestBlockLabel:
         labels = [dict(zip(calls.labelnames, key)) for key, _ in calls.items()]
         assert labels == [{"scheme": "w4a8-is", "kind": "dense",
                            "shape": f"{M}x{K}x{N}", "block": block}]
+
+    # Mixtral's expert up projection over its 8-layer stack: the decode
+    # call (C = 32, one m-tile) takes the blocks chosen by shape, prefill
+    # (C = 512) the defaults
+    @pytest.mark.parametrize("C,block", [(32, "32x2048x2048"),
+                                         (512, "128x256x512")])
+    def test_traced_grouped_call_records_launched_block(self, C, block):
+        R, E, K, N = 8, 8, 4096, 14336
+        spec = QuantSpec()
+
+        def call(x, rc, q, s, layer):
+            return ops.qgemm_grouped(
+                x, {"qvalue": q, "scale": s, "alpha": 1024.0}, spec,
+                row_counts=rc, layer=layer)
+
+        reg = obs.Registry()
+        with obs.use_registry(reg):
+            jax.eval_shape(
+                call, jax.ShapeDtypeStruct((E, C, K), jnp.bfloat16),
+                jax.ShapeDtypeStruct((E,), jnp.int32),
+                jax.ShapeDtypeStruct((R, E, K // 2, N), jnp.int8),
+                jax.ShapeDtypeStruct((R, E, K // 128, N), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+        calls = reg.counter("qgemm_calls_total", "",
+                            ("scheme", "kind", "shape", "block"))
+        labels = [dict(zip(calls.labelnames, key)) for key, _ in calls.items()]
+        assert labels == [{"scheme": "w4a8-is", "kind": "grouped",
+                           "shape": f"{E}x{C}x{K}x{N}", "block": block}]
 
     def test_explicit_block_is_recorded_as_launched(self):
         spec, params, x = _dense_case(K=512, N=384, M=8)

@@ -87,12 +87,15 @@ def test_dense_gemm_compiles(one_chip, scheme, K, N, M):
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("C", [8, 128, 160, 256])
+@pytest.mark.parametrize("C", [8, 64, 128, 160, 256])
 @pytest.mark.parametrize("proj", ["up", "down"])
 def test_ragged_grouped_is_compiles(one_chip, proj, C, dtype):
     """Every capacity the engine produces, including the multiples of bm
     (128, 256) that the wrapper does not pad: the full-K activation slab
-    and its quantization must fit VMEM at K=14336."""
+    and its quantization must fit VMEM at K=14336. Blocks by shape: up to
+    one m-tile (C <= 128) up to 2 MiB of packed weight a step, narrowed
+    where the slab leaves no room (down at C = 128 in f32), the defaults
+    above."""
     K, N = EXPERT_KN[proj]
     E = 8
     _compile(
@@ -142,10 +145,15 @@ def test_layer_stacked_dense_is_compiles_qwen2(one_chip, K, N):
     _stacked_dense_is(one_chip, 10, K, N, 32)
 
 
-@pytest.mark.parametrize("proj", ["up", "down"])
-def test_layer_stacked_ragged_is_compiles(one_chip, proj):
+@pytest.mark.parametrize("proj,C", [("up", 8), ("down", 8), ("up", 32),
+                                    ("down", 32)],
+                         ids=["up", "down", "up-decode", "down-decode"])
+def test_layer_stacked_ragged_is_compiles(one_chip, proj, C):
+    # decode: 32 slots x top-2 over 8 experts at capacity_factor 4.0 gives
+    # C = 32, one m-tile, blocks by shape (2048, 2048); C = 8 is the
+    # dispatch's smallest capacity
     K, N = EXPERT_KN[proj]
-    E, C = 8, 8  # decode: 32 slots x top-2 over 8 experts
+    E = 8
     _compile(
         lambda x, rc, q, s, lyr, a: fg_grouped_gemm_integer_scale_ragged(
             x, rc, q, s, lyr, group_size=GS, alpha=a),
